@@ -95,8 +95,7 @@ def _flash_ring_ok(q) -> bool:
     attention inside the ring (the einsum path materializes a fp32
     (B,H,S,S) score block per ring step — the flash partials never do)."""
     from ....ops.pallas import flash_attention as fa
-    B, H, S, D = q.shape
-    return fa.available() and S % 128 == 0 and D >= 64
+    return fa.flash_eligible(q.shape[2], q.shape[3])
 
 
 def _ring_fwd_flash(q, k, v, axis_name, causal, scale):

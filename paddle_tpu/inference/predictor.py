@@ -965,11 +965,10 @@ class ContinuousBatchingEngine:
         names the output positions the same way (default ``(logits,
         pool)``; a single kind maps the output pytree directly) —
         logits are replicated (the per-shard body already all-gathered
-        them over tp AND dp; ``check_rep=False`` skips the symbolic
+        them over tp AND dp; ``check_vma=False`` skips the symbolic
         replication proof, same as the training-side ring-attention
         shard_map). ``cache`` picks whose pool specs "pool" means —
         the DRAFT pool's programs (ISSUE 20) pass their own cache."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         pool_specs = (cache if cache is not None else self.cache
                       ).pool_specs
@@ -985,10 +984,10 @@ class ContinuousBatchingEngine:
             kinds["adapters"] = self.adapters.specs
         out_specs = (kinds[out_kinds[0]] if len(out_kinds) == 1
                      else tuple(kinds[k] for k in out_kinds))
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=tuple(kinds[k] for k in arg_kinds),
-            out_specs=out_specs, check_rep=False)
+            out_specs=out_specs, check_vma=False)
 
     def _decode(self):
         if self._decode_fn is None:
@@ -1749,17 +1748,16 @@ class ContinuousBatchingEngine:
         if (self._steps - 1) % 16:      # first step, then every 16th
             return
         if self._tp_probe is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import NamedSharding, PartitionSpec as P
             mesh, ax, tp = self.mesh, self._tp_axis, self._tp
             vp = -(-self.cfg.vocab_size // tp)  # per-shard logits cols
             x = jax.device_put(
                 jnp.zeros((self.max_batch, vp * tp), jnp.float32),
                 NamedSharding(mesh, P(None, ax)))
-            f = jax.jit(shard_map(
+            f = jax.jit(jax.shard_map(
                 lambda t: jax.lax.all_gather(t, ax, axis=1, tiled=True),
                 mesh=mesh, in_specs=P(None, ax), out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             np.asarray(f(x))            # compile outside the timing
             self._tp_probe = (f, x)
         probe, x = self._tp_probe

@@ -457,10 +457,33 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
-def _attention(q, k, v, causal=True):
-    """(B,S,H,hd) attention; Pallas flash on TPU, fused jnp elsewhere."""
-    if _fa.available() and q.shape[1] % 128 == 0 and q.shape[-1] >= 64:
-        return _fa.flash_attention(q, k, v, causal=causal)
+def _attention(q, k, v, causal=True, mesh_axes=None):
+    """(B,S,H,hd) attention; Pallas flash where
+    :func:`~paddle_tpu.ops.pallas.flash_attention.flash_eligible` says
+    so, fused jnp elsewhere. Under a mesh the kernel runs per shard
+    (batch over the data axes, heads over tp): Mosaic kernels are not
+    partitioned by GSPMD, and attention needs no cross-shard traffic."""
+    if _fa.flash_eligible(q.shape[1], q.shape[-1]):
+        attn = partial(_fa.flash_attention, causal=causal)
+        if mesh_axes is not None:
+            mesh = mesh_axes["mesh"]
+            spec = P(mesh_axes["data"], None, mesh_axes["tp"], None)
+            # map over every axis not already manual: the kernel lowers
+            # only with none left to GSPMD. Inside a pipeline stage
+            # (manual over pp) the enclosing mesh is the one to name.
+            ctx = jax.sharding.get_abstract_mesh()
+            attn = jax.shard_map(
+                attn, mesh=ctx if ctx.manual_axes else mesh,
+                axis_names=set(mesh.axis_names) - set(ctx.manual_axes),
+                in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False)
+        return attn(q, k, v)
+    return _attention_jnp(q, k, v, causal)
+
+
+def _attention_jnp(q, k, v, causal=True):
+    """The fused-softmax jnp attention: the path off the chip, and the
+    reference the flash kernel is checked against on it."""
     b, sq, h, hd = q.shape
     hk = k.shape[2]
     if hk != h:
@@ -476,8 +499,11 @@ def _attention(q, k, v, causal=True):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes):
-    """One decoder layer. lp = per-layer params (no leading L axis)."""
+def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None):
+    """One decoder layer. lp = per-layer params (no leading L axis).
+    ``attn_axes``: mesh axes for the per-shard flash kernel alone, for a
+    caller that places activations itself (the pipeline stages);
+    defaults to ``mesh_axes``."""
     B, S, H = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
 
@@ -511,17 +537,18 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes):
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     if cp:
-        from jax import shard_map
         from ..distributed.fleet.meta_parallel.context_parallel import (
             ring_attention)
         spec = P(mesh_axes["data"], cp, mesh_axes["tp"], None)
-        attn = shard_map(
+        attn = jax.shard_map(
             partial(ring_attention, axis_name=cp, causal=True),
             mesh=mesh_axes["mesh"], in_specs=(spec, spec, spec),
             out_specs=spec, check_vma=False)
         o = attn(q, k, v).reshape(B, S, nh * hd)
     else:
-        o = _attention(q, k, v, causal=True).reshape(B, S, nh * hd)
+        o = _attention(q, k, v, causal=True,
+                       mesh_axes=attn_axes or mesh_axes).reshape(
+                           B, S, nh * hd)
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "attn_out")
     x = sp(x + o @ lp["wo"])

@@ -144,12 +144,16 @@ def make_train_step_pp(cfg: llama.LlamaConfig, mesh: Mesh, *,
         pipeline_spmd, pipeline_interleave, pipeline_1f1b,
         pipeline_interleave_1f1b)
 
+    attn_axes = {"mesh": mesh, "data": dp,
+                 "tp": "tp" if "tp" in mesh.axis_names else None}
+
     def make_stage_fn(cos, sin):
         def stage_fn(stage_params, xin):
             x = xin[:, :-1] if moe_aux else xin
 
             def body(c, lp):
-                y, aux = llama._block(c, lp, cos, sin, cfg, None)
+                y, aux = llama._block(c, lp, cos, sin, cfg, None,
+                                      attn_axes=attn_axes)
                 return y, aux
             y, auxs = lax.scan(body, x, stage_params)
             if not moe_aux:

@@ -19,16 +19,10 @@ import math
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import available, set_interpret  # shared gate
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
-
 from . import flash_attention as _fa
 
 
@@ -158,9 +152,17 @@ def _swiglu_bwd_kernel(g_ref, u_ref, d_ref, dg_ref, du_ref):
     du_ref[...] = (d * silu).astype(du_ref.dtype)
 
 
+def _swiglu_rows(n, h, block_rows):
+    """Row block sized from the row width: the backward holds five
+    double-buffered (br, h) blocks plus fp32 temporaries in the 16 MiB of
+    scoped VMEM, so br * h stays within 128 Ki elements (br = 32 at
+    width 4096), in multiples of the bf16 sublane tile."""
+    return min(block_rows, n, max(16, (131072 // h) // 16 * 16))
+
+
 def _swiglu_2d(g, u, block_rows):
     n, h = g.shape
-    br = min(block_rows, n)
+    br = _swiglu_rows(n, h, block_rows)
     return pl.pallas_call(
         _swiglu_fwd_kernel,
         grid=(pl.cdiv(n, br),),
@@ -184,7 +186,7 @@ def _swiglu_fwd_rule(g, u, block_rows):
 def _swiglu_bwd_rule(block_rows, res, d):
     g, u = res
     n, h = g.shape
-    br = min(block_rows, n)
+    br = _swiglu_rows(n, h, block_rows)
     dg, du = pl.pallas_call(
         _swiglu_bwd_kernel,
         grid=(pl.cdiv(n, br),),
@@ -438,7 +440,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
     # the array dim or be a lane multiple); kernels index by grid row
     in_specs.append(pl.BlockSpec(
         (B * HK,), lambda i, j: (0,),
-        memory_space=pltpu.SMEM if _PALLAS_OK else None))
+        memory_space=pltpu.SMEM))
     inputs.append(lens)
 
     out = pl.pallas_call(
@@ -534,14 +536,14 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
         pl.BlockSpec((1, 1, page, D),
                      lambda i, j, bt_: (i % HK, bt_[i // HK, j], 0, 0)),
         pl.BlockSpec((B * HK,), lambda i, j, bt_: (0,),
-                     memory_space=pltpu.SMEM if _PALLAS_OK else None),
+                     memory_space=pltpu.SMEM),
     ]
     inputs = [bt, qt, kp, vp, lens]
     if quant:
         for _ in range(2):
             in_specs.append(pl.BlockSpec(
                 (B * HK,), lambda i, j, bt_: (0,),
-                memory_space=pltpu.SMEM if _PALLAS_OK else None))
+                memory_space=pltpu.SMEM))
         inputs += [_rows(k_dequant_scale), _rows(v_dequant_scale)]
         kernel = functools.partial(_paged_decode_kernel_q, scale=s,
                                    page=page)

@@ -48,17 +48,12 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import available, set_interpret  # noqa: F401 — gate
 from . import flash_attention as _fa
 from . import fused as _fused
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
 
 
 def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -167,11 +162,6 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     :func:`paged_attention_reference` (pool layout (P, page, HK, D),
     per-row int8 scales (P, page, HK)), but per-row attention work is
     sized by ``ceil(length/page)`` instead of the slot extent."""
-    if not _PALLAS_OK:
-        raise RuntimeError(
-            "paged_attention_kernel: jax.experimental.pallas is "
-            "unavailable — use paged_attention() (or use_kernel=False) "
-            "for the pure-lax fallback")
     B, H, D = q.shape
     P, page, HK = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     assert H % HK == 0
@@ -263,10 +253,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     — interpret mode in tests), pure-lax gather fallback elsewhere so
     tier-1 CPU runs exercise dense-decode-identical numerics."""
     if use_kernel is None:
-        try:
-            use_kernel = jax.devices()[0].platform == "tpu"
-        except Exception:
-            use_kernel = False
+        use_kernel = _fa.on_tpu()
     if use_kernel:
         return paged_attention_kernel(
             q, k_pages, v_pages, block_tables, lengths, scale=scale,

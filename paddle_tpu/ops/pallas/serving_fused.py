@@ -46,18 +46,13 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import available, set_interpret  # noqa: F401 — gate
 from . import flash_attention as _fa
 from . import fused as _fused
 from . import paged_attention as _pa
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
 
 
 def rotate_half(x: jax.Array) -> jax.Array:
@@ -168,11 +163,6 @@ def fused_paged_decode_kernel(q, cos_row, sin_row, k_pages, v_pages,
     :func:`~paddle_tpu.ops.pallas.paged_attention.
     paged_attention_kernel`; the only addition is the in-VMEM rotation,
     whose values match the unfused XLA rotation exactly."""
-    if not _PALLAS_OK:
-        raise RuntimeError(
-            "fused_paged_decode_kernel: jax.experimental.pallas is "
-            "unavailable — use fused_paged_decode_attention() for the "
-            "pure-lax fallback")
     B, H, D = q.shape
     P, page, HK = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     assert H % HK == 0
@@ -271,10 +261,7 @@ def fused_paged_decode_attention(q, cos_row, sin_row, k_pages, v_pages,
     TPU or when forced (interpret mode in tests), pure-lax reference —
     bit-identical to the unfused reference composition — elsewhere."""
     if use_kernel is None:
-        try:
-            use_kernel = jax.devices()[0].platform == "tpu"
-        except Exception:
-            use_kernel = False
+        use_kernel = _fa.on_tpu()
     if use_kernel:
         return fused_paged_decode_kernel(
             q, cos_row, sin_row, k_pages, v_pages, block_tables,
@@ -471,11 +458,6 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
     (hence T <= 32 in tree mode — comb trees are shallow and narrow),
     and only the mask predicate changes inside the step.
     """
-    if not _PALLAS_OK:
-        raise RuntimeError(
-            "flash_chunk_attention_kernel: jax.experimental.pallas is "
-            "unavailable — use flash_chunk_attention() for the "
-            "pure-lax fallback")
     B, T, H, D = q.shape
     W, HK = ck.shape[1], ck.shape[2]
     assert H % HK == 0
@@ -532,7 +514,7 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
                                    **tkw)
     in_specs.append(pl.BlockSpec(
         (B * HK,), lambda i, j: (0,),
-        memory_space=pltpu.SMEM if _PALLAS_OK else None))
+        memory_space=pltpu.SMEM))
     inputs.append(kst)
     if tree_mask is not None:
         # per-node ancestor bitmask, repeated over kv-head groups like
@@ -542,7 +524,7 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
                 ).sum(axis=2)                             # (B, T)
         in_specs.append(pl.BlockSpec(
             (B * HK, T), lambda i, j: (0, 0),
-            memory_space=pltpu.SMEM if _PALLAS_OK else None))
+            memory_space=pltpu.SMEM))
         inputs.append(jnp.repeat(bits, HK, axis=0))
 
     out = pl.pallas_call(
@@ -573,10 +555,7 @@ def flash_chunk_attention(q, ck, cv, length, kstart, *, scale=None,
     ``paged_verify_forward`` (the fused VERIFY kernel, linear AND —
     via ``tree_mask`` — tree speculative)."""
     if use_kernel is None:
-        try:
-            use_kernel = jax.devices()[0].platform == "tpu"
-        except Exception:
-            use_kernel = False
+        use_kernel = _fa.on_tpu()
     if use_kernel:
         return flash_chunk_attention_kernel(
             q, ck, cv, length, kstart, scale=scale, k_rows=k_rows,

@@ -184,7 +184,17 @@ class MultiProcessCluster:
     steps run; :meth:`step` / :meth:`run` drive the cluster; the
     failure counters carry the same names. Construction SPAWNS the
     replica workers (and dials the shared fabric when given its
-    endpoint)."""
+    endpoint).
+
+    ONE PROCESS PER CHIP: every worker initialises JAX with the
+    environment it inherits (``env=``), so on a host whose accelerator
+    belongs to one process at a time N workers are N claimants for one
+    chip — the second fails or hangs. Nobody has shown each worker
+    pinned to its own chip under this controller, so the plane has run
+    only with CPU workers and has never been measured on a chip
+    (ROADMAP D9); the in-process
+    :class:`~paddle_tpu.serving.cluster.ServingCluster` is the one
+    that can be."""
 
     def __init__(self, *, replicas: int = 1, workdir: str,
                  factory: str =
@@ -200,8 +210,7 @@ class MultiProcessCluster:
                  clock=time.monotonic,
                  handoff_retries: int = 2, retry_sleep=time.sleep,
                  rpc_kw: Optional[Dict] = None,
-                 spawn_timeout_s: float = 300.0,
-                 xla_cache_dir: Optional[str] = None, env=None):
+                 spawn_timeout_s: float = 300.0, env=None):
         if prefill_replicas >= replicas and replicas > 0 \
                 and prefill_replicas > 0:
             raise ValueError("need at least one decode replica")
@@ -219,7 +228,6 @@ class MultiProcessCluster:
         self._retry_sleep = retry_sleep
         self._rpc_kw = dict(rpc_kw or {})
         self._spawn_timeout_s = float(spawn_timeout_s)
-        self._xla_cache_dir = xla_cache_dir
         self._env = env
         self.nodes: List[Optional[ReplicaProcess]] = [
             self._spawn_node(i) for i in range(replicas)]
@@ -263,7 +271,6 @@ class MultiProcessCluster:
                 "fabric": self.fabric,
                 "trace": self.trace,
                 "metrics": self.metrics,
-                "xla_cache_dir": self._xla_cache_dir,
                 "port_file": os.path.join(
                     self.workdir, f"replica{idx:03d}.endpoint")}
 

@@ -455,21 +455,10 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     with open(args.spec) as f:
         spec = json.load(f)
-    cache_dir = spec.get("xla_cache_dir")
-    if cache_dir:
-        # the tier-1 harness's persistent compilation cache
-        # (tests/conftest.py): worker processes compile the same tiny
-        # programs the parent already did — dedupe them
-        try:
-            import jax
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass
+    # workers compile the same programs as each other and as their
+    # predecessors on the same WAL directory: share the persistent cache
+    from .._core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if spec.get("trace"):
         from ..observability import tracing
         tracing.enable()

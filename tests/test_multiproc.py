@@ -54,7 +54,6 @@ from paddle_tpu.serving.rpc import (
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-XLA_CACHE = os.path.join(REPO, "artifacts", "xla_cache")
 
 
 # ---------------------------------------------------------------------------
@@ -432,8 +431,7 @@ def _run_identity_with_kill(tmp, prompts, max_new, ref_tokens,
     try:
         mc = MultiProcessCluster(replicas=2, prefill_replicas=1,
                                  workdir=tmp, trace=True,
-                                 factory_kw=factory_kw or None,
-                                 xla_cache_dir=XLA_CACHE)
+                                 factory_kw=factory_kw or None)
         handles = [mc.submit(p, max_new_tokens=max_new)
                    for p in prompts]
         killed = False
@@ -509,7 +507,7 @@ class TestMultiProcessCluster:
             fp = FabricProcess(str(tmp_path), page_size=8)
             mc1 = MultiProcessCluster(
                 replicas=1, workdir=str(tmp_path / "c1"),
-                fabric=fp.endpoint, xla_cache_dir=XLA_CACHE)
+                fabric=fp.endpoint)
             h1 = mc1.submit(sysprompt, max_new_tokens=6)
             mc1.run(max_steps=200)
             ts1 = mc1.tier_stats(0)
@@ -520,7 +518,7 @@ class TestMultiProcessCluster:
 
             mc2 = MultiProcessCluster(
                 replicas=1, workdir=str(tmp_path / "c2"),
-                fabric=fp.endpoint, xla_cache_dir=XLA_CACHE)
+                fabric=fp.endpoint)
             h2 = mc2.submit(sysprompt, max_new_tokens=6)
             mc2.run(max_steps=200)
             ts2 = mc2.tier_stats(0)
@@ -557,8 +555,7 @@ class TestMultiProcessCluster:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         report = mod.run_multiproc_soak(seed=0, requests=6,
-                                        workdir=str(tmp_path),
-                                        xla_cache_dir=XLA_CACHE)
+                                        workdir=str(tmp_path))
         assert report["failovers"] >= 1
         assert report["handoff_corruptions"] >= 1
         assert report["fabric"]["puts_total"] >= 1

@@ -449,7 +449,7 @@ class TestDegradedLadder:
             inj.arm("decode_step", "raise", nth=2)
             sup, _ = _supervised_run(_factory(None), inj)
             # one REAL failure on top (a non-injected exception)
-            sup._on_failure(RuntimeError("tunnel reset"))
+            sup._on_failure(RuntimeError("connection reset"))
             snap = obs.REGISTRY.to_json()
         finally:
             obs.REGISTRY.clear()

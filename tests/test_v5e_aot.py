@@ -1,0 +1,143 @@
+"""Compile the repo's programs for the real chip with no chip present.
+
+The installed libtpu describes a v5e 2x2 topology under
+``JAX_PLATFORMS=cpu``, and ``jit(f).lower(<avals sharded on those
+devices>).compile()`` runs the real XLA:TPU and Mosaic compilers, so a
+kernel that overflows scoped VMEM or a Mosaic call left to GSPMD fails
+HERE and not on the first chip run. The CPU meshes cannot show either:
+there the flash kernel is not eligible and every test takes the jnp path.
+It proves compilation only; ``chip_smoke.py`` proves the run.
+"""
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.models import llama, train, train_pp
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import fused, paged_attention
+
+# the flagship width of chip_smoke.py / bench.py, and a narrow twin with
+# the same 128-wide heads for the tier-1 train steps
+FLAGSHIP = dict(vocab_size=32000, hidden_size=1536, intermediate_size=4096,
+                num_heads=12, num_kv_heads=12, max_seq_len=4096)
+NARROW = dict(vocab_size=512, hidden_size=256, intermediate_size=512,
+              num_heads=2, num_kv_heads=2, max_seq_len=512)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    # libtpu takes a machine-wide lock; another session compiling at the
+    # same time must not fail this one
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+    devs = topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices
+    assert len(devs) == 4 and devs[0].device_kind == "TPU v5 lite"
+    return devs
+
+
+def _compile(fn, *avals):
+    """Lower and compile ``fn`` (jitted here unless it already is) for the
+    avals' TPU devices, and see that the kernel is in the lowering."""
+    with fa.force_compiled_lowering():
+        lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*avals)
+        lowered.compile()
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def _on(dev, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(dev))
+
+
+def test_flash_fwd_bwd_flagship_shape(v5e):
+    q = _on(v5e[0], (4, 4096, 12, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("page,kv", [(16, "bf16"), (16, "int8"),
+                                     (8, "bf16"), (32, "int8")])
+def test_paged_decode_kernel(v5e, page, kv):
+    B, H, D, pages, ppseq = 8, 12, 128, 257, 2048 // page
+    d = v5e[0]
+    pool = _on(d, (pages, page, H, D),
+               jnp.int8 if kv == "int8" else jnp.bfloat16)
+    scales = _on(d, (pages, page, H), jnp.float32)
+    args = [_on(d, (B, H, D), jnp.bfloat16), pool, pool,
+            _on(d, (B, ppseq), jnp.int32), _on(d, (B,), jnp.int32)]
+
+    def f(q, k, v, bt, lens, *sc):
+        ks, vs = sc if sc else (None, None)
+        # use_kernel=None: the dispatcher itself must pick the kernel
+        return paged_attention.paged_attention(
+            q, k, v, bt, lens, ks_pages=ks, vs_pages=vs)
+    if kv == "int8":
+        args += [scales, scales]
+    _compile(f, *args)
+
+
+def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
+    """block_rows=256 x width 4096 needed 19.93 MiB of the 16 MiB scoped
+    VMEM, forward and backward; the row block now follows the width."""
+    g = _on(v5e[0], (4 * 4096, 4096), jnp.bfloat16)
+
+    def loss(g, u):
+        return fused.swiglu(g, u).astype(jnp.float32).sum()
+    _compile(jax.value_and_grad(loss, argnums=(0, 1)), g, g)
+
+
+def _abstract_state(cfg, shardings):
+    state = jax.eval_shape(lambda k: train.init_train_state(k, cfg),
+                           jax.random.key(0))
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        state, shardings)
+
+
+def _hybrid(v5e, width, seq):
+    cfg = llama.LlamaConfig(**{**width, "max_seq_len": seq}, num_layers=2,
+                            dtype=jnp.bfloat16, remat=True)
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 2), ("dp", "fsdp", "tp"))
+    step = train.make_train_step(cfg, mesh, seq_chunk=512)
+    tokens = jax.ShapeDtypeStruct(
+        (4, seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+    _compile(step, _abstract_state(cfg, train.state_shardings(mesh, cfg)),
+             tokens)
+
+
+def test_hybrid_train_step_lowers_with_the_flash_kernel(v5e):
+    """("dp","fsdp","tp") = (1,2,2): the flash call sits on GSPMD-sharded
+    operands and lowers only inside a shard_map ("Mosaic kernels cannot
+    be automatically partitioned")."""
+    _hybrid(v5e, NARROW, 512)
+
+
+@pytest.mark.slow
+def test_hybrid_train_step_flagship_width(v5e):
+    _hybrid(v5e, FLAGSHIP, 4096)
+
+
+def test_pipeline_train_step_lowers_with_the_flash_kernel(v5e):
+    """("dp","pp","tp") = (1,2,2), interleave_1f1b: the stages are manual
+    over pp only, so the kernel needs the remaining axes mapped too."""
+    cfg = llama.LlamaConfig(**NARROW, num_layers=4, dtype=jnp.bfloat16,
+                            remat=True)
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 2), ("dp", "pp", "tp"))
+    step = train_pp.make_train_step_pp(
+        cfg, mesh, num_microbatches=2, schedule="interleave_1f1b",
+        num_chunks=2)
+    tokens = jax.ShapeDtypeStruct(
+        (4, 512), jnp.int32, sharding=NamedSharding(mesh, P("dp")))
+    _compile(step,
+             _abstract_state(cfg, train_pp.state_shardings_pp(mesh, cfg)),
+             tokens)

@@ -1342,8 +1342,7 @@ def run_crash_soak(seed: int = 0, kills: int = 4,
 
 
 def run_multiproc_soak(seed: int = 0, requests: int = 6,
-                       max_steps: int = 600, workdir=None,
-                       xla_cache_dir=None) -> dict:
+                       max_steps: int = 600, workdir=None) -> dict:
     """Multi-process soak (ISSUE 19): a REAL process tree — one
     prefill worker, one decode worker, one shared KV fabric server —
     driven by :class:`~paddle_tpu.serving.MultiProcessCluster` with
@@ -1394,10 +1393,6 @@ def run_multiproc_soak(seed: int = 0, requests: int = 6,
             for p, m in jobs]
 
     wd = workdir or tempfile.mkdtemp(prefix="mp_soak_")
-    if xla_cache_dir is None:
-        xla_cache_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "artifacts", "xla_cache")
     t_start = time.perf_counter()
     fp = None
     mc = None
@@ -1406,8 +1401,7 @@ def run_multiproc_soak(seed: int = 0, requests: int = 6,
         fp = FabricProcess(wd, page_size=8)
         mc = MultiProcessCluster(
             replicas=2, prefill_replicas=1,
-            workdir=os.path.join(wd, "cluster"), fabric=fp.endpoint,
-            xla_cache_dir=xla_cache_dir)
+            workdir=os.path.join(wd, "cluster"), fabric=fp.endpoint)
         reqs = [mc.submit(p, max_new_tokens=m) for p, m in jobs]
         with inj:
             # first handoff export ships corrupt bytes; a mid-run send

@@ -1,13 +1,14 @@
 """One-off perf sweep for the bench config on the real chip.
 
 Runs each variant in a subprocess (isolates OOM/compile failures), prints
-tokens/s + MFU per variant. Not part of the driver flow — a tuning tool.
+tokens/s + MFU per variant. The parent never touches JAX, so each child
+in turn is the one process on the chip. A tuning tool: it prints, and
+changes no configuration.
 """
 import json
 import os
 import subprocess
 import sys
-import time
 
 CHILD = r"""
 import time, json, os, sys
@@ -40,8 +41,6 @@ tps = batch * seq / dt
 mfu = tps * cfg.flops_per_token(seq) / 197e12
 print("SWEEP_RESULT " + json.dumps(
     {"variant": variant, "tps": round(tps, 1), "mfu": round(mfu, 4)}))
-sys.stdout.flush()
-os._exit(0)
 """
 
 VARIANTS = [
@@ -58,45 +57,8 @@ VARIANTS = [
 ]
 
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-WINNER_PATH = os.path.join(_REPO, "PERF_WINNER.json")
-BASE_NAME = "base_b4_nothing"
-ADOPT_MARGIN = 1.02     # flip the bench config only for a >2% win
-
-
-def _record_winner(results):
-    """If a measured variant beats the base by the adoption margin,
-    write PERF_WINNER.json so bench.py's pick_config applies it on the
-    next (e.g. driver end-of-round) run — no manual flip needed."""
-    by_name = {r["variant"]["name"]: r for r in results}
-    base = by_name.get(BASE_NAME)
-    if base is None or not results:
-        return
-    best = max(results, key=lambda r: r["tps"])
-    if best["variant"]["name"] == BASE_NAME or \
-            best["tps"] < base["tps"] * ADOPT_MARGIN:
-        # base (still) wins: clear any stale winner so bench reverts
-        if os.path.exists(WINNER_PATH):
-            os.remove(WINNER_PATH)
-            print("SWEEP_WINNER cleared (base config wins)")
-        return
-    rec = {"variant": best["variant"], "tps": best["tps"],
-           "mfu": best["mfu"], "base_tps": base["tps"],
-           "gain": round(best["tps"] / base["tps"] - 1, 4),
-           "recorded_unix": time.time(),
-           "recorded_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                        time.gmtime())}
-    # atomic: the driver's bench may read concurrently with this write
-    tmp = WINNER_PATH + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(rec, f, indent=1)
-    os.replace(tmp, WINNER_PATH)
-    print("SWEEP_WINNER " + json.dumps(rec))
-
-
 def main():
     names = sys.argv[1:]
-    results = []
     for v in VARIANTS:
         if names and v["name"] not in names:
             continue
@@ -117,7 +79,6 @@ def main():
                     except ValueError:
                         continue
                     print(line)
-                    results.append(parsed)
                     break
             if parsed is None:
                 tail = " | ".join(proc.stdout.strip().splitlines()[-3:])
@@ -125,8 +86,6 @@ def main():
         except subprocess.TimeoutExpired:
             print(f"SWEEP_TIMEOUT {v['name']}")
         sys.stdout.flush()
-    if not names:                 # only a FULL sweep may adopt a winner
-        _record_winner(results)
 
 
 if __name__ == "__main__":

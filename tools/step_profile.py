@@ -1,8 +1,8 @@
-"""Measured component breakdown of the bench train step on a live chip.
+"""Measured component breakdown of the bench train step on a chip.
 
 Times separately-jitted slices of the headline config (660M Llama,
-batch 4 x seq 4096) with host-transfer fences, then prints a markdown
-table of step-time shares. One-off tuning/analysis tool — feeds
+batch 4 x seq 4096), then prints a markdown table of step-time shares.
+No chip is an error. One-off tuning/analysis tool — feeds
 PERF_NOTES.md (the MFU ceiling accounting), not the driver flow.
 
   python tools/step_profile.py            # on the real chip
@@ -37,16 +37,15 @@ def fence_tree(tree):
 def main():
     from paddle_tpu.models import llama, train
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu:
-        cfg = llama.LlamaConfig(
-            vocab_size=32000, hidden_size=1536, intermediate_size=4096,
-            num_layers=20, num_heads=12, num_kv_heads=12,
-            max_seq_len=4096, dtype=jnp.bfloat16, remat=True)
-        batch, seq, chunk = 4, 4096, 512
-    else:  # smoke path
-        cfg = llama.LlamaConfig.tiny(num_layers=2, max_seq_len=256)
-        batch, seq, chunk = 2, 256, None
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"step_profile: no TPU (platform "
+                         f"{dev.platform!r}); nothing to measure")
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, hidden_size=1536, intermediate_size=4096,
+        num_layers=20, num_heads=12, num_kv_heads=12,
+        max_seq_len=4096, dtype=jnp.bfloat16, remat=True)
+    batch, seq, chunk = 4, 4096, 512
 
     step = train.make_train_step(cfg, seq_chunk=chunk)
     state = jax.jit(lambda k: train.init_train_state(k, cfg))(
@@ -135,10 +134,7 @@ def main():
     # profiler summary tables (host spans + device op/category tables
     # from the jax.profiler trace) — the per-XLA-op ranking that feeds
     # the MFU residual accounting in PERF_NOTES.md
-    try:
-        profiled_summary(step, hold["s"], tokens)
-    except Exception as e:     # analysis extra; never kill the timings
-        print(f"profiler summary skipped: {type(e).__name__}: {e}")
+    profiled_summary(step, hold["s"], tokens)
 
 
 def profiled_summary(step, state, tokens, record_steps=2):
