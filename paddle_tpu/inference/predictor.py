@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import hooks as _obs
+from ..observability.spans import SpanTotals
 from ..serving.resilience import fault_point as _fault_point
 
 
@@ -332,6 +333,13 @@ def create_predictor(config: Config, layer=None) -> Predictor:
 
 
 # ---------------- continuous-batching decode engine ----------------
+
+def _named_jit(f, name: str, **jit_kw):
+    """``jax.jit(f)`` under a name that says what the program is: it
+    shows as ``jit_<name>`` on the ``XLA Modules`` line of a trace."""
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f, **jit_kw)
+
 
 class InFlightStep:
     """One dispatched-but-uncommitted decode/verify program (ISSUE 12).
@@ -713,7 +721,10 @@ class ContinuousBatchingEngine:
         # chunk handles — committed in dispatch order by commit_inflight
         self._inflight: Optional[InFlightStep] = None
         self._inflight_chunks: List[Dict] = []
-        self._fence_ns = 0      # device-wait accumulated since last take
+        # span totals and counters of this engine (and of the scheduler
+        # that owns it); they come out in stats()
+        self.spans = SpanTotals()
+        self._launched: set = set()     # program keys already called once
         self._next_rid = 0
         self._steps = 0
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
@@ -1050,7 +1061,8 @@ class ContinuousBatchingEngine:
                     return (nxt, raw), paged
                 return nxt, paged
 
-            self._decode_fn = jax.jit(f, donate_argnums=(2,))
+            self._decode_fn = _named_jit(f, "paged_decode",
+                                         donate_argnums=(2,))
         return self._decode_fn
 
     def _chunk_fn(self, ctx_cap: int, width: int):
@@ -1093,7 +1105,9 @@ class ContinuousBatchingEngine:
                 if self.mesh is not None:
                     f = self._tp_map(f, ("params", "rep", "pool", "rep",
                                          "rep", "rep"))
-            self._chunk_fns[key] = jax.jit(f, donate_argnums=(2,))
+            self._chunk_fns[key] = _named_jit(
+                f, f"prefill_chunk_c{ctx_cap}_w{width}",
+                donate_argnums=(2,))
         return self._chunk_fns[key]
 
     def _spec_fn(self, ctx_cap: int, T: int):
@@ -1149,7 +1163,8 @@ class ContinuousBatchingEngine:
                 # corrected residual draws from p with the draft zeroed
                 return logits.astype(jnp.float32), paged
 
-            self._spec_fns[key] = jax.jit(f, donate_argnums=(2,))
+            self._spec_fns[key] = _named_jit(
+                f, f"spec_verify_c{ctx_cap}_t{T}", donate_argnums=(2,))
         return self._spec_fns[key]
 
     # ---- draft-model + tree speculation programs (ISSUE 20) ----
@@ -1178,7 +1193,8 @@ class ContinuousBatchingEngine:
                                      "batch", "batch", "batch"),
                                  out_kinds=("pool",),
                                  cache=self.draft_cache)
-            self._draft_fns[key] = jax.jit(f, donate_argnums=(2,))
+            self._draft_fns[key] = _named_jit(
+                f, f"draft_catchup_c{ctx_cap}_w{T}", donate_argnums=(2,))
         return self._draft_fns[key]
 
     def _draft_decode(self):
@@ -1202,7 +1218,8 @@ class ContinuousBatchingEngine:
                 f = self._tp_map(f, ("params", "batch", "pool",
                                      "batch", "batch", "batch"),
                                  cache=self.draft_cache)
-            self._draft_dec_fn = jax.jit(f, donate_argnums=(2,))
+            self._draft_dec_fn = _named_jit(f, "draft_decode",
+                                            donate_argnums=(2,))
         return self._draft_dec_fn
 
     def _tree_fn(self, ctx_cap: int, T: int):
@@ -1249,7 +1266,8 @@ class ContinuousBatchingEngine:
                     kinds += ["adapters", "batch"]
                 f = self._tp_map(f, tuple(kinds),
                                  out_kinds=("rep", "rows"))
-            self._tree_fns[key] = jax.jit(f)
+            self._tree_fns[key] = _named_jit(
+                f, f"tree_verify_c{ctx_cap}_t{T}")
         return self._tree_fns[key]
 
     def _tree_commit_fn(self, T: int):
@@ -1272,8 +1290,24 @@ class ContinuousBatchingEngine:
                 f = self._tp_map(f, ("pool", "rows", "batch", "batch",
                                      "batch", "batch"),
                                  out_kinds=("pool",))
-            self._tree_commit_fns[T] = jax.jit(f, donate_argnums=(0,))
+            self._tree_commit_fns[T] = _named_jit(
+                f, f"tree_commit_t{T}", donate_argnums=(0,))
         return self._tree_commit_fns[T]
+
+    def _launch(self, fn, args, kind: str, ctx_cap: int = 0,
+                width: int = 0):
+        """Call a jitted program. The first call for a key traces,
+        lowers and compiles it (or loads it from the cache): that one
+        runs under ``engine.build_program``, which marks the step that
+        paid for it."""
+        key = (kind, ctx_cap, width)
+        if key in self._launched:
+            return fn(*args)
+        with self.spans.span("engine.build_program", kind=kind,
+                             ctx_cap=ctx_cap, width=width):
+            out = fn(*args)
+        self._launched.add(key)
+        return out
 
     # ---- scheduling ----
     def _install_slot(self, slot: int, req: GenerationRequest):
@@ -1419,6 +1453,8 @@ class ContinuousBatchingEngine:
             # counter already measures tokens actually forwarded
             _obs.serving_admitted(1, seq.size)
             _obs.serving_prefix(int(shared), seq.size - int(shared))
+            self.spans.count("prompt_tokens_total", seq.size)
+            self.spans.count("prefix_hit_tokens_total", int(shared))
         return True
 
     def swap_candidate(self, req: GenerationRequest) -> bool:
@@ -1579,39 +1615,44 @@ class ContinuousBatchingEngine:
         # commits nothing (neither ``done`` nor a sampled token)
         _fault_point("prefill_chunk")
         t0 = _obs.generate_begin()
-        args = [self.params, jnp.asarray(chunk), cache.pool,
-                jnp.asarray(cache.block_tables[slot]), jnp.int32(done),
-                jnp.int32(take)]
-        if self.adapters is not None:
-            args += [self.adapters.arrays,
-                     jnp.asarray(self._aslot[slot:slot + 1])]
-        logits, cache.pool = self._chunk_fn(ctx_cap, width)(*args)
-        samp = rawmax = None
-        if done + take >= S and not req.tokens:
-            # final chunk of a fresh admission (or a mid-prefill
-            # victim's resume): the first token comes from these
-            # logits. Keep the sample on device; fetch at commit.
-            lg = logits[0]
-            if self.constraints and req.constraint is not None:
-                # the FIRST token obeys the grammar too: the slot mask
-                # (installed at admission from the DFA start state)
-                # applies before the argmax/categorical, same rule as
-                # the decode program's in-graph where. The UNMASKED
-                # argmax rides along so the violation-avoided counter
-                # covers this commit path like the decode one.
-                rawmax = jnp.argmax(lg)
-                lg = jnp.where(jnp.asarray(self._cmask[slot]), lg,
-                               -jnp.inf)
-            if self.temperature == 0.0:
-                samp = jnp.argmax(lg)
-            else:
-                self._key, k = jax.random.split(self._key)
-                samp = jax.random.categorical(
-                    k, lg / self.temperature)
-        self._inflight_chunks.append(
-            {"slot": slot, "req": req, "seat": int(self._seat[slot]),
-             "take": take, "t0": t0, "logits": logits, "samp": samp,
-             "rawmax": rawmax, "ttr": _obs.serving_trace_now()})
+        with self.spans.span("engine.dispatch", kind="chunk"):
+            args = [self.params, jnp.asarray(chunk), cache.pool,
+                    jnp.asarray(cache.block_tables[slot]),
+                    jnp.int32(done), jnp.int32(take)]
+            if self.adapters is not None:
+                args += [self.adapters.arrays,
+                         jnp.asarray(self._aslot[slot:slot + 1])]
+            logits, cache.pool = self._launch(
+                self._chunk_fn(ctx_cap, width), args, "chunk", ctx_cap,
+                width)
+            samp = rawmax = None
+            if done + take >= S and not req.tokens:
+                # final chunk of a fresh admission (or a mid-prefill
+                # victim's resume): the first token comes from these
+                # logits. Keep the sample on device; fetch at commit.
+                lg = logits[0]
+                if self.constraints and req.constraint is not None:
+                    # the FIRST token obeys the grammar too: the slot
+                    # mask (installed at admission from the DFA start
+                    # state) applies before the argmax/categorical,
+                    # same rule as the decode program's in-graph where.
+                    # The UNMASKED argmax rides along so the
+                    # violation-avoided counter covers this commit path
+                    # like the decode one.
+                    rawmax = jnp.argmax(lg)
+                    lg = jnp.where(jnp.asarray(self._cmask[slot]), lg,
+                                   -jnp.inf)
+                if self.temperature == 0.0:
+                    samp = jnp.argmax(lg)
+                else:
+                    self._key, k = jax.random.split(self._key)
+                    samp = jax.random.categorical(
+                        k, lg / self.temperature)
+            self._inflight_chunks.append(
+                {"slot": slot, "req": req,
+                 "seat": int(self._seat[slot]), "take": take, "t0": t0,
+                 "logits": logits, "samp": samp, "rawmax": rawmax,
+                 "ttr": _obs.serving_trace_now()})
         return width
 
     def _commit_chunk(self, h: Dict) -> int:
@@ -1621,15 +1662,15 @@ class ContinuousBatchingEngine:
         next token is already known and is fed back into decode
         instead of re-sampling (the resumed request must not fork)."""
         slot, req, take = h["slot"], h["req"], h["take"]
-        cache = self.cache
-        # both obs calls fence the chunk logits when a sink is active —
-        # that wait is device time, not exposed host time
-        t_f0 = time.perf_counter_ns()
-        if self.fused:
-            _obs.serving_fused_latency("chunk_flash_attn", h["t0"],
-                                       h["logits"])
-        _obs.serving_prefill_chunk(h["t0"], h["logits"], take)
-        self._fence_ns += time.perf_counter_ns() - t_f0
+        with self.spans.span("engine.wait", kind="chunk"):
+            # both obs calls fence the chunk logits when a sink is
+            # active — that wait is device time, not exposed host time
+            if self.fused:
+                _obs.serving_fused_latency("chunk_flash_attn", h["t0"],
+                                           h["logits"])
+            _obs.serving_prefill_chunk(h["t0"], h["logits"], take)
+            # a final chunk's first token: the ONE device→host fetch
+            first = int(h["samp"]) if h["samp"] is not None else None
         ent = self._pending.get(slot)
         if (ent is None or ent[0] is not req
                 or int(self._seat[slot]) != h["seat"]):
@@ -1639,6 +1680,15 @@ class ContinuousBatchingEngine:
             # between dispatch and commit: commit nothing; the fresh
             # admission replays the span through its own chunks
             return 0
+        with self.spans.span("engine.commit", rows=1):
+            return self._commit_chunk_host(h, ent, first)
+
+    def _commit_chunk_host(self, h: Dict, ent: List, first) -> int:
+        """The host bookkeeping of :meth:`_commit_chunk`, after the
+        read: the ``done`` cursor, prefix registration, the first
+        token."""
+        slot, req, take = h["slot"], h["req"], h["take"]
+        cache = self.cache
         done = ent[2] + take
         _obs.serving_trace_span(
             req, "prefill_chunk", h.get("ttr", 0),
@@ -1658,9 +1708,6 @@ class ContinuousBatchingEngine:
             # as in the uninterrupted run).
             self._last[slot] = np.int32(req.tokens[-1])
         else:
-            t_f = time.perf_counter_ns()
-            first = int(h["samp"])          # the ONE device→host fetch
-            self._fence_ns += time.perf_counter_ns() - t_f
             self._last[slot] = first
             # violation check against the PRE-advance slot mask with
             # the UNMASKED argmax, mirroring the decode commit — read
@@ -1879,13 +1926,6 @@ class ContinuousBatchingEngine:
         that a commit fence is pending."""
         return self._inflight is not None or bool(self._inflight_chunks)
 
-    def take_fence_ns(self) -> int:
-        """Device-wait nanoseconds accumulated by commit fences since
-        the last call — the scheduler's host-vs-device attribution
-        input for the ``host_overhead_fraction`` gauge."""
-        ns, self._fence_ns = self._fence_ns, 0
-        return ns
-
     def decode_dispatch(self, mask) -> Optional[InFlightStep]:
         """DISPATCH half of :meth:`decode_step`: launch the jitted
         ragged decode program for the ``mask`` slots and return the
@@ -1906,27 +1946,27 @@ class ContinuousBatchingEngine:
         # so a fault at either recovers by journal replay
         _fault_point("decode_step")
         t0f = _obs.generate_begin() if self.fused else 0
-        self._key, k = jax.random.split(self._key)
-        args = [self.params, jnp.asarray(self._last), cache.pool,
-                jnp.asarray(cache.block_tables),
-                jnp.asarray(cache.lengths),
-                jnp.asarray(mask), k]
-        if self.adapters is not None:
-            args += [self.adapters.arrays, jnp.asarray(self._aslot)]
-        if self.constraints:
-            if self._cmask_dirty or self._cmask_dev is None:
-                self._cmask_dev = jnp.asarray(self._cmask)
-                self._cmask_dirty = False
-            args += [self._cmask_dev]
-        out, cache.pool = self._decode()(*args)
-        raw = None
-        if self.constraints:
-            out, raw = out
-        _fault_point("dispatch")
-        self._inflight = InFlightStep("decode", mask, self._rids.copy(),
-                                      self._seat.copy(), out, t0f=t0f,
-                                      raw=raw,
-                                      ttr=_obs.serving_trace_now())
+        with self.spans.span("engine.dispatch", kind="decode"):
+            self._key, k = jax.random.split(self._key)
+            args = [self.params, jnp.asarray(self._last), cache.pool,
+                    jnp.asarray(cache.block_tables),
+                    jnp.asarray(cache.lengths),
+                    jnp.asarray(mask), k]
+            if self.adapters is not None:
+                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+            if self.constraints:
+                if self._cmask_dirty or self._cmask_dev is None:
+                    self._cmask_dev = jnp.asarray(self._cmask)
+                    self._cmask_dirty = False
+                args += [self._cmask_dev]
+            out, cache.pool = self._launch(self._decode(), args, "decode")
+            raw = None
+            if self.constraints:
+                out, raw = out
+            _fault_point("dispatch")
+            self._inflight = InFlightStep(
+                "decode", mask, self._rids.copy(), self._seat.copy(),
+                out, t0f=t0f, raw=raw, ttr=_obs.serving_trace_now())
         return self._inflight
 
     def _decode_commit(self, h: InFlightStep) -> int:
@@ -1938,21 +1978,29 @@ class ContinuousBatchingEngine:
         request changed since dispatch (preempt + readmit) is skipped
         via the rid snapshot; the dropped token is re-decoded
         greedy-identically on resume."""
-        cache = self.cache
         # resilience sites: the commit seam, then the device→host
         # transfer — host state commits only after both, so a fault at
         # either leaves the request handles at the previous step's
         # committed state (the supervisor's recovery contract)
         _fault_point("commit")
-        # the device-wait window OPENS before the observability calls:
+        # the device-wait span OPENS before the observability calls:
         # serving_fused_latency fences h.out when metrics are on, and
         # charging that wait to exposed host time would inflate the
         # host_overhead_fraction gauge exactly when it is emitted
-        t_f = time.perf_counter_ns()
-        _obs.serving_fused_latency("decode_rope_attn", h.t0f, h.out)
-        _fault_point("transfer")
-        nxt = np.asarray(h.out)
-        self._fence_ns += time.perf_counter_ns() - t_f
+        with self.spans.span("engine.wait", kind="decode"):
+            _obs.serving_fused_latency("decode_rope_attn", h.t0f, h.out)
+            _fault_point("transfer")
+            nxt = np.asarray(h.out)
+            raw = np.asarray(h.raw) if self.constraints else None
+        rows = int(h.mask.sum())
+        with self.spans.span("engine.commit", rows=rows):
+            return self._decode_commit_host(h, nxt, raw, rows)
+
+    def _decode_commit_host(self, h: InFlightStep, nxt, raw,
+                            rows: int) -> int:
+        """The host bookkeeping of :meth:`_decode_commit`, after the
+        read."""
+        cache = self.cache
         valid = (h.mask & (self._rids == h.rids) & (h.rids >= 0)
                  & (self._seat == h.seats))
         slots = np.flatnonzero(valid)
@@ -1988,7 +2036,6 @@ class ContinuousBatchingEngine:
                 # would have violated the grammar (each one is a saved
                 # parse failure). Runs BEFORE retirement clears slots.
                 t0m = time.perf_counter_ns()
-                raw = np.asarray(h.raw)
                 viol = crows = 0
                 for s, t in zip(sl, tl):
                     creq = self._slots[s]
@@ -2014,7 +2061,7 @@ class ContinuousBatchingEngine:
         # rows that passed the seat guard and actually committed —
         # identical in sync mode (nothing re-seats between dispatch and
         # commit there), honest under overlap preemption races
-        _obs.serving_step(int(h.mask.sum()), self.max_batch,
+        _obs.serving_step(rows, self.max_batch,
                           alloc.num_used, alloc.num_usable)
         if self._dp_axis is not None:
             # per-dp-shard row load of the DISPATCHED program: slot s
@@ -2040,7 +2087,8 @@ class ContinuousBatchingEngine:
                   else self._spec_commit(h))
         fence = getattr(self.cache, "fence_swaps", None)
         if fence is not None:
-            fence()
+            with self.spans.span("engine.wait", kind="swap"):
+                fence()
         return n
 
     def decode_step(self, mask) -> int:
@@ -2175,10 +2223,13 @@ class ContinuousBatchingEngine:
                 catchup += c
             ctx_cap = dc.ctx_cap_pages(dc.pages_for(
                 int(dc.lengths[cmask].max()))) * dc.page_size
-            dc.pool = self._draft_catchup_fn(ctx_cap, W)(
-                self.draft_params, jnp.asarray(chunk), dc.pool,
-                jnp.asarray(dc.block_tables), jnp.asarray(dc.lengths),
-                jnp.asarray(cmask))
+            with self.spans.span("engine.dispatch", kind="draft_catchup"):
+                dc.pool = self._launch(
+                    self._draft_catchup_fn(ctx_cap, W),
+                    [self.draft_params, jnp.asarray(chunk), dc.pool,
+                     jnp.asarray(dc.block_tables),
+                     jnp.asarray(dc.lengths), jnp.asarray(cmask)],
+                    "draft_catchup", ctx_cap, W)
             dc.lengths[cmask] += adv[cmask]
         # --- autoregressive draft loop (speculative feeds advance
         # only the LOCAL run-length; dc.lengths stays the valid prefix)
@@ -2196,11 +2247,14 @@ class ContinuousBatchingEngine:
         cands = {s: [] for s in rows} if tree_w else None
         dec = self._draft_decode()
         for i in range(k):
-            logits, dc.pool = dec(
-                self.draft_params, jnp.asarray(x), dc.pool,
-                jnp.asarray(dc.block_tables), jnp.asarray(run_len),
-                jnp.asarray(amask))
-            logits = np.asarray(logits)
+            with self.spans.span("engine.dispatch", kind="draft"):
+                logits, dc.pool = self._launch(
+                    dec, [self.draft_params, jnp.asarray(x), dc.pool,
+                          jnp.asarray(dc.block_tables),
+                          jnp.asarray(run_len), jnp.asarray(amask)],
+                    "draft")
+            with self.spans.span("engine.wait", kind="draft"):
+                logits = np.asarray(logits)
             run_len[amask] += 1
             for s in rows:
                 z = logits[s].astype(np.float64)
@@ -2347,18 +2401,19 @@ class ContinuousBatchingEngine:
             int(cache.lengths[mask].max()))) * cache.page_size
         _fault_point("verify_step")
         t0 = _obs.generate_begin()
-        args = [self.params, jnp.asarray(chunk), cache.pool,
-                jnp.asarray(cache.block_tables),
-                jnp.asarray(cache.lengths), jnp.asarray(mask)]
-        if self.adapters is not None:
-            args += [self.adapters.arrays, jnp.asarray(self._aslot)]
-        out, cache.pool = self._spec_fn(ctx_cap, T)(*args)
-        _fault_point("dispatch")
-        self._inflight = InFlightStep("spec", mask, self._rids.copy(),
-                                      self._seat.copy(), out,
-                                      drafts=drafts, dlen=dlen, t0=t0,
-                                      ttr=_obs.serving_trace_now(),
-                                      qs=qs)
+        with self.spans.span("engine.dispatch", kind="spec"):
+            args = [self.params, jnp.asarray(chunk), cache.pool,
+                    jnp.asarray(cache.block_tables),
+                    jnp.asarray(cache.lengths), jnp.asarray(mask)]
+            if self.adapters is not None:
+                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+            out, cache.pool = self._launch(
+                self._spec_fn(ctx_cap, T), args, "spec", ctx_cap, T)
+            _fault_point("dispatch")
+            self._inflight = InFlightStep(
+                "spec", mask, self._rids.copy(), self._seat.copy(), out,
+                drafts=drafts, dlen=dlen, t0=t0,
+                ttr=_obs.serving_trace_now(), qs=qs)
         return self._inflight
 
     def _spec_commit(self, h: InFlightStep) -> int:
@@ -2367,20 +2422,26 @@ class ContinuousBatchingEngine:
         Rollback of rejected draft KV is pure host bookkeeping (see
         :meth:`spec_step`); slots whose request changed since dispatch
         are skipped via the rid snapshot."""
+        _fault_point("commit")
+        # device-wait span opens before the (fencing) obs call —
+        # same host-attribution rule as _decode_commit
+        with self.spans.span("engine.wait", kind="spec"):
+            if self.fused:
+                _obs.serving_fused_latency("verify_flash_attn", h.t0,
+                                           h.out)
+            _fault_point("transfer")
+            out = np.asarray(h.out)   # (B, T) greedy targets — or, under
+            #                           sampled speculation, (B, T, V)
+            #                           verify logits for rejection sampling
+        t1 = time.perf_counter_ns()        # device fence: verify done
+        with self.spans.span("engine.commit", rows=int(h.mask.sum())):
+            return self._spec_commit_host(h, out, t1)
+
+    def _spec_commit_host(self, h: InFlightStep, out, t1: int) -> int:
+        """The host bookkeeping of :meth:`_spec_commit`, after the
+        read."""
         cache = self.cache
         mask, drafts, dlen = h.mask, h.drafts, h.dlen
-        _fault_point("commit")
-        # device-wait window opens before the (fencing) obs call —
-        # same host-attribution rule as _decode_commit
-        t_f = time.perf_counter_ns()
-        if self.fused:
-            _obs.serving_fused_latency("verify_flash_attn", h.t0, h.out)
-        _fault_point("transfer")
-        out = np.asarray(h.out)   # (B, T) greedy targets — or, under
-        #                           sampled speculation, (B, T, V)
-        #                           verify logits for rejection sampling
-        t1 = time.perf_counter_ns()        # device fence: verify done
-        self._fence_ns += t1 - t_f
         from ..serving.speculative import (longest_accepted_prefix,
                                            rejection_sample_tokens)
         sampled = self.temperature != 0.0
@@ -2492,18 +2553,20 @@ class ContinuousBatchingEngine:
             int(cache.lengths[mask].max()))) * cache.page_size
         _fault_point("tree_verify")
         t0 = _obs.generate_begin()
-        args = [self.params, jnp.asarray(chunk), cache.pool,
-                jnp.asarray(cache.block_tables),
-                jnp.asarray(cache.lengths), jnp.asarray(mask),
-                jnp.asarray(depths), jnp.asarray(anc)]
-        if self.adapters is not None:
-            args += [self.adapters.arrays, jnp.asarray(self._aslot)]
-        out, rows = self._tree_fn(ctx_cap, T)(*args)
-        _fault_point("dispatch")
-        self._inflight = InFlightStep(
-            "tree", mask, self._rids.copy(), self._seat.copy(), out,
-            drafts=trees, t0=t0, ttr=_obs.serving_trace_now(),
-            rows=rows)
+        with self.spans.span("engine.dispatch", kind="tree"):
+            args = [self.params, jnp.asarray(chunk), cache.pool,
+                    jnp.asarray(cache.block_tables),
+                    jnp.asarray(cache.lengths), jnp.asarray(mask),
+                    jnp.asarray(depths), jnp.asarray(anc)]
+            if self.adapters is not None:
+                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+            out, rows = self._launch(self._tree_fn(ctx_cap, T), args,
+                                     "tree", ctx_cap, T)
+            _fault_point("dispatch")
+            self._inflight = InFlightStep(
+                "tree", mask, self._rids.copy(), self._seat.copy(), out,
+                drafts=trees, t0=t0, ttr=_obs.serving_trace_now(),
+                rows=rows)
         return self._inflight
 
     def _tree_commit(self, h: InFlightStep) -> int:
@@ -2518,17 +2581,34 @@ class ContinuousBatchingEngine:
         host bookkeeping. Rejected nodes were never placed, so
         rejection needs NO rollback of any kind; guard-skipped slots
         pass path_len 0 and their nodes route to the trash page."""
+        _fault_point("commit")
+        with self.spans.span("engine.wait", kind="tree"):
+            if self.fused:
+                _obs.serving_fused_latency("verify_flash_attn", h.t0,
+                                           h.out)
+            _fault_point("transfer")
+            out = np.asarray(h.out)     # (B, T) argmax — or, sampled,
+            #                             (B, T, V) per-node verify logits
+        t1 = time.perf_counter_ns()
+        rows = int(h.mask.sum())
+        # the placement program sits between the two halves of the
+        # bookkeeping, under a dispatch span of its own
+        with self.spans.span("engine.commit", rows=rows):
+            plans, place = self._tree_pick_paths(h, out)
+        with self.spans.span("engine.dispatch", kind="tree_commit"):
+            self.cache.pool = self._launch(
+                self._tree_commit_fn(self._tree_T),
+                [self.cache.pool, h.rows] + [jnp.asarray(a) for a in place],
+                "tree_commit", width=self._tree_T)
+        with self.spans.span("engine.commit", rows=rows):
+            return self._tree_commit_host(h, out, plans, t1)
+
+    def _tree_pick_paths(self, h: InFlightStep, out):
+        """Each row's accepted root path: the commit plans and the
+        placement program's host inputs (block tables, pre-commit
+        lengths, path nodes, path lengths)."""
         cache = self.cache
         mask = h.mask
-        _fault_point("commit")
-        t_f = time.perf_counter_ns()
-        if self.fused:
-            _obs.serving_fused_latency("verify_flash_attn", h.t0, h.out)
-        _fault_point("transfer")
-        out = np.asarray(h.out)     # (B, T) argmax — or, sampled,
-        #                             (B, T, V) per-node verify logits
-        t1 = time.perf_counter_ns()
-        self._fence_ns += t1 - t_f
         from ..serving.speculative import (longest_accepted_path,
                                            tree_rejection_sample)
         sampled = self.temperature != 0.0
@@ -2566,13 +2646,17 @@ class ContinuousBatchingEngine:
             path_len[slot] = len(path)
             plans.append((slot, req, t, toks, a))
         # device placement FIRST, against the pre-commit tables and
-        # lengths (retirement below resets them for finished rows —
-        # their already-placed rows die with their freed pages, the
+        # lengths (retirement afterwards resets them for finished rows
+        # — their already-placed rows die with their freed pages, the
         # contract every release relies on)
-        cache.pool = self._tree_commit_fn(T)(
-            cache.pool, h.rows, jnp.asarray(cache.block_tables),
-            jnp.asarray(base_len), jnp.asarray(path_nodes),
-            jnp.asarray(path_len))
+        return plans, (cache.block_tables, base_len, path_nodes, path_len)
+
+    def _tree_commit_host(self, h: InFlightStep, out, plans,
+                          t1: int) -> int:
+        """The host bookkeeping of :meth:`_tree_commit`, after the
+        placement program is launched."""
+        cache = self.cache
+        sampled = self.temperature != 0.0
         n_slots = committed = drafted = accepted = 0
         paths = []
         for slot, req, t, toks, a in plans:
@@ -2712,4 +2796,7 @@ class ContinuousBatchingEngine:
         if self.spec_tree is not None:
             s["tree_width"], s["tree_depth"] = self.spec_tree
             s["tree_nodes"] = self._tree_T - 1
+        # the step's phases as totals, and the counters fed where the
+        # work happens (observability/spans.py)
+        s.update(self.spans.snapshot())
         return s

@@ -110,7 +110,7 @@ def make_train_step(cfg, mesh: Optional[Mesh] = None, *,
         return mdl.loss_fn(params, tokens, cfg, mesh_axes,
                            seq_chunk=seq_chunk)
 
-    def step_fn(state: TrainState, tokens: jax.Array):
+    def train_step(state: TrainState, tokens: jax.Array):
         lv, grads = jax.value_and_grad(loss)(state.params, tokens)
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
@@ -134,12 +134,12 @@ def make_train_step(cfg, mesh: Optional[Mesh] = None, *,
         return new_state, {"loss": lv, "grad_norm": gnorm}
 
     if mesh is None:
-        return jax.jit(step_fn, donate_argnums=(0,))
+        return jax.jit(train_step, donate_argnums=(0,))
 
     st_sh = state_shardings(mesh, cfg, mdl)
     data_spec = P(mesh_axes["data"], mesh_axes["cp"])
     tok_sh = NamedSharding(mesh, data_spec)
     rep = NamedSharding(mesh, P())
-    return jax.jit(step_fn, donate_argnums=(0,),
+    return jax.jit(train_step, donate_argnums=(0,),
                    in_shardings=(st_sh, tok_sh),
                    out_shardings=(st_sh, {"loss": rep, "grad_norm": rep}))

@@ -202,6 +202,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, out_dtype=None,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="flash_fwd", metadata={"kernel": "flash_fwd"},
     )(q, k, v)
     return out, lse
 
@@ -359,6 +360,7 @@ def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="flash_bwd_dkv", metadata={"kernel": "flash_bwd_dkv"},
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -377,6 +379,7 @@ def _bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret_mode(),
+        name="flash_bwd_dq", metadata={"kernel": "flash_bwd_dq"},
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

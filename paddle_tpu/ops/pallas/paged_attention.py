@@ -242,6 +242,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * HK, rep, D), q.dtype),
         interpret=_fa._interpret_mode(),
+        name="paged_attention", metadata={"kernel": "paged_attention"},
     )(*inputs)
     return out.reshape(B, HK, rep, D).reshape(B, H, D)
 

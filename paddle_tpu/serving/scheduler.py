@@ -130,10 +130,14 @@ class ServingScheduler:
         #: committed units (tokens/slots) of the last step — the
         #: busy-spin detector's input alongside last_plan
         self.last_committed = 0
+        #: the engine's span totals: the step's phases land beside the
+        #: engine's own (dispatch, wait, commit) and come out in stats()
+        self.spans = engine.spans
         #: host-overhead telemetry mirrors (readable without the
         #: metrics registry — the bench rider's source): fraction of
         #: the last step's wall time spent on EXPOSED host work (host
-        #: bookkeeping not hidden under an in-flight device program)
+        #: bookkeeping not hidden under an in-flight device program),
+        #: derived from the span totals in :meth:`step`
         self.last_host_frac: Optional[float] = None
         self.host_frac_ema: Optional[float] = None
         self.idle_fences_total = 0
@@ -376,8 +380,10 @@ class ServingScheduler:
                 # clamp covers a victim preempted and re-admitted
                 # within this same pass (its requeue stamp postdates
                 # ``now``) — that wait is zero, not negative.
-                _obs.serving_queue_wait(
-                    max(0.0, now - req.enqueued_at), prio)
+                wait = max(0.0, now - req.enqueued_at)
+                _obs.serving_queue_wait(wait, prio)
+                self.spans.count("queue_wait_ns_total", wait * 1e9)
+                self.spans.count("admissions_total", 1)
 
     def _plan(self, reserved: int = 0) -> StepPlan:
         eng = self.engine
@@ -513,49 +519,58 @@ class ServingScheduler:
                 "the scheduler attached — submit through "
                 "ServingScheduler.submit so priority admission is "
                 "not bypassed")
-        t_wall0 = time.perf_counter_ns()
         # host work done while a previous step is in flight on device
         # is HIDDEN (off the critical path); the same work with the
         # device idle is EXPOSED — the host_overhead_fraction gauge's
         # numerator. The synchronous path never overlaps, so all its
         # host time is exposed by construction.
         hidden = self.overlap and eng.has_inflight()
-        eng.take_fence_ns()                 # reset the device-wait tally
-        now = self.clock()
-        self._expire_deadlines(now)
-        self._admit(now)
-        # host tier (ISSUE 10): admissions that SWAPPED IN during
-        # _admit already wrote KV bytes this step (one scatter per
-        # resume) — charge them against the step budget at the prefill
-        # rate (page_size tokens per page). A single swap-in larger
-        # than the whole budget AMORTIZES: the debt carries into later
-        # steps' reserves, so every step's (planned + reserved) stays
-        # under the ceiling and the average per-step KV-write bound
-        # the budget promises holds through swap-heavy bursts.
-        consume = getattr(eng.cache, "consume_swap_charge", None)
-        if consume is not None:
-            self._swap_debt += consume()
-        budget = self.planner.token_budget
-        reserved = (min(self._swap_debt, budget) if budget
-                    else self._swap_debt)
-        self._swap_debt -= reserved
-        plan = self._plan(reserved)
-        t_planned = time.perf_counter_ns()
-        if self.overlap:
-            # the ONE commit fence: step N's result is needed now —
-            # its sampled tokens seed step N+1's dispatch inputs
-            committed = eng.commit_inflight()
-            plan = self._trim_plan(plan)
-            self._dispatch_plan(plan)
-        else:
-            committed = self._execute_plan(plan)
-        self.last_plan = plan
-        self.last_committed = committed
-        self._steps += 1
-        t_end = time.perf_counter_ns()
-        wall = max(1, t_end - t_wall0)
-        exposed = max(0, (t_end - t_wall0) - eng.take_fence_ns()
-                      - ((t_planned - t_wall0) if hidden else 0))
+        sp = self.spans
+        step0, wait0 = sp.ns("sched.step"), sp.ns("engine.wait")
+        plan0 = sp.ns("sched.admit") + sp.ns("sched.plan")
+        with sp.span("sched.step", step=self._steps):
+            now = self.clock()
+            with sp.span("sched.admit", queued=sum(
+                    len(q) for q in self._queues.values())):
+                self._expire_deadlines(now)
+                self._admit(now)
+            with sp.span("sched.plan"):
+                # host tier (ISSUE 10): admissions that SWAPPED IN
+                # during _admit already wrote KV bytes this step (one
+                # scatter per resume) — charge them against the step
+                # budget at the prefill rate (page_size tokens per
+                # page). A single swap-in larger than the whole budget
+                # AMORTIZES: the debt carries into later steps'
+                # reserves, so every step's (planned + reserved) stays
+                # under the ceiling and the average per-step KV-write
+                # bound the budget promises holds through swap-heavy
+                # bursts.
+                consume = getattr(eng.cache, "consume_swap_charge", None)
+                if consume is not None:
+                    self._swap_debt += consume()
+                budget = self.planner.token_budget
+                reserved = (min(self._swap_debt, budget) if budget
+                            else self._swap_debt)
+                self._swap_debt -= reserved
+                plan = self._plan(reserved)
+            planned_ns = sp.ns("sched.admit") + sp.ns("sched.plan") - plan0
+            if self.overlap:
+                # the ONE commit fence: step N's result is needed now —
+                # its sampled tokens seed step N+1's dispatch inputs
+                committed = eng.commit_inflight()
+                with sp.span("sched.plan"):
+                    plan = self._trim_plan(plan)
+                self._dispatch_plan(plan)
+            else:
+                committed = self._execute_plan(plan)
+            self.last_plan = plan
+            self.last_committed = committed
+            self._steps += 1
+        # the step less device-wait less hidden planning, from the span
+        # totals: no second set of stamps
+        wall = max(1, sp.ns("sched.step") - step0)
+        exposed = max(0, wall - (sp.ns("engine.wait") - wait0)
+                      - (planned_ns if hidden else 0))
         frac = min(1.0, exposed / wall)
         self.last_host_frac = frac
         self.host_frac_ema = (frac if self.host_frac_ema is None

@@ -105,6 +105,18 @@ REQUIRED = {
         ("_obs.serving_tree_verify(", 1),
         ('_fault_point("draft_propose")', 1),
         ('_fault_point("tree_verify")', 1),
+        # the serving step's spans on the profiler's clock (ISSUE 26):
+        # one dispatch per program launched (chunk, decode, verify,
+        # tree verify and its placement, draft catch-up and decode),
+        # one wait per place the host blocks on a device value, one
+        # commit per read, and the first call of each program key
+        ('.span("engine.dispatch"', 7),
+        ('.span("engine.wait"', 6),
+        ('.span("engine.commit"', 5),
+        ('.span("engine.build_program"', 1),
+        # the counters fed where the engine already knows the numbers
+        ('.count("prompt_tokens_total"', 1),
+        ('.count("prefix_hit_tokens_total"', 1),
     ],
     "paddle_tpu/observability/hooks.py": [
         # the ISSUE 20 hook families themselves: the predictor entries
@@ -139,6 +151,13 @@ REQUIRED = {
         # cross-lifecycle stitching survives
         ("_obs.serving_trace_submit(", 1),
         ("_obs.serving_trace_enqueued(", 2),
+        # the step's own spans (ISSUE 26): host_overhead_fraction is
+        # derived from their totals, so dropping one blinds it too
+        ('.span("sched.step"', 1),
+        ('.span("sched.admit"', 1),
+        ('.span("sched.plan"', 2),            # plan, and trim under overlap
+        ('.count("queue_wait_ns_total"', 1),
+        ('.count("admissions_total"', 1),
     ],
     "paddle_tpu/serving/resilience.py": [
         # fault-tolerant serving (ISSUE 8): injected + real failure
