@@ -7,22 +7,38 @@ HBM is sized by TOKENS IN FLIGHT instead of ``batch * longest_request``
 (reference: block_multi_head_attention_kernel.cu; TPU-native design:
 Ragged Paged Attention, arxiv 2604.15464 / vLLM block tables).
 
-Two implementations with IDENTICAL semantics:
+Two implementations of one contract:
 
-- :func:`paged_attention_kernel` — Pallas TPU kernel: the block table
-  feeds the K/V BlockSpec index maps via scalar prefetch, so the page
-  gather happens in the memory pipeline (no materialized contiguous
-  copy). The grid is RAGGED: a second scalar-prefetched vector of
-  per-row page counts clamps the index maps (no DMA past a row's last
-  live page) and early-outs the softmax step, so a mixed-length batch
-  pays ``Σ ceil(len_i/page)`` pages of attention work instead of
-  ``B * ppseq``. int8 pages carry PER-ROW dequant scales (the
-  cachekv-int8 tier of the dense path) and dequantize in VMEM — HBM
-  reads stay 1 byte/element.
+- :func:`paged_attention_kernel` — Pallas TPU kernel. The pools stay in
+  HBM as they are stored: in ``(P, page, HK, D)`` a page with all its
+  kv heads is one contiguous slab, and the kernel fetches slabs by page
+  id (block table in SMEM by scalar prefetch) with async copies into a
+  double-buffered VMEM scratch — no transposed or gathered copy of a
+  pool exists (where XLA pads a position's HK rows to a tile, as for 12
+  heads or 2 int8 heads, it re-tiles the pool into slabs first; for 8
+  heads, or 1 or 2 bf16 heads, no byte moves). The grid has one step
+  per request row; inside it a loop walks the row's LIVE pages in
+  groups of G pages (G from the slab size and a VMEM budget: hundreds
+  of KB of K and of V a group), the next group, or the next row's
+  first, in flight while this one is computed. A mixed-length batch
+  therefore reads and computes
+  ``Σ ceil(len_i/page)`` pages, and nothing runs for a dead page. The
+  row's whole ``(H, D)`` query block multiplies a slab's ``page*HK``
+  key rows at once; a mask keeps each query head to the rows of its own
+  kv head (GQA, MQA and MHA alike). int8 pages carry PER-ROW dequant
+  scales (the cachekv-int8 tier of the dense path) that ride the same
+  page ids and apply in VMEM — HBM reads of K and V stay 1
+  byte/element.
 - :func:`paged_attention_reference` — pure ``lax`` gather + the exact
   attention composition of ``models/generate._attn_with_cache`` (same
   einsums, f32 accumulation, -1e30 masking), so tier-1 CPU tests
   exercise the same numerics the dense decode path produces.
+
+The kernel keeps the reference's arithmetic (scores and online-softmax
+state in f32, ``p`` cast to the compute dtype for ``p @ v``) but one
+softmax step spans a group of pages, so its f32 sums run in another
+order: kernel and reference agree to ``rtol = atol = 2e-5`` in f32
+(greedy tokens equal on the engine parity tests), not bit for bit.
 
 :func:`paged_attention` dispatches: kernel on real TPU (or when forced
 via ``use_kernel=True`` — interpret mode in tests), reference elsewhere.
@@ -31,14 +47,13 @@ TENSOR-PARALLEL serving (ISSUE 7) runs this op UNCHANGED, per shard:
 inside the engine's ``shard_map`` each shard holds ``nkv/tp`` heads of
 every page (``(P, page, nkv/tp, hd)`` local pools, the same page ids
 everywhere) and its own ``nh/tp`` query heads. Attention softmax is
-per-head, so the kernel needs NO cross-shard communication — the grid
-simply has ``B * nkv/tp`` rows instead of ``B * nkv``, and the GQA
-``rep = H // HK`` grouping still holds because query and kv heads shard
-along the same head-group boundaries (``models/llama.
-validate_serving_tp`` guarantees the divisibility; the ``nkv < tp``
-replication path presents exactly one kv head per shard). Lowering of
-the sharded program is gated by ``tools/aot_validate.py --config
-serving-tp``.
+per-head, so the kernel needs NO cross-shard communication — a shard's
+slabs are simply ``nkv/tp`` heads wide, and the GQA ``rep = H // HK``
+grouping still holds because query and kv heads shard along the same
+head-group boundaries (``models/llama.validate_serving_tp`` guarantees
+the divisibility; the ``nkv < tp`` replication path presents exactly
+one kv head per shard). Lowering of the sharded program is gated by
+``tools/aot_validate.py --config serving-tp``.
 """
 from __future__ import annotations
 
@@ -47,13 +62,13 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import available, set_interpret  # noqa: F401 — gate
 from . import flash_attention as _fa
-from . import fused as _fused
 
 
 def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -113,138 +128,214 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
     return o[:, 0]                                 # (B, H, D)
 
 
-# ------------- Pallas RAGGED kernel (per-row-scale int8 tier) -------------
-#
-# The grid's column extent is the SLOT extent (ppseq pages — static
-# shapes), but per-row work is LENGTH-PROPORTIONAL (Ragged Paged
-# Attention, arxiv 2604.15464): a scalar-prefetched per-row page count
-# drives (a) the K/V index maps, which CLAMP exhausted iterations to the
-# row's last live page — the pipeline sees an unchanged block index and
-# issues no new DMA — and (b) an early-out in the softmax step, which
-# skips the dots and finalizes the output at the row's own last page.
-# A mixed-length batch therefore streams Σ ceil(len_i/page) pages of KV
-# instead of B * ppseq.
+# ---------------- Pallas kernel: rows x live page groups ----------------
 
-def _paged_kernel(bt_ref, cnt_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
-                  acc, m_sc, l_sc, *, scale, page):
-    """One (rep, D) query block vs one page of K/V; pages arrive via the
-    scalar-prefetched block-table index maps, so grid column j IS logical
-    page j of this request (online-softmax offset j*page) while j is
-    live; cnt_ref (the per-row page count) early-outs the rest. len_ref
-    is the whole (B*HK,) SMEM vector (Mosaic rank-1 block rule)."""
-    i = pl.program_id(0)
-    _fused._decode_softmax_step(q_ref[0], k_ref[0, 0], v_ref[0, 0],
-                                len_ref[i],
-                                o_ref, acc, m_sc, l_sc, scale=scale,
-                                block_k=page, num_valid=cnt_ref[i])
+# VMEM the K and V page buffers may take together, both slots of the
+# double buffer counted; a group is unrolled over at most _MAX_GROUP pages
+_KV_VMEM_BUDGET = 2 * 1024 * 1024
+_MAX_GROUP = 16
+_NO_HEAD = 1 << 30      # offset of a key row of another kv head: never live
 
 
-def _paged_kernel_rowq(bt_ref, cnt_ref, q_ref, k_ref, v_ref, ks_ref,
-                       vs_ref, len_ref, o_ref, acc, m_sc, l_sc, *,
-                       scale, page):
-    """int8-page variant: PER-ROW dequant scales ride (1, 1, page, 1)
-    VMEM blocks gathered by the same block-table index map as K/V, so
-    each cached token row dequantizes with its own scale in VMEM (the
-    self-calibrating cachekv-int8 tier of the dense decode kernel)."""
-    i = pl.program_id(0)
-    _fused._decode_softmax_step(q_ref[0], k_ref[0, 0], v_ref[0, 0],
-                                len_ref[i],
-                                o_ref, acc, m_sc, l_sc, scale=scale,
-                                block_k=page, k_scale=ks_ref[0, 0],
-                                v_scale=vs_ref[0, 0],
-                                num_valid=cnt_ref[i])
+def _pages_per_group(ppseq, page, HK, D, itemsize):
+    """Pages one step of the kernel moves and computes: as many whole
+    page slabs ``(page*HK, D)`` of K and of V as fit the VMEM budget
+    twice over (double buffer), no more than a row can hold."""
+    slab = page * HK * D * itemsize
+    return max(1, min(_KV_VMEM_BUDGET // (4 * slab), _MAX_GROUP, ppseq))
+
+
+def _in_hbm(pool):
+    """The kernel streams pages from HBM, and its roofline is HBM's. Inside
+    a compiled program XLA otherwise parks a scanned layer's K pool on
+    chip across the call (100 MiB of the v5e's 128 of VMEM), and the copies
+    then read VMEM: say where the pools are read from. The constraint
+    exists only under ``jit`` and for the compiled kernel."""
+    if _fa._interpret_mode() or not isinstance(pool, jax.core.Tracer):
+        return pool
+    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+
+
+def _paged_kernel(bt_ref, cnt_ref, len_ref, q_ref, off_ref, k_hbm, v_hbm,
+                  *rest, scale, page, G, quant):
+    """One request row per grid step; inside it a loop over the row's
+    LIVE page groups, ``ceil(cnt/G)`` of them.
+
+    The pools stay in HBM as ``(P, page*HK, D)``: a page with all its kv
+    heads is one contiguous slab. A group is up to G slabs of K and of V,
+    fetched by page id from the scalar-prefetched block table with one
+    async copy each into a double-buffered VMEM scratch; the next group
+    (of this row, or the first of the next row) is in flight while this
+    one is computed. Dead pages are neither fetched nor looped over.
+
+    A slab's key rows are ``(position, kv head)`` pairs. The row's whole
+    ``(H, D)`` query block multiplies all of them in one product per
+    page, and ``off_ref`` (``(H, page*HK)``: the position in the page
+    where query head and key row share a kv head, ``_NO_HEAD``
+    elsewhere) masks the scores of other heads together with the
+    positions at or past the row's length; a masked score's p is exactly
+    0, so each query head's softmax runs over its own kv head only.
+    Online softmax in f32 per group; p is cast to the pool's compute
+    dtype for ``p @ v`` as the reference does.
+
+    int8 pools: the per-row scales arrive as ``(1, page*HK)`` lane rows
+    by the same page ids and multiply the scores (K) and p (V) in VMEM;
+    the int8 values themselves are exact in the compute dtype.
+
+    The buffers are zeroed once, so what a partial group leaves in the
+    slots it did not fetch is zeros or an earlier page's finite data,
+    and ``p == 0`` there contributes nothing."""
+    if quant:
+        ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, slot_ref = rest
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf),
+                   (ks_hbm, ksbuf), (vs_hbm, vsbuf))
+    else:
+        o_ref, kbuf, vbuf, sem, slot_ref = rest
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf))
+    b = pl.program_id(0)
+    nrows = pl.num_programs(0)
+
+    def group_copies(row, j, slot, fn):
+        """``start`` or ``wait`` for the copies of group j of ``row``:
+        its live pages only."""
+        live = cnt_ref[row] - j * G
+        for g in range(G):
+            @pl.when(g < live)
+            def _():
+                pid = bt_ref[row, j * G + g]
+                for n, (hbm, buf) in enumerate(streams):
+                    getattr(pltpu.make_async_copy(
+                        hbm.at[pid], buf.at[slot, g], sem.at[n, slot]),
+                        fn)()
+
+    @pl.when(b == 0)
+    def _first():
+        for _, buf in streams:
+            buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        group_copies(0, 0, 0, "start")
+
+    q = q_ref[0]                                   # (H, D)
+    off = off_ref[...]                             # (H, page*HK)
+    rows = off.shape[1]
+    length = len_ref[b]
+    ngroups = pl.cdiv(cnt_ref[b], G)
+    cdt = q.dtype
+
+    def group(j, carry):
+        m, l, acc, slot = carry
+        last = j + 1 == ngroups
+        nrow = jnp.where(last, b + 1, b)
+
+        @pl.when(nrow < nrows)
+        def _():
+            group_copies(nrow, jnp.where(last, 0, j + 1), 1 - slot, "start")
+        group_copies(b, j, slot, "wait")
+
+        ss, lives = [], []
+        for g in range(G):
+            s = jax.lax.dot_general(
+                q, kbuf[slot, g].astype(cdt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quant:
+                s = s * ksbuf[slot, g][:, :rows]
+            live = (j * G + g) * page + off < length
+            ss.append(jnp.where(live, s, _fa.DEFAULT_MASK_VALUE))
+            lives.append(live)
+        m_cur = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in ss])
+        m_new = jnp.maximum(m, m_cur)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l
+        acc = acc * alpha
+        for g in range(G):
+            p = jnp.where(lives[g], jnp.exp(ss[g] - m_new), 0.0)
+            l = l + jnp.sum(p, axis=1, keepdims=True)
+            if quant:
+                p = p * vsbuf[slot, g][:, :rows]
+            acc = acc + jax.lax.dot_general(
+                p.astype(cdt), vbuf[slot, g].astype(cdt),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc, 1 - slot
+
+    H, D = q.shape
+    m, l, acc, slot = lax.fori_loop(
+        0, ngroups, group,
+        (jnp.full((H, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, D), jnp.float32), slot_ref[0]))
+    slot_ref[0] = slot
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
                            scale=None, ks_pages=None, vs_pages=None):
-    """Pallas ragged paged decode attention; same contract (and the same
-    results, bit for bit — masked pages were exact no-ops) as
+    """Pallas paged decode attention; same contract as
     :func:`paged_attention_reference` (pool layout (P, page, HK, D),
-    per-row int8 scales (P, page, HK)), but per-row attention work is
-    sized by ``ceil(length/page)`` instead of the slot extent."""
+    per-row int8 scales (P, page, HK)), with work and HBM reads sized by
+    each row's ``ceil(length/page)`` live pages. The pools are read
+    from HBM as they are stored (for the head counts of the module's
+    note, a reshape that moves no byte); only the small scales pools of
+    the int8 tier are laid out anew, as lane rows.
+
+    A group's keys enter one softmax step, so the f32 sums run in
+    another order than the reference's: the results agree to
+    ``rtol = atol = 2e-5`` in f32, not bit for bit. A row of length 0
+    attends to nothing and returns zeros."""
     B, H, D = q.shape
     P, page, HK = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     assert H % HK == 0
     rep = H // HK
     s = scale if scale is not None else 1.0 / math.sqrt(D)
     ppseq = block_tables.shape[1]
-    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
-
-    # pool -> (HK, P, page, D): kv-head leads so one grid row serves a
-    # whole GQA head group with no HBM duplication
-    kp = k_pages.transpose(2, 0, 1, 3)
-    vp = v_pages.transpose(2, 0, 1, 3)
-    qt = q.reshape(B, HK, rep, D).reshape(B * HK, rep, D)
-    lens = jnp.repeat(lengths, HK)
-    bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)  # clamp -1
-    # per-ROW live page counts (broadcast over the HK grid rows of each
-    # request); >= 1 so every row finalizes its output block
-    cnt = jnp.clip(-(-lengths // page), 1, ppseq).astype(jnp.int32)
-    cnt = jnp.repeat(cnt, HK)
-
     if (ks_pages is None) != (vs_pages is None):
         raise ValueError(
             "paged_attention: ks_pages and vs_pages must be passed "
             "together — int8 pools quantize both K and V")
     quant = ks_pages is not None
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)  # clamp -1
+    # live pages per row; >= 1 so every row has a group to finish on
+    cnt = jnp.clip(-(-lengths // page), 1, ppseq).astype(jnp.int32)
 
-    def _page_idx(i, j, bt_, cnt_):
-        # clamp exhausted iterations to the row's LAST live page: the
-        # block index is unchanged vs the previous iteration, so the
-        # pipeline skips the copy — the ragged grid's DMA early-out
-        return bt_[i // HK, jnp.minimum(j, cnt_[i] - 1)]
+    rows = page * HK
+    G = _pages_per_group(ppseq, page, HK, D, k_pages.dtype.itemsize)
+    col = np.arange(rows)
+    off = np.where(col[None, :] % HK == np.arange(H)[:, None] // rep,
+                   col[None, :] // HK, _NO_HEAD).astype(np.int32)
 
-    in_specs = [
-        pl.BlockSpec((1, rep, D), lambda i, j, bt_, cnt_: (i, 0, 0)),
-        pl.BlockSpec((1, 1, page, D),
-                     lambda i, j, bt_, cnt_:
-                     (i % HK, _page_idx(i, j, bt_, cnt_), 0, 0)),
-        pl.BlockSpec((1, 1, page, D),
-                     lambda i, j, bt_, cnt_:
-                     (i % HK, _page_idx(i, j, bt_, cnt_), 0, 0)),
-    ]
-    inputs = [bt, cnt, qt, kp, vp]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    row_block = pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0))
+    in_specs = [row_block,
+                pl.BlockSpec((H, rows), lambda b, *_: (0, 0)), hbm, hbm]
+    inputs = [bt, cnt, lengths, q, jnp.asarray(off),
+              _in_hbm(k_pages.reshape(P, rows, D)),
+              _in_hbm(v_pages.reshape(P, rows, D))]
+    scratch = [pltpu.VMEM((2, G, rows, D), k_pages.dtype),
+               pltpu.VMEM((2, G, rows, D), v_pages.dtype)]
     if quant:
-        def _scl(sc):   # (P, page, HK) -> (HK, P, page, 1)
-            return jnp.asarray(sc, jnp.float32).transpose(
-                2, 0, 1).reshape(HK, P, page, 1)
-        in_specs += [
-            pl.BlockSpec((1, 1, page, 1),
-                         lambda i, j, bt_, cnt_:
-                         (i % HK, _page_idx(i, j, bt_, cnt_), 0, 0)),
-            pl.BlockSpec((1, 1, page, 1),
-                         lambda i, j, bt_, cnt_:
-                         (i % HK, _page_idx(i, j, bt_, cnt_), 0, 0)),
-        ]
-        inputs += [_scl(ks_pages), _scl(vs_pages)]
-        kernel = functools.partial(_paged_kernel_rowq, scale=s, page=page)
-    else:
-        kernel = functools.partial(_paged_kernel, scale=s, page=page)
-    in_specs.append(pl.BlockSpec(
-        (B * HK,), lambda i, j, bt_, cnt_: (0,),
-        memory_space=pltpu.SMEM))
-    inputs.append(lens)
+        # one lane row a page, padded to whole lane tiles for the copy
+        pad = -rows % 128
+        in_specs += [any_space, any_space]
+        inputs += [jnp.pad(jnp.asarray(sc, jnp.float32).reshape(P, 1, rows),
+                           ((0, 0), (0, 0), (0, pad)))
+                   for sc in (ks_pages, vs_pages)]
+        scratch += [pltpu.VMEM((2, G, 1, rows + pad), jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((4 if quant else 2, 2)),
+                pltpu.SMEM((1,), jnp.int32)]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * HK, ppseq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rep, D),
-                               lambda i, j, bt_, cnt_: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, D), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * HK, rep, D), q.dtype),
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, scale=s, page=page, G=G,
+                          quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B,), in_specs=in_specs,
+            out_specs=row_block, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=_fa._interpret_mode(),
         name="paged_attention", metadata={"kernel": "paged_attention"},
     )(*inputs)
-    return out.reshape(B, HK, rep, D).reshape(B, H, D)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,

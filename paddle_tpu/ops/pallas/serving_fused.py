@@ -6,8 +6,8 @@ every intermediate a step writes to HBM and re-reads is tokens/s lost.
 Two fusions live here (the third — the fused page gather/scatter — is a
 plain donated XLA program in ``serving/paged_cache._pool_move``):
 
-- :func:`fused_paged_decode_attention` — the ragged paged decode kernel
-  (ops/pallas/paged_attention.py) grown to apply the query's RoPE
+- :func:`fused_paged_decode_attention` — a ragged paged decode kernel
+  (one page of one kv head a grid step) grown to apply the query's RoPE
   ROTATION IN-KERNEL next to the existing in-VMEM int8 KV dequant: the
   unfused step materializes the rotated q to HBM and re-reads it in the
   attention kernel (plus, on the reference path, a dequanted fp copy of
@@ -159,10 +159,15 @@ def fused_paged_decode_kernel(q, cos_row, sin_row, k_pages, v_pages,
                   per-row int8 dequant scales
     block_tables: (B, ppseq) int32; lengths: (B,) incl. current token
 
-    Same ragged grid, GQA head-group mapping and online-softmax step as
+    The ragged ``(B*HK, ppseq)`` grid — one page of one kv head a grid
+    step, the index maps clamped to a row's live pages, the online
+    softmax of ``fused._decode_softmax_step`` — that the unfused
     :func:`~paddle_tpu.ops.pallas.paged_attention.
-    paged_attention_kernel`; the only addition is the in-VMEM rotation,
-    whose values match the unfused XLA rotation exactly."""
+    paged_attention_kernel` had before it was re-blocked to groups of
+    pages with all heads (PERF.md, PR 27); this path is off in every
+    configuration the benchmark runs and keeps that grid until it is
+    compared (ROADMAP D3). The addition is the in-VMEM rotation, whose
+    values match the unfused XLA rotation exactly."""
     B, H, D = q.shape
     P, page, HK = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     assert H % HK == 0

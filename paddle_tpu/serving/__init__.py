@@ -85,8 +85,10 @@ SLO-aware scheduler.
   token-identical to :class:`ServingCluster` on the same trace,
   ``kill -9`` of a replica process handled as WAL-recovering failover.
 - the paged attention op lives in
-  :mod:`paddle_tpu.ops.pallas.paged_attention` (Pallas kernel + pure-lax
-  fallback) and the continuous-batching engine in
+  :mod:`paddle_tpu.ops.pallas.paged_attention` (a Pallas kernel that
+  reads each row's live pages, all kv heads of a page at once, from the
+  pool as it is stored + a pure-lax fallback) and the
+  continuous-batching engine in
   :mod:`paddle_tpu.inference.predictor`
   (:class:`~paddle_tpu.inference.ContinuousBatchingEngine`).
 """

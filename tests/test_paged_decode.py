@@ -127,6 +127,57 @@ class TestPagedAttentionOp:
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("HK,rep", [(1, 4), (2, 2), (2, 8), (8, 2),
+                                        (4, 1)])
+    def test_kernel_matches_reference_ragged(self, monkeypatch, HK, rep,
+                                             pool):
+        """The kernel body (interpret mode) against the reference over
+        the head groupings the engine's configurations present, with
+        groups of two pages so that rows end inside a page, on a page
+        boundary, inside a group and on the slot extent."""
+        page, D, pp, P = 8, 16, 5, 24
+        itemsize = {"f32": 4, "bf16": 2, "int8": 1}[pool]
+        monkeypatch.setattr(pa, "_KV_VMEM_BUDGET",
+                            2 * 4 * page * HK * D * itemsize)
+        assert pa._pages_per_group(pp, page, HK, D, itemsize) == 2
+        rs = np.random.RandomState(HK * 16 + rep)
+        # 1, one short of a page, a page, one over, a partial last group
+        # (3 pages), the slot extent, an idle row on the trash page, and
+        # a row whose table ends in -1
+        lens = [1, page - 1, page, page + 1, 2 * page + 4, pp * page, 1,
+                page]
+        B = len(lens)
+        bt = np.stack([rs.choice(np.arange(1, P), pp, replace=False)
+                       for _ in range(B)]).astype(np.int32)
+        bt[6] = 0
+        bt[7, 1:] = -1
+        dt = jnp.bfloat16 if pool == "bf16" else jnp.float32
+        q = jnp.asarray(rs.randn(B, HK * rep, D), dt)
+        kw = {}
+        if pool == "int8":
+            kp, vp = (jnp.asarray(rs.randint(-127, 128, (P, page, HK, D)),
+                                  jnp.int8) for _ in range(2))
+            kw = dict(
+                ks_pages=jnp.asarray(rs.rand(P, page, HK) * 0.05 + 0.01,
+                                     jnp.float32),
+                vs_pages=jnp.asarray(rs.rand(P, page, HK) * 0.05 + 0.01,
+                                     jnp.float32))
+        else:
+            kp, vp = self._pages(rs, P, page, HK, D, dt)
+        bt, lens = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+        ref = pa.paged_attention_reference(q, kp, vp, bt, lens, **kw)
+        fa.set_interpret(True)
+        try:
+            ker = pa.paged_attention_kernel(q, kp, vp, bt, lens, **kw)
+        finally:
+            fa.set_interpret(False)
+        # bf16: p is rounded to bf16 against another running maximum
+        tol = 2e-2 if pool == "bf16" else 2e-5
+        np.testing.assert_allclose(np.asarray(ker, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
     def test_mismatched_scales_raise(self):
         rs = np.random.RandomState(2)
         kp, vp = self._pages(rs, 4, 8, 2, 16)

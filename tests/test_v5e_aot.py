@@ -46,8 +46,9 @@ def _compile(fn, *avals):
     avals' TPU devices, and see that the kernel is in the lowering."""
     with fa.force_compiled_lowering():
         lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*avals)
-        lowered.compile()
+        compiled = lowered.compile()
     assert "tpu_custom_call" in lowered.as_text()
+    return compiled
 
 
 def _on(dev, shape, dtype):
@@ -64,14 +65,36 @@ def test_flash_fwd_bwd_flagship_shape(v5e):
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
-@pytest.mark.parametrize("page,kv", [(16, "bf16"), (16, "int8"),
-                                     (8, "bf16"), (32, "int8")])
-def test_paged_decode_kernel(v5e, page, kv):
-    B, H, D, pages, ppseq = 8, 12, 128, 257, 2048 // page
+# B, H, HK, page, ppseq, pages: MHA at three page sizes; the benchmark's
+# serving cells (internlm2-1.8b); ERNIE-4.5-0.3B's 8:1 GQA; one shard of
+# the cells' model under tp=4
+PAGED_SHAPES = {
+    "mha-p16": (8, 12, 12, 16, 128, 257),
+    "mha-p8": (8, 12, 12, 8, 256, 257),
+    "mha-p32": (8, 12, 12, 32, 64, 257),
+    "cell": (32, 16, 8, 64, 64, 801),
+    "ernie": (32, 16, 2, 64, 64, 801),
+    "tp4-shard": (32, 4, 2, 64, 64, 801),
+}
+
+
+# in_place: the kernel reads the pool as it is stored. It sees a page as
+# page*HK rows of D; that reshape moves no byte where XLA tiles the HK rows
+# of a position as whole packed words (8 bf16 or int8 heads, 2 bf16 heads),
+# and is a relayout where it pads them (12 heads, 2 int8 heads)
+@pytest.mark.parametrize("shape,kv,in_place", [
+    ("mha-p16", "bf16", False), ("mha-p16", "int8", False),
+    ("mha-p8", "bf16", False), ("mha-p32", "int8", False),
+    ("cell", "bf16", True), ("cell", "int8", True),
+    ("ernie", "bf16", True), ("ernie", "int8", False),
+    ("tp4-shard", "bf16", True), ("tp4-shard", "int8", False)])
+def test_paged_decode_kernel(v5e, shape, kv, in_place):
+    B, H, HK, page, ppseq, pages = PAGED_SHAPES[shape]
+    D = 128
     d = v5e[0]
-    pool = _on(d, (pages, page, H, D),
+    pool = _on(d, (pages, page, HK, D),
                jnp.int8 if kv == "int8" else jnp.bfloat16)
-    scales = _on(d, (pages, page, H), jnp.float32)
+    scales = _on(d, (pages, page, HK), jnp.float32)
     args = [_on(d, (B, H, D), jnp.bfloat16), pool, pool,
             _on(d, (B, ppseq), jnp.int32), _on(d, (B,), jnp.int32)]
 
@@ -82,7 +105,11 @@ def test_paged_decode_kernel(v5e, page, kv):
             q, k, v, bt, lens, ks_pages=ks, vs_pages=vs)
     if kv == "int8":
         args += [scales, scales]
-    _compile(f, *args)
+    compiled = _compile(f, *args)
+    if in_place:
+        # no transposed, gathered or re-tiled copy of a pool in the temp
+        pool_bytes = pages * page * HK * D * pool.dtype.itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
 def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
