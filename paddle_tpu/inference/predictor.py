@@ -646,7 +646,24 @@ class ContinuousBatchingEngine:
         cache_kw = dict(page_size=page_size, num_pages=num_pages,
                         kv_dtype=kv_cache_dtype,
                         enable_prefix_cache=enable_prefix_cache,
-                        mesh=mesh)
+                        mesh=mesh, prefill_chunk=prefill_chunk)
+        # --- sliding-window layers (LlamaConfig.layer_pattern): the
+        # cache keeps a second pool and block table for them and the
+        # decode and chunk programs take both. What walks ONE pool's
+        # pages a row refuses such a config here, by name: the host
+        # tier's swap and demotion, the verify programs of every
+        # speculation mode (the fabric's handoff and drain/restore
+        # refuse at their call, in PagedKVCache).
+        if "sliding" in cfg.period:
+            for on, what in ((host_tier, "host_tier"),
+                             (spec_k or spec_tree, "speculative decoding"
+                              " (spec_k / spec_tree)"),
+                             (draft_layers, "draft_layers")):
+                if on:
+                    raise ValueError(
+                        f"ContinuousBatchingEngine: {what} is not "
+                        f"supported on a config with sliding-window "
+                        f"layers (two pools and block tables a row)")
         if host_tier:
             from ..serving.host_tier import TieredKVCache
             self.cache = TieredKVCache(
@@ -727,6 +744,14 @@ class ContinuousBatchingEngine:
         self._launched: set = set()     # program keys already called once
         self._next_rid = 0
         self._steps = 0
+        # --- expert-layer counters (cfg.moe): every decode and chunk
+        # program adds its layers' [routed items, experts hit, largest
+        # expert load, expert layers run] to ``_moe_acc`` ON THE DEVICE;
+        # the decode program hands the sum back packed behind its
+        # tokens, so the one read of the step's tokens brings them
+        self._moe_acc = (jnp.zeros((4,), jnp.int32)
+                         if cfg.moe is not None else None)
+        self._moe_zero = self._moe_acc
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
         # supervisor; -1 renders as the "router" lane in exports
         self.replica_id = -1
@@ -1007,42 +1032,41 @@ class ContinuousBatchingEngine:
             ax, fz = self._tp_axis, self.fused
             dpx = self._dp_axis
             ad_on, cons = self.adapters is not None, self.constraints
+            win = self.cache.window is not None
+            moe = cfg.moe is not None
+            nlayers = cfg.num_layers
 
-            if ad_on:
-                def fwd(params, last, paged, tables, lengths, active,
-                        ad, aslot):
-                    return gen.paged_decode_forward(
-                        params, last, paged, tables, lengths, cfg,
-                        active=active, use_kernel=uk, tp_axis=ax,
-                        dp_axis=dpx, fused=fz, adapters=ad,
-                        adapter_slots=aslot)
-                if self.mesh is not None:
-                    fwd = self._tp_map(fwd, ("params", "batch", "pool",
-                                             "batch", "batch", "batch",
-                                             "adapters", "batch"))
-            else:
-                def fwd(params, last, paged, tables, lengths, active):
-                    return gen.paged_decode_forward(
-                        params, last, paged, tables, lengths, cfg,
-                        active=active, use_kernel=uk, tp_axis=ax,
-                        dp_axis=dpx, fused=fz)
-                if self.mesh is not None:
-                    fwd = self._tp_map(fwd, ("params", "batch", "pool",
-                                             "batch", "batch", "batch"))
+            def fwd(params, last, paged, tables, lengths, active, *rest):
+                # rest (engine-config-static): [the sliding layers'
+                # block tables], then [adapter arrays, adapter slots]
+                rest = list(rest)
+                wt = rest.pop(0) if win else None
+                ad, aslot = (rest if ad_on else (None, None))
+                return gen.paged_decode_forward(
+                    params, last, paged, tables, lengths, cfg,
+                    active=active, use_kernel=uk, tp_axis=ax,
+                    dp_axis=dpx, fused=fz, adapters=ad,
+                    adapter_slots=aslot, window_tables=wt,
+                    with_stats=moe)
+            n_fwd = int(win) + 2 * int(ad_on)
+            if self.mesh is not None:
+                fwd = self._tp_map(
+                    fwd, ("params", "batch", "pool", "batch", "batch",
+                          "batch") + ("batch",) * win
+                    + ("adapters", "batch") * ad_on,
+                    out_kinds=("rep", "pool") + ("rep",) * moe)
 
             def f(params, last, paged, tables, lengths, active, key,
                   *extra):
-                # extra layout (engine-config-static): [adapter arrays,
-                # adapter slots] when the pool is on, then [the (B, V)
-                # allowed-token mask] when constraints are on
+                # extra layout (engine-config-static): what ``fwd``
+                # takes (sliding block tables, adapter arrays and
+                # slots), then [the (B, V) allowed-token mask] when
+                # constraints are on, then [the expert counters'
+                # accumulator] of a MoE config
                 extra = list(extra)
-                if ad_on:
-                    logits, paged = fwd(params, last, paged, tables,
-                                        lengths, active, extra.pop(0),
-                                        extra.pop(0))
-                else:
-                    logits, paged = fwd(params, last, paged, tables,
-                                        lengths, active)
+                logits, paged, *st = fwd(params, last, paged, tables,
+                                         lengths, active, *extra[:n_fwd])
+                del extra[:n_fwd]
                 raw = None
                 if cons:
                     # the UNCONSTRAINED argmax rides along so the commit
@@ -1057,6 +1081,10 @@ class ContinuousBatchingEngine:
                 else:
                     nxt = jax.random.categorical(
                         key, logits / temp, axis=-1).astype(jnp.int32)
+                if moe:
+                    # the counters ride behind the tokens: one read
+                    nxt = jnp.concatenate(
+                        [nxt, extra.pop(0) + jnp.append(st[0], nlayers)])
                 if cons:
                     return (nxt, raw), paged
                 return nxt, paged
@@ -1082,29 +1110,36 @@ class ContinuousBatchingEngine:
             # program): every batch arg keeps the "rep" kind and only
             # dp_axis threads through, so a MoE config's expert
             # dispatch can still all-to-all over the dp axis
-            if self.adapters is not None:
-                def f(params, chunk, paged, table, ctx_len, chunk_len,
-                      ad, aslot):
-                    return gen.paged_prefill_chunk(
-                        params, chunk, paged, table, cfg,
-                        ctx_cap=ctx_cap, ctx_len=ctx_len,
-                        chunk_len=chunk_len, tp_axis=ax, dp_axis=dpx,
-                        fused=fz, use_kernel=uk, adapters=ad,
-                        adapter_slot=aslot)
-                if self.mesh is not None:
-                    f = self._tp_map(f, ("params", "rep", "pool", "rep",
-                                         "rep", "rep", "adapters",
-                                         "rep"))
-            else:
-                def f(params, chunk, paged, table, ctx_len, chunk_len):
-                    return gen.paged_prefill_chunk(
-                        params, chunk, paged, table, cfg,
-                        ctx_cap=ctx_cap, ctx_len=ctx_len,
-                        chunk_len=chunk_len, tp_axis=ax, dp_axis=dpx,
-                        fused=fz, use_kernel=uk)
-                if self.mesh is not None:
-                    f = self._tp_map(f, ("params", "rep", "pool", "rep",
-                                         "rep", "rep"))
+            ad_on = self.adapters is not None
+            win = self.cache.window is not None
+            moe = cfg.moe is not None
+            nlayers = cfg.num_layers
+
+            def fwd(params, chunk, paged, table, ctx_len, chunk_len,
+                    *rest):
+                # rest: [the sliding layers' block table], then
+                # [adapter arrays, adapter slot]
+                rest = list(rest)
+                wt = rest.pop(0) if win else None
+                ad, aslot = (rest if ad_on else (None, None))
+                return gen.paged_prefill_chunk(
+                    params, chunk, paged, table, cfg, ctx_cap=ctx_cap,
+                    ctx_len=ctx_len, chunk_len=chunk_len, tp_axis=ax,
+                    dp_axis=dpx, fused=fz, use_kernel=uk, adapters=ad,
+                    adapter_slot=aslot, window_table=wt, with_stats=moe)
+            if self.mesh is not None:
+                fwd = self._tp_map(
+                    fwd, ("params", "rep", "pool", "rep", "rep", "rep")
+                    + ("rep",) * win + ("adapters", "rep") * ad_on,
+                    out_kinds=("rep", "pool") + ("rep",) * moe)
+            f = fwd
+            if moe:
+                def f(*args):
+                    # the last argument is the expert counters'
+                    # accumulator; it comes back with this chunk's added
+                    logits, paged, st = fwd(*args[:-1])
+                    return logits, paged, args[-1] + jnp.append(
+                        st, nlayers)
             self._chunk_fns[key] = _named_jit(
                 f, f"prefill_chunk_c{ctx_cap}_w{width}",
                 donate_argnums=(2,))
@@ -1304,7 +1339,8 @@ class ContinuousBatchingEngine:
         if key in self._launched:
             return fn(*args)
         with self.spans.span("engine.build_program", kind=kind,
-                             ctx_cap=ctx_cap, width=width):
+                             ctx_cap=ctx_cap, width=width,
+                             period=len(self.cfg.period)):
             out = fn(*args)
         self._launched.add(key)
         return out
@@ -1619,12 +1655,22 @@ class ContinuousBatchingEngine:
             args = [self.params, jnp.asarray(chunk), cache.pool,
                     jnp.asarray(cache.block_tables[slot]),
                     jnp.int32(done), jnp.int32(take)]
+            if cache.window:
+                # the sliding layers' pages for the chunk's positions
+                # (a copy: the commit writes this row while a chunk
+                # that no read waited for may still be running)
+                cache.window_extend(slot, done + take)
+                args += [jnp.asarray(cache.window_tables[slot].copy())]
             if self.adapters is not None:
                 args += [self.adapters.arrays,
                          jnp.asarray(self._aslot[slot:slot + 1])]
-            logits, cache.pool = self._launch(
+            if self._moe_acc is not None:
+                args += [self._moe_acc]
+            logits, cache.pool, *acc = self._launch(
                 self._chunk_fn(ctx_cap, width), args, "chunk", ctx_cap,
                 width)
+            if acc:
+                self._moe_acc = acc[0]
             samp = rawmax = None
             if done + take >= S and not req.tokens:
                 # final chunk of a fresh admission (or a mid-prefill
@@ -1694,12 +1740,19 @@ class ContinuousBatchingEngine:
             req, "prefill_chunk", h.get("ttr", 0),
             replica=self.replica_id, slot=slot, seq=len(req.tokens),
             meta={"take": int(take), "done": int(done)})
+        if cache.window:
+            # what the next chunk's (or the first decode step's) first
+            # query no longer sees goes back to the sliding pool
+            self.spans.count("window_pages_released_total",
+                             cache.window_release(slot, done))
         if done < ent[1].size:
             ent[2] = done
             return take
         del self._pending[slot]
         cache.register_prefix(slot, req.prompt[0])
         cache.lengths[slot] = ent[1].size
+        if cache.window:
+            cache.window_extend(slot, done + 1)   # the first decode write
         req.finish_reason = None            # clears transient "preempted"
         if req.tokens:
             # preemption resume: the replay covered prompt +
@@ -1952,6 +2005,8 @@ class ContinuousBatchingEngine:
                     jnp.asarray(cache.block_tables),
                     jnp.asarray(cache.lengths),
                     jnp.asarray(mask), k]
+            if cache.window:
+                args += [jnp.asarray(cache.window_tables.copy())]
             if self.adapters is not None:
                 args += [self.adapters.arrays, jnp.asarray(self._aslot)]
             if self.constraints:
@@ -1959,6 +2014,9 @@ class ContinuousBatchingEngine:
                     self._cmask_dev = jnp.asarray(self._cmask)
                     self._cmask_dirty = False
                 args += [self._cmask_dev]
+            if self._moe_acc is not None:
+                args += [self._moe_acc]
+                self._moe_acc = self._moe_zero
             out, cache.pool = self._launch(self._decode(), args, "decode")
             raw = None
             if self.constraints:
@@ -1992,6 +2050,13 @@ class ContinuousBatchingEngine:
             _fault_point("transfer")
             nxt = np.asarray(h.out)
             raw = np.asarray(h.raw) if self.constraints else None
+        if self._moe_acc is not None:
+            nxt, moe = nxt[:self.max_batch], nxt[self.max_batch:]
+            for name, n in zip(("moe_routed_items_total",
+                                "moe_experts_hit_total",
+                                "moe_max_expert_load_total",
+                                "moe_layer_steps_total"), moe.tolist()):
+                self.spans.count(name, n)
         rows = int(h.mask.sum())
         with self.spans.span("engine.commit", rows=rows):
             return self._decode_commit_host(h, nxt, raw, rows)
@@ -2007,6 +2072,9 @@ class ContinuousBatchingEngine:
         if slots.size:
             toks = nxt[slots]
             cache.lengths[slots] += 1
+            if cache.window:
+                self.spans.count("window_pages_released_total",
+                                 cache.window_step(slots))
             self._last[slots] = toks
             new_cnt = self._ntok[slots] + 1
             self._ntok[slots] = new_cnt
@@ -2779,6 +2847,11 @@ class ContinuousBatchingEngine:
         if self.fused:
             s["fused_kernels"] = True
         s["cow_copies"] = self.cache.cow_copies
+        s["full_pool_used_peak"] = self.cache.allocator.peak_in_use
+        if self.cache.window:
+            wa = self.cache.window_allocator
+            s["window_pool_used_peak"] = wa.peak_in_use
+            s["window_pool_usable"] = wa.num_usable
         if self.adapters is not None:
             s.update(self.adapters.stats())
         if getattr(self.cache, "host", None) is not None:

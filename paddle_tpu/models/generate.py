@@ -10,6 +10,16 @@ K/V into a preallocated (L, B, S_max, H, D) cache (static shapes; no
 dynamic growth), decode is a ``lax.scan`` over steps where each step does
 a single-token forward against the cache with a length mask. Greedy or
 temperature/top-k sampling via stateless PRNG.
+
+Caches by layer kind. A config whose ``layer_pattern`` names sliding
+layers keeps two sets of arrays, dense and paged alike: ``k``/``v``
+(``ks``/``vs``) hold the FULL layers, ``k_w``/``v_w`` (``ks_w``/``vs_w``)
+the SLIDING ones, each stacked over the layers of its kind in model
+order — paged: ``(layers of the kind, pages, page, nkv, hd)``, two pools
+with page ids and block tables of their own. A plain config has the one
+set. Every forward scans over periods (``_layer_feed``); inside a period
+each run of one layer kind (``_runs``) is one layer body, scanned when the
+run has several layers; a period of one is the scan over layers.
 """
 from __future__ import annotations
 
@@ -81,16 +91,40 @@ def _tp_heads(layers: Dict, cfg: LlamaConfig) -> Tuple[int, int]:
             layers["wk"].shape[-1] // cfg.hd)
 
 
+#: suffix of a layer kind's cache arrays (see the module docstring)
+KIND_SUFFIX = {"full": "", "sliding": "_w"}
+
+
+def _kind_arrays(cfg: LlamaConfig, make) -> Dict:
+    """``make(kind)`` -> that kind's arrays by their plain names; all
+    kinds of the config's period in one dict, suffixed."""
+    out = {}
+    for kind in dict.fromkeys(cfg.period):
+        out.update({n + KIND_SUFFIX[kind]: a
+                    for n, a in make(kind).items()})
+    return out
+
+
+def _of_kind(arrays: Dict, kind: str) -> Dict:
+    """The arrays of one layer kind under their plain names."""
+    sfx = KIND_SUFFIX[kind]
+    return {n: arrays[n + sfx] for n in ("k", "v", "ks", "vs")
+            if n + sfx in arrays}
+
+
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
-               kv_dtype=None, num_kv_heads: Optional[int] = None) -> Dict:
+               kv_dtype=None, num_kv_heads: Optional[int] = None,
+               window_len: Optional[int] = None) -> Dict:
     """``kv_dtype="int8"``: int8 KV cache with PER-ROW dequant scales
     (each cached token row carries its own scale — self-calibrating, no
     calibration pass), halving KV HBM for long-context decode
     (reference: the cachekv-int8 tier of block_multihead_attention).
     ``num_kv_heads`` overrides the config's head count — the per-shard
     temp caches of the tensor-parallel chunk/verify programs hold only
-    the shard's own kv heads."""
-    L, nkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.hd
+    the shard's own kv heads. ``window_len``: the width of the SLIDING
+    layers' arrays where it is not ``max_len`` (the chunk program's temp
+    cache holds one window of context for them)."""
+    nkv, hd = cfg.num_kv_heads, cfg.hd
     if num_kv_heads is not None:
         nkv = num_kv_heads
     if kv_dtype is not None and jnp.dtype(kv_dtype) != jnp.int8:
@@ -99,21 +133,27 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
             f"None (model dtype) or 'int8' (quantized cache with per-row "
             f"scales); a silently full-precision cache would misreport "
             f"the serving configuration")
-    if kv_dtype is not None:
+
+    def make(kind):
+        L = cfg.kind_layers(kind)
+        S = window_len if kind == "sliding" and window_len else max_len
+        if kv_dtype is not None:
+            return {
+                "k": jnp.zeros((L, batch, S, nkv, hd), jnp.int8),
+                "v": jnp.zeros((L, batch, S, nkv, hd), jnp.int8),
+                "ks": jnp.zeros((L, batch, S, nkv), jnp.float32),
+                "vs": jnp.zeros((L, batch, S, nkv), jnp.float32),
+            }
         return {
-            "k": jnp.zeros((L, batch, max_len, nkv, hd), jnp.int8),
-            "v": jnp.zeros((L, batch, max_len, nkv, hd), jnp.int8),
-            "ks": jnp.zeros((L, batch, max_len, nkv), jnp.float32),
-            "vs": jnp.zeros((L, batch, max_len, nkv), jnp.float32),
+            "k": jnp.zeros((L, batch, S, nkv, hd), cfg.dtype),
+            "v": jnp.zeros((L, batch, S, nkv, hd), cfg.dtype),
         }
-    return {
-        "k": jnp.zeros((L, batch, max_len, nkv, hd), cfg.dtype),
-        "v": jnp.zeros((L, batch, max_len, nkv, hd), cfg.dtype),
-    }
+    return _kind_arrays(cfg, make)
 
 
 def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
-                     kv_dtype=None, tp: Optional[int] = None) -> Dict:
+                     kv_dtype=None, tp: Optional[int] = None,
+                     window_pages: Optional[int] = None) -> Dict:
     """Paged KV cache: one global pool of fixed-size token pages per
     layer — ``(L, num_pages, page_size, nkv, hd)`` — indexed by
     per-request block tables instead of a dense ``(L, B, S_max, ...)``
@@ -134,8 +174,22 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
     chips. GQA with ``num_kv_heads < tp`` takes the replication path —
     the head extent expands to ``tp`` (each kv head repeated
     ``tp/num_kv_heads`` times, one per shard), so per-shard page bytes
-    are ``1/num_kv_heads`` of the pool rather than ``1/tp``."""
-    L, nkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.hd
+    are ``1/num_kv_heads`` of the pool rather than ``1/tp``.
+
+    A config with sliding layers gets TWO pools, one per layer kind
+    (module docstring): the full layers' of ``num_pages`` pages and the
+    sliding layers' of ``window_pages``, the second sized by the
+    allocator for a window and a chunk a row
+    (:class:`~paddle_tpu.serving.PagedKVCache`), not for ``max_len``."""
+    nkv, hd = cfg.num_kv_heads, cfg.hd
+    if "sliding" in cfg.period and window_pages is None:
+        raise ValueError(
+            "init_paged_cache: the config has sliding layers; "
+            "window_pages must size their pool")
+    if "full" not in cfg.period:
+        raise ValueError(
+            "init_paged_cache: a period without a full layer is not "
+            "served (block tables and admission follow the full pool)")
     if tp is not None:
         # validate_serving_mesh rather than validate_serving_tp: the
         # head contract is identical and MoE configs are legal on the
@@ -145,17 +199,48 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
         raise ValueError(
             f"init_paged_cache: kv_dtype={kv_dtype!r} is not supported — "
             f"pass None (model dtype) or 'int8'")
-    if kv_dtype is not None:
+
+    def make(kind):
+        L = cfg.kind_layers(kind)
+        P = window_pages if kind == "sliding" else num_pages
+        if kv_dtype is not None:
+            return {
+                "k": jnp.zeros((L, P, page_size, nkv, hd), jnp.int8),
+                "v": jnp.zeros((L, P, page_size, nkv, hd), jnp.int8),
+                "ks": jnp.zeros((L, P, page_size, nkv), jnp.float32),
+                "vs": jnp.zeros((L, P, page_size, nkv), jnp.float32),
+            }
         return {
-            "k": jnp.zeros((L, num_pages, page_size, nkv, hd), jnp.int8),
-            "v": jnp.zeros((L, num_pages, page_size, nkv, hd), jnp.int8),
-            "ks": jnp.zeros((L, num_pages, page_size, nkv), jnp.float32),
-            "vs": jnp.zeros((L, num_pages, page_size, nkv), jnp.float32),
+            "k": jnp.zeros((L, P, page_size, nkv, hd), cfg.dtype),
+            "v": jnp.zeros((L, P, page_size, nkv, hd), cfg.dtype),
         }
-    return {
-        "k": jnp.zeros((L, num_pages, page_size, nkv, hd), cfg.dtype),
-        "v": jnp.zeros((L, num_pages, page_size, nkv, hd), cfg.dtype),
-    }
+    return _kind_arrays(cfg, make)
+
+
+def _take_pages(pool, table):
+    """The pages ``table`` (n,) names, in order, as token rows: pool
+    (L, P, page, nkv, ...) -> (L, n * page, nkv, ...). With 8 kv heads
+    or more the plain gather moves nothing but the pages. With fewer, a
+    position's ``(nkv, hd)`` rows do not fill one of XLA's (8, 128)
+    tiles, and asked to gather such pages XLA first re-tiles the WHOLE
+    pool (two copies of the pool a chunk, seen at 4 heads): there
+    a page is taken as one slab of ``page * nkv`` rows, the view the
+    paged kernel reads too, and a short table (a sliding layer's
+    window) page by page, since XLA's gather of few slabs again starts
+    by slicing the whole pool in halves."""
+    L, P, page, nkv = pool.shape[:4]
+    n = table.shape[0]
+    if nkv >= 8:
+        g = jnp.take(pool, table, axis=1)               # (L, n, pg, .)
+    else:
+        slabs = pool.reshape((L, P, page * nkv) + pool.shape[4:])
+        if n <= 32:
+            g = jnp.concatenate(
+                [lax.dynamic_index_in_dim(slabs, table[i], 1)
+                 for i in range(n)], axis=1)
+        else:
+            g = jnp.take(slabs, table, axis=1)
+    return g.reshape((L, n * page) + pool.shape[3:])
 
 
 def _scatter_rows(pool, dst, rows):
@@ -167,53 +252,80 @@ def _scatter_rows(pool, dst, rows):
     return flat.reshape(pool.shape)
 
 
-def _moe_apply(xi, le, wg, wu, wd, cfg: LlamaConfig, tp_axis=None):
-    """Per-item expert SwiGLU: ``xi`` (n, H) routed token copies,
-    ``le`` (n,) LOCAL expert ids into this shard's expert stacks
-    ``wg``/``wu`` (E_l, H, i_cols) / ``wd`` (E_l, i, h_cols).
+#: the expert stacks of a layer tree: kept out of the layer scan's slices
+EXPERT_STACKS = ("moe_wg", "moe_wu", "moe_wd")
 
-    Every item's FFN is the dense SwiGLU with its expert's matrices,
-    gathered per item (``jnp.take`` over the expert axis) and applied
-    as a batched matvec — the contraction order over the input axis is
-    identical for every batch size, which is what makes the
-    expert-parallel path token-identical to the single-device
-    dense-dispatch reference (the SAME function with full stacks and
-    global ids). Under tp the expert matrices arrive column-sharded
-    exactly like the dense ``wg``/``wu``/``wd`` and the activations
-    all-gather to full width before each contraction — the ISSUE 7
-    exact-concat argument, unchanged."""
-    dt = xi.dtype
-    gw = jnp.take(wg, le, axis=0).astype(dt)            # (n, H, i_l)
-    uw = jnp.take(wu, le, axis=0).astype(dt)
-    dw = jnp.take(wd, le, axis=0).astype(dt)            # (n, i, h_l)
-    g = jax.nn.silu(jnp.einsum("nh,nhi->ni", xi, gw).astype(
-        jnp.float32)).astype(dt)
-    u = jnp.einsum("nh,nhi->ni", xi, uw)
+
+def _expert_apply(x_rows, item_row, le, stacks, layer, tp_axis=None,
+                  use_kernel=None):
+    """Expert SwiGLU over routed items, grouped by expert.
+
+    ``x_rows`` (R, H) token rows, ``item_row`` (n,) the row each item
+    copies, ``le`` (n,) LOCAL expert ids into this shard's experts of
+    layer ``layer``; ``stacks`` are the model's three expert stacks as
+    they are stored, ``(L, E_l, H, i_cols)`` twice and ``(L, E_l, i,
+    h_cols)``. The items are sorted by expert (a stable sort: within an
+    expert they keep token order), the group sizes are the counts, and
+    each projection is ONE grouped matmul
+    (``ops/pallas/grouped_matmul.py``: a Pallas kernel on the chip,
+    ``lax.ragged_dot`` off it) in place of a private gathered copy of
+    three matrices an item: an expert with no item is never read, and
+    one with items is read once. The call is handed the stacks of ALL
+    layers and the layer's number, and reads the layer's experts out of
+    the stack where they lie: a custom call's operand is a whole
+    buffer, so a layer's slice (0.8 GB of experts at the published
+    widths) would be copied out for it at every layer of every step.
+    The outputs go back to item order. Under tp the stacks arrive
+    column-sharded like the dense ``wg``/``wu``/``wd`` and the
+    activations all-gather to full width before each contraction (the
+    ISSUE 7 exact-concat argument)."""
+    from ..ops.pallas.grouped_matmul import grouped_matmul
+    n, dt = le.shape[0], x_rows.dtype
+    wg, wu, wd = stacks
+    sizes = jnp.zeros((wg.shape[1],), jnp.int32).at[le].add(1)
+    order = jnp.argsort(le)                     # stable: token order kept
+    xs = jnp.take(x_rows, jnp.take(item_row, order), axis=0)
+    mm = partial(grouped_matmul, sizes=sizes, layer=layer,
+                 use_kernel=use_kernel)
+    g = jax.nn.silu(mm(xs, wg).astype(jnp.float32)).astype(dt)
+    u = mm(xs, wu)
     gu = g * u
     if tp_axis is not None:
         gu = _tp_allgather(gu, tp_axis, 1)
-    o = jnp.einsum("ni,nih->nh", gu, dw)
+    o = mm(gu, wd)
     if tp_axis is not None:
         o = _tp_allgather(o, tp_axis, 1)
-    return o
+    inv = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    return jnp.take(o, inv, axis=0)
 
 
-def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None):
-    """Serving MoE FFN (ISSUE 17): capacity-DROPLESS top-k routing +
-    per-item expert apply, expert-parallel over the dp axis.
+def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
+             valid=None, experts=None, layer=0, use_kernel=None):
+    """Serving MoE FFN: capacity-DROPLESS top-k routing and one grouped
+    expert layer (:func:`_expert_apply`) for decode and chunk alike,
+    expert-parallel over the dp axis (ISSUE 17).
 
     x: (B, T, H); lp carries this layer's ``moe_gate`` (H, E) fp32
     router (replicated — every shard routes identically, the
     bit-identity precondition) and expert stacks ``moe_wg``/``moe_wu``/
     ``moe_wd`` — FULL E on a single chip, E/dp experts per shard under
-    expert parallelism (their column axis tp-sharded either way).
+    expert parallelism (their column axis tp-sharded either way). A
+    forward that scans over layers hands the stacks of ALL layers as
+    ``experts`` and says which ``layer`` this is (:func:`_expert_apply`
+    has the reason); ``lp`` then carries the router alone.
+    Returns ``(y, stats)``; ``stats`` is int32 ``[routed items, experts
+    hit, largest expert load]`` of this layer over the rows ``valid``
+    (B, T) marks (all of them when None): what the engine's ``moe_*``
+    counters sum.
 
     Routing: ``top_k`` over the fp32 router logits (lax.top_k —
-    deterministic lowest-index tie-break), softmax over the k selected
-    logits, and the combine ``y = sum_j w_j * out_j`` runs over the
-    top-k slots IN SLOT ORDER in fp32 — the same fixed-order sum on
-    every path, so EP decode is token-identical to the dense-dispatch
-    reference (this function with ``dp_axis=None`` and full stacks).
+    deterministic lowest-index tie-break) and a softmax over the k
+    selected logits, which IS the softmax over all experts, its k
+    largest, renormalised (``norm_topk_prob``). The combine
+    ``y = sum_j w_j * out_j`` runs over the top-k slots IN SLOT ORDER in
+    fp32 — the same fixed-order sum on every path. No capacity, no
+    dropped token: the groups are as long as the routing makes them.
 
     Dispatch (dp > 1): the N*k routed items scatter into per-owner send
     buffers of capacity N*k each — dropless BY CONSTRUCTION (a worst
@@ -228,17 +340,22 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None):
     moe = cfg.moe
     k = moe.top_k
     gate = lp["moe_gate"].astype(jnp.float32)           # (H, E)
-    wg, wu, wd = lp["moe_wg"], lp["moe_wu"], lp["moe_wd"]
+    if experts is None:
+        experts = tuple(lp[n][None] for n in EXPERT_STACKS)
     E = gate.shape[-1]
-    El = wg.shape[0]                                    # local experts
+    El = experts[0].shape[1]                            # local experts
     N = B * T
     xf = x.reshape(N, H)
     logits = xf.astype(jnp.float32) @ gate              # (N, E)
     vals, idx = lax.top_k(logits, k)                    # (N, k)
     w = jax.nn.softmax(vals, axis=-1)                   # fp32
-    items_x = jnp.repeat(xf, k, axis=0)                 # (N*k, H)
     items_e = idx.reshape(-1).astype(jnp.int32)         # global ids
     n = N * k
+    item_row = jnp.arange(n, dtype=jnp.int32) // k
+    live = (jnp.ones((n,), jnp.int32) if valid is None else
+            jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
+    load = jnp.zeros((E,), jnp.int32).at[items_e].add(live)
+    stats = jnp.stack([jnp.sum(live), jnp.sum(load > 0), jnp.max(load)])
     if dp_axis is not None and El != E:
         # expert-parallel dispatch: owner shard + local id from the
         # LOCAL stack shape (dp = E/El — no collective needed), rank
@@ -250,7 +367,8 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None):
         pos = jnp.sum((owner[None, :] == owner[:, None])
                       & (ar[None, :] < ar[:, None]),
                       axis=1).astype(jnp.int32)
-        sx = jnp.zeros((dp, n, H), x.dtype).at[owner, pos].set(items_x)
+        sx = jnp.zeros((dp, n, H), x.dtype).at[owner, pos].set(
+            jnp.take(xf, item_row, axis=0))
         se = jnp.zeros((dp, n), jnp.int32).at[owner, pos].set(le)
         # trace-time all-to-all accounting (the serving_tp_allgather
         # contract — fires once per compile per layer): token payload
@@ -260,17 +378,72 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None):
             + int(se.size) * 4, n)
         rx = lax.all_to_all(sx, dp_axis, split_axis=0, concat_axis=0)
         re = lax.all_to_all(se, dp_axis, split_axis=0, concat_axis=0)
-        out = _moe_apply(rx.reshape(dp * n, H), re.reshape(dp * n),
-                         wg, wu, wd, cfg, tp_axis=tp_axis)
+        out = _expert_apply(rx.reshape(dp * n, H),
+                            jnp.arange(dp * n, dtype=jnp.int32),
+                            re.reshape(dp * n), experts, layer,
+                            tp_axis=tp_axis, use_kernel=use_kernel)
         back = lax.all_to_all(out.reshape(dp, n, H), dp_axis,
                               split_axis=0, concat_axis=0)
         items_out = back[owner, pos]                    # (N*k, H)
     else:
-        items_out = _moe_apply(items_x, items_e, wg, wu, wd, cfg,
-                               tp_axis=tp_axis)
+        items_out = _expert_apply(xf, item_row, items_e, experts, layer,
+                                  tp_axis=tp_axis, use_kernel=use_kernel)
     y = jnp.sum(items_out.reshape(N, k, H).astype(jnp.float32)
                 * w[:, :, None], axis=1)
-    return y.astype(x.dtype).reshape(B, T, H)
+    return y.astype(x.dtype).reshape(B, T, H), stats
+
+
+def _runs(period):
+    """A period as its runs of one layer kind: ``[(kind, first layer of the
+    run, layers in it), ...]``."""
+    runs = []
+    for j, kind in enumerate(period):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, j, 1])
+    return [tuple(r) for r in runs]
+
+
+def _by_period(a, n: int, plen: int):
+    """A kind's stacked arrays ``(layers of the kind, ...)`` as ``(periods,
+    of the kind a period, ...)`` for a scan over periods; a period of one
+    layer is the array itself."""
+    return a if plen == 1 else a.reshape((a.shape[0] // n, n) + a.shape[1:])
+
+
+def _layer_feed(tree, plen: int):
+    """How a scan over periods reaches a stacked ``(L, ...)`` tree of layer
+    arrays: ``(what to put among the scan's xs, take)`` with
+    ``take(slice, i, j)`` the arrays of layer ``j`` of period ``i``. A
+    period of one is the scan's own slice. With several layers a period
+    the stack stays outside the scan and a layer is cut out of it by its
+    number, which is what the scan does itself; a period's slice cut
+    again by position would be copied twice."""
+    if plen == 1 or tree is None:
+        return tree, lambda sl, i, j: sl
+    return None, lambda sl, i, j: jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i * plen + j, 0,
+                                           keepdims=False), tree)
+
+
+def _split_experts(layers: Dict):
+    """A layer tree as (what the layer scan slices, the three expert
+    stacks whole or None): :func:`_expert_apply` reads a layer's experts
+    in place."""
+    if EXPERT_STACKS[0] not in layers:
+        return layers, None
+    return ({n: a for n, a in layers.items() if n not in EXPERT_STACKS},
+            tuple(layers[n] for n in EXPERT_STACKS))
+
+
+def _refuse_sliding(cfg: LlamaConfig, what: str):
+    """The programs that know one pool and one block table a row."""
+    if "sliding" in cfg.period:
+        raise ValueError(
+            f"{what}: the config has sliding-window layers (two pools, "
+            f"two block tables a row); only paged_prefill_chunk and "
+            f"paged_decode_forward serve it")
 
 
 def paged_prefill_insert(params, prompt: jax.Array, paged: Dict,
@@ -308,6 +481,7 @@ def paged_prefill_insert(params, prompt: jax.Array, paged: Dict,
         raise ValueError(
             f"paged_prefill_insert: one request at a time (got batch "
             f"{B}); continuous batching admits requests individually")
+    _refuse_sliding(cfg, "paged_prefill_insert")
     page = paged["k"].shape[2]
     ext = block_table.shape[0] * page          # the slot's full extent
     if S > ext:
@@ -346,7 +520,8 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
                         block_table: jax.Array, cfg: LlamaConfig, *,
                         ctx_cap: int, ctx_len, chunk_len, tp_axis=None,
                         dp_axis=None, fused=None, use_kernel=None,
-                        adapters=None, adapter_slot=None):
+                        adapters=None, adapter_slot=None,
+                        window_table=None, with_stats=False):
     """Prefill ONE chunk of a request's prompt against the KV already in
     its pages — the chunked-prefill / prefix-cache continuation program
     (one compile per static ``(ctx_cap, C)`` pair; the engine buckets
@@ -407,7 +582,16 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     — the one-request sibling of :func:`paged_decode_forward`'s per-row
     gather (``adapter_slot`` is this request's pool slot; q/o adapters
     leave the chunk's CACHED K/V adapter-agnostic by construction, so
-    prefix sharing stays valid across tenants)."""
+    prefix sharing stays valid across tenants).
+
+    ``window_table``: the slot's page ids in the SLIDING layers' pool,
+    by the same logical page index (entries of pages that slid out of
+    the window hold the trash page and are never read). Those layers'
+    temp cache holds one window of context, not ``ctx_cap``: the rows
+    ``[ctx_len - wcap, ctx_len)`` gathered from the window's pages, so
+    a chunk deep in a long prompt costs them what a shallow one does.
+    ``with_stats``: also return the summed ``_moe_ffn`` stats of the
+    chunk's valid rows."""
     B, C = tokens.shape
     if B != 1:
         raise ValueError(
@@ -423,39 +607,68 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     ctx_len = jnp.asarray(ctx_len, jnp.int32).reshape(())
     chunk_len = jnp.asarray(chunk_len, jnp.int32).reshape(())
     pad = ctx_cap - ctx_len                       # garbage rows below
+    sliding = "sliding" in cfg.period
+    # context rows a sliding layer's first chunk query can see: one
+    # window less itself, in whole pages, never more than the context
+    wcap = (min(ctx_cap, -(-(cfg.sliding_window - 1) // page) * page)
+            if sliding else 0)
     dense = init_cache(cfg, 1, W, kv_dtype="int8" if quant else None,
-                       num_kv_heads=paged["k"].shape[3])
+                       num_kv_heads=paged["k"].shape[3],
+                       window_len=wcap + C)
     if ctx_cap:
         ppc = ctx_cap // page
         ctx_tbl = block_table[:ppc]
         srows = jnp.clip(jnp.arange(ctx_cap, dtype=jnp.int32) - pad,
                          0, ctx_cap - 1)
-        for name in paged:
-            g = jnp.take(paged[name], ctx_tbl, axis=1)  # (L, ppc, pg, .)
-            g = g.reshape((g.shape[0], ppc * page) + g.shape[3:])
+        for name in _of_kind(paged, "full"):
+            g = _take_pages(paged[name], ctx_tbl)       # (L, ppc*pg, .)
             g = jnp.take(g, srows, axis=1)              # right-aligned
             dense[name] = dense[name].at[:, 0, :ctx_cap].set(
                 g.astype(dense[name].dtype))
+    if wcap:
+        # temp row t holds logical position ctx_len - wcap + t; the
+        # pages that cover them start at the window's first live page
+        lo = ctx_len - wcap
+        p0 = jnp.maximum(lo, 0) // page
+        npg = wcap // page + 1
+        win_tbl = jnp.take(window_table, jnp.clip(
+            p0 + jnp.arange(npg, dtype=jnp.int32), 0,
+            window_table.shape[0] - 1))
+        wrows = jnp.clip(lo - p0 * page
+                         + jnp.arange(wcap, dtype=jnp.int32),
+                         0, npg * page - 1)
+        for name in _of_kind(paged, "sliding"):
+            name += KIND_SUFFIX["sliding"]
+            g = jnp.take(_take_pages(paged[name], win_tbl), wrows, axis=1)
+            dense[name] = dense[name].at[:, 0, :wcap].set(
+                g.astype(dense[name].dtype))
     kstart = pad[None]                                  # (1,)
     rpos = (ctx_len + jnp.arange(C, dtype=jnp.int32))[None, :]
-    logits, dense = _forward_cached(params, tokens, dense, ctx_cap, cfg,
-                                    W, use_kernel=use_kernel, rpos=rpos,
-                                    kstart=kstart,
-                                    logits_at=chunk_len - 1,
-                                    tp_axis=tp_axis, dp_axis=dp_axis,
-                                    fused=bool(fused),
-                                    adapters=adapters,
-                                    adapter_slots=adapter_slot)
     pos = jnp.arange(C, dtype=jnp.int32)
+    out = _forward_cached(params, tokens, dense, ctx_cap, cfg,
+                          W, use_kernel=use_kernel, rpos=rpos,
+                          kstart=kstart, logits_at=chunk_len - 1,
+                          tp_axis=tp_axis, dp_axis=dp_axis,
+                          fused=bool(fused), adapters=adapters,
+                          adapter_slots=adapter_slot, pos_w=wcap,
+                          kstart_w=(jnp.maximum(wcap - ctx_len, 0)[None]
+                                    if sliding else None),
+                          moe_valid=(pos < chunk_len)[None, :],
+                          with_stats=with_stats)
+    logits, dense = out[0], out[1]
     logical = jnp.clip(ctx_len + pos, 0, ext - 1)
-    dst = jnp.where(pos < chunk_len,
-                    block_table[logical // page] * page + logical % page,
-                    0)
-    out = {}
-    for name in paged:
-        rows = dense[name][:, 0, ctx_cap:]              # (L, C, ...)
-        out[name] = _scatter_rows(paged[name], dst, rows)
-    return logits, out
+    new = {}
+    for kind in dict.fromkeys(cfg.period):
+        table = window_table if kind == "sliding" else block_table
+        at = wcap if kind == "sliding" else ctx_cap
+        dst = jnp.where(pos < chunk_len,
+                        table[logical // page] * page + logical % page,
+                        0)
+        for name in _of_kind(paged, kind):
+            name += KIND_SUFFIX[kind]
+            rows = dense[name][:, 0, at:]               # (L, C, ...)
+            new[name] = _scatter_rows(paged[name], dst, rows)
+    return (logits, new) + tuple(out[2:])
 
 
 def paged_verify_forward(params, tokens: jax.Array, paged: Dict,
@@ -525,6 +738,7 @@ def paged_verify_forward(params, tokens: jax.Array, paged: Dict,
     those nodes. Pools pass through untouched (the caller keeps its
     reference), so rejection needs no rollback at all."""
     B, T = tokens.shape
+    _refuse_sliding(cfg, "paged_verify_forward")
     tree = tree_depth is not None
     if tree and tree_mask is None:
         raise ValueError("paged_verify_forward: tree_depth requires "
@@ -679,7 +893,8 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                          block_tables: jax.Array, lengths: jax.Array,
                          cfg: LlamaConfig, *, active=None,
                          use_kernel=None, tp_axis=None, dp_axis=None,
-                         fused=None, adapters=None, adapter_slots=None):
+                         fused=None, adapters=None, adapter_slots=None,
+                         window_tables=None, with_stats=False):
     """One continuous-batching decode step over the ragged batch: every
     slot advances one token in a single static-shape program.
 
@@ -739,7 +954,16 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     sampling stays on replicated data outside the mesh. With
     ``cfg.moe`` set the dense SwiGLU is replaced by :func:`_moe_ffn`
     (expert-parallel over dp when the expert stacks arrive
-    E-sharded)."""
+    E-sharded).
+
+    ``window_tables`` (B, ppseq): each slot's page ids in the SLIDING
+    layers' pool, by logical page index like ``block_tables``; a
+    sliding layer writes and reads there, and its attention walks only
+    the pages a window back from the row's length (the kernel's and the
+    reference's ``window``). The fused decode kernel has no window, so
+    under ``fused`` those layers take the unfused call.
+    ``with_stats``: also return the ``_moe_ffn`` stats summed over
+    layers, counted over the ``active`` rows."""
     from ..ops.pallas import paged_attention as _pa
     from ..ops.pallas import serving_fused as _sf
     fused = bool(fused)
@@ -756,39 +980,57 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     aslot = asc = None
     if adapters is not None:
         aslot, asc = _adapter_prep(adapters, adapter_slots, cfg)
-    cos, sin = rope_tables(ext, cfg.hd, cfg.rope_theta)
+    period = cfg.period
+    plen = len(period)
+    kinds = tuple(dict.fromkeys(period))
+    if "sliding" in kinds and window_tables is None:
+        raise ValueError(
+            "paged_decode_forward: the config has sliding layers; "
+            "window_tables must give their pool's page ids")
+    tables = {"full": block_tables, "sliding": window_tables}
+    rope = llama.rope_tables_by_kind(cfg, ext)
     rpos = lengths[:, None]                          # (B, 1)
     if fused:
         # per-row rope table rows for the in-kernel rotation (the new
         # token sits at position ``lengths``, always < ext)
-        cos_row = jnp.take(cos, lengths, axis=0)     # (B, hd/2)
-        sin_row = jnp.take(sin, lengths, axis=0)
+        rope_row = {kind: (jnp.take(c, lengths, axis=0),
+                           jnp.take(s_, lengths, axis=0))
+                    for kind, (c, s_) in rope.items()}
     # per-row destination slot; inactive rows dump into the trash page
     # (page 0 slot 0 — reserved by serving.BlockAllocator) so a retired
     # slot's stale table can never clobber a live request's pages
     row = jnp.arange(B)
-    dst = jnp.where(active,
-                    block_tables[row, lengths // page] * page
-                    + lengths % page,
-                    0)
-    if dp_axis is not None:
-        # the FULL batch's destination slots, in single-chip row order
-        # (tiled concat over dp shards = the batch split's inverse);
-        # gathered ONCE here, closed over by every layer's scatter
-        dst = _tp_allgather(dst, dp_axis, 0)
+    dsts = {}
+    for kind in kinds:
+        dst = jnp.where(active,
+                        tables[kind][row, lengths // page] * page
+                        + lengths % page,
+                        0)
+        if dp_axis is not None:
+            # the FULL batch's destination slots, in single-chip row
+            # order (tiled concat over dp shards = the batch split's
+            # inverse); gathered ONCE here, closed over by every
+            # layer's scatter
+            dst = _tp_allgather(dst, dp_axis, 0)
+        dsts[kind] = dst
     x = jnp.take(params["embed"], tokens[:, None], axis=0).astype(
         cfg.dtype)                                   # (B, 1, H)
+    names = ("k", "v", "ks", "vs") if quant else ("k", "v")
+    # where in its kind's slice of a period a layer's pool sits
+    slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
 
-    def body(xc, layer_in):
-        layer_in = list(layer_in)
-        ad_l = None
-        if adapters is not None:
-            ad_l, layer_in = layer_in[-4:], layer_in[:-4]
-        if quant:
-            lp, kp, vp, ksp, vsp = layer_in
-        else:
-            lp, kp, vp = layer_in
-            ksp = vsp = None
+    def layer(xc, lp, kind, kp, vp, ksp, vsp, ad_l, at_layer, base):
+        # kp .. vsp: pages (P', page, ...) holding this layer's, either
+        # its own slice of the pool (``base`` None) or the whole pool of
+        # its kind with the layer's pages from page ``base`` on
+        cos, sin = rope[kind]
+        window = cfg.window_of(kind)
+        dst, table = dsts[kind], tables[kind]
+        if base is not None:
+            dst, table = dst + base * page, table + base
+        # the fused kernel has no window: a sliding layer's call is the
+        # unfused one
+        fuse = fused and window is None
         h1 = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
         q = h1 @ _w(lp, "wq", xc.dtype)
         if ad_l is not None:
@@ -796,11 +1038,12 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         q = q.reshape(B, 1, nh, hd)
         k = (h1 @ _w(lp, "wk", xc.dtype)).reshape(B, 1, nkv, hd)
         v = (h1 @ _w(lp, "wv", xc.dtype)).reshape(B, 1, nkv, hd)
-        if not fused:
+        if not fuse:
             # unfused: q rotates here in XLA and round-trips HBM into
             # the attention op; fused moves this rotation into VMEM
             q = _rope_rows(q, cos, sin, rpos)
         k = _rope_rows(k, cos, sin, rpos)
+
         def _pool_write(pool, rows):
             # dp shards scatter the FULL batch's rows (gathered in
             # shard order to match the full dst) into their pool
@@ -829,7 +1072,7 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         else:
             kp = _pool_write(kp, k[:, 0].astype(kp.dtype))
             vp = _pool_write(vp, v[:, 0].astype(vp.dtype))
-        if fused:
+        if fuse:
             # trace-time dispatch counter + bytes-saved estimate: the
             # rotated q's HBM write+read per layer (plus, on int8
             # tiers, the in-VMEM dequant the unfused reference pays as
@@ -839,13 +1082,14 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                 "decode_rope_attn",
                 2 * B * nh * hd * jnp.dtype(cfg.dtype).itemsize)
             o = _sf.fused_paged_decode_attention(
-                q[:, 0], cos_row, sin_row, kp, vp, block_tables,
+                q[:, 0], *rope_row[kind], kp, vp, table,
                 lengths + 1, ks_pages=ksp, vs_pages=vsp,
                 use_kernel=use_kernel)
         else:
             o = _pa.paged_attention(
-                q[:, 0], kp, vp, block_tables, lengths + 1,
-                ks_pages=ksp, vs_pages=vsp, use_kernel=use_kernel)
+                q[:, 0], kp, vp, table, lengths + 1,
+                ks_pages=ksp, vs_pages=vsp, use_kernel=use_kernel,
+                window=window)
         o = o.reshape(B, 1, nh * hd)
         if tp_axis is not None:
             o = _tp_allgather(o, tp_axis, 2)
@@ -859,9 +1103,13 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         else:
             xo = xc + ow
         h2 = rms_norm(xo, lp["mlp_norm"], cfg.rms_eps)
+        stats = None
         if cfg.moe is not None:
-            y = xo + _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
-                              dp_axis=dp_axis)
+            ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
+                                 dp_axis=dp_axis, valid=active[:, None],
+                                 experts=experts, layer=at_layer,
+                                 use_kernel=use_kernel)
+            y = xo + ff
         else:
             g = jax.nn.silu((h2 @ _w(lp, "wg", xc.dtype)).astype(
                 jnp.float32)).astype(xc.dtype)
@@ -872,17 +1120,75 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                                        tp_axis, 2)
             else:
                 y = xo + (g * u) @ _w(lp, "wd", xc.dtype)
-        return y, ((kp, vp, ksp, vsp) if quant else (kp, vp))
+        return y, (kp, vp, ksp, vsp), stats
 
-    xs = [params["layers"], paged["k"], paged["v"]]
-    if quant:
-        xs += [paged["ks"], paged["vs"]]
-    if adapters is not None:
-        xs += [adapters["aq"], adapters["bq"], adapters["ao"],
-               adapters["bo"]]
-    x, new = lax.scan(body, x, tuple(xs))
-    new_paged = ({"k": new[0], "v": new[1], "ks": new[2], "vs": new[3]}
-                 if quant else {"k": new[0], "v": new[1]})
+    scanned, experts = _split_experts(params["layers"])
+    pools = {kind: _of_kind(paged, kind) for kind in kinds}
+    lps, take_lp = _layer_feed(scanned, plen)
+    ads, take_ad = _layer_feed(
+        None if adapters is None else tuple(
+            adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
+    index = jnp.arange(cfg.num_layers // plen, dtype=jnp.int32)
+    if plen == 1:
+        # A plain decoder: the scan slices each layer's pool out of the
+        # stack and stacks the written slices again (the program the
+        # dense cells have run since PR 21).
+        def body(xc, xs):
+            lp, pool, ad_l, i = xs
+            xc, out, st = layer(xc, lp, period[0], pool["k"], pool["v"],
+                                pool.get("ks"), pool.get("vs"), ad_l, i,
+                                None)
+            return xc, (dict(zip(names, out)), st)
+        x, (new, stats) = lax.scan(
+            body, x, (lps, pools[period[0]], ads, index))
+        new = {period[0]: new}
+    else:
+        # Layers of two kinds: the pools ride in the scan's carry WHOLE,
+        # viewed as (layers * pages, page, ...): a layer writes its rows
+        # and the kernel reads its pages where they lie, by block tables
+        # moved up to the layer's first page, and no slice of a pool is
+        # copied out or back. Inside a period each run of one kind
+        # (:func:`_runs`) is a scan of its own over one layer body, so a
+        # program traces and lowers one body a run, not one a layer.
+        pool_pages = {kind: d["k"].shape[1] for kind, d in pools.items()}
+        zero = (jnp.zeros((3,), jnp.int32) if cfg.moe is not None
+                else None)
+
+        def body(carry, xs):
+            lps_i, ads_i, i = xs
+
+            def one(carry, j, kind, first):
+                # layer j of the period; ``first``: the period's first
+                # layer of this run, at slot ``slot_of[first]`` of its kind
+                xc, held, stats = carry
+                c = held[kind]
+                base = ((i * period.count(kind) + slot_of[first]
+                         + (j - first)) * pool_pages[kind])
+                xc, out, st = layer(
+                    xc, take_lp(lps_i, i, j), kind, c["k"], c["v"],
+                    c.get("ks"), c.get("vs"), take_ad(ads_i, i, j),
+                    i * plen + j, base)
+                held = {**held, kind: dict(zip(names, out))}
+                return xc, held, (stats if st is None else stats + st)
+
+            for kind, first, n in _runs(period):
+                if n == 1:
+                    carry = one(carry, first, kind, first)
+                else:
+                    carry, _ = lax.scan(
+                        lambda cr, j, kind=kind, first=first: (
+                            one(cr, j, kind, first), None),
+                        carry, first + jnp.arange(n, dtype=jnp.int32))
+            return carry, None
+        flat = {kind: {n: a.reshape((-1,) + a.shape[2:])
+                       for n, a in d.items()} for kind, d in pools.items()}
+        (x, new, stats), _ = lax.scan(body, (x, flat, zero),
+                                      (lps, ads, index))
+    if plen == 1 and stats is not None:
+        stats = jnp.sum(stats, axis=0)
+    new_paged = {n + KIND_SUFFIX[kind]: a.reshape(
+        paged[n + KIND_SUFFIX[kind]].shape)
+        for kind in kinds for n, a in new[kind].items()}
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if cfg.tie_embeddings:
         head = params["embed"].T.astype(x.dtype)    # replicated: full
@@ -897,6 +1203,8 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         # full-batch logits on every shard: sampling + constraint masks
         # stay on replicated data outside the mesh
         logits = _tp_allgather(logits, dp_axis, 0)
+    if with_stats:
+        return logits, new_paged, stats
     return logits, new_paged
 
 
@@ -980,7 +1288,7 @@ def _use_decode_kernel(override=None):
 
 def _attn_with_cache(q, ck, cv, length, nh, use_kernel=None,
                      kstart=None, k_rows=None, v_rows=None,
-                     fused=False, tree_mask=None):
+                     fused=False, tree_mask=None, window=None):
     """q (B,T,nh,hd) vs cache (B,Smax,nkv,hd); positions >= length masked.
     length: scalar or (B,) current valid length INCLUDING q's tokens.
     kstart: optional (B,) first VALID cache position per row (left-padded
@@ -1001,9 +1309,13 @@ def _attn_with_cache(q, ck, cv, length, nh, use_kernel=None,
     mask still applies); a linear-chain tree's matrix is exactly the
     lower triangle, reproducing this function's causal mask bit for
     bit. Requires the verify layout: static ``length`` == Smax (the
-    chunk is the last T cache rows)."""
+    chunk is the last T cache rows).
+    window: a sliding layer's — a query at cache position p sees the
+    keys at ``p - window < kpos <= p`` (the dense decode kernel has no
+    such bound, so a windowed layer takes the jnp or the flash path)."""
     B, T, _, hd = q.shape
-    if T == 1 and kstart is None and _use_decode_kernel(use_kernel):
+    if (T == 1 and kstart is None and window is None
+            and _use_decode_kernel(use_kernel)):
         # single-token decode: fused block attention against the padded
         # cache (reference: block_multi_head_attention_kernel.cu); int8
         # caches dequantize INSIDE the kernel
@@ -1028,7 +1340,7 @@ def _attn_with_cache(q, ck, cv, length, nh, use_kernel=None,
             "chunk_flash_attn", 2 * B * nh * T * ck.shape[1] * 4)
         return flash_chunk_attention(
             q, ck, cv, length, kstart, k_rows=k_rows, v_rows=v_rows,
-            use_kernel=use_kernel, tree_mask=tree_mask)
+            use_kernel=use_kernel, tree_mask=tree_mask, window=window)
     if k_rows is not None:
         # XLA fuses the dequant into the attention reads
         ck = (ck.astype(jnp.float32) * k_rows[..., None]).astype(q.dtype)
@@ -1046,7 +1358,11 @@ def _attn_with_cache(q, ck, cv, length, nh, use_kernel=None,
         # position
         qpos = (length - T) + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos <= qpos, s, -1e30)
+        if window is not None:
+            s = jnp.where(kpos > qpos - window, s, -1e30)
     else:
+        if window is not None:
+            raise ValueError("_attn_with_cache: tree_mask with a window")
         # tree verify: committed columns (below the chunk) stay fully
         # visible, chunk columns obey the ancestor matrix
         allow = jnp.concatenate(
@@ -1072,9 +1388,14 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
                  use_kernel=None, rpos=None, kstart=None,
                  cache_ks=None, cache_vs=None, tp_axis=None,
                  dp_axis=None, fused=False, ad_l=None, aslot=None,
-                 ascale=None, tree_mask=None):
+                 ascale=None, tree_mask=None, window=None,
+                 moe_valid=None, experts=None, layer=0):
     """One decoder layer over T tokens starting at cache index ``pos``.
-    cache_k/v: (B, Smax, nkv, hd) this layer's cache; returns updated.
+    cache_k/v: (B, Smax, nkv, hd) this layer's cache; returns
+    ``(x, cache_k, cache_v, cache_ks, cache_vs, moe stats or None)``.
+    window: the layer's sliding window (see :func:`_attn_with_cache`);
+    moe_valid: (B, T) rows the ``_moe_ffn`` stats count; experts,
+    layer: the expert stacks of all layers and which one this is.
     rpos: optional (B,T) per-row rope positions (!= cache index when the
     batch is left-padded); kstart: optional (B,) first valid cache slot.
     cache_ks/vs: (B, Smax, nkv) per-row dequant scales when the cache is
@@ -1139,7 +1460,7 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
                          use_kernel=use_kernel, kstart=kstart,
                          k_rows=cache_ks if quant else None,
                          v_rows=cache_vs if quant else None,
-                         fused=fused, tree_mask=tree_mask)
+                         fused=fused, tree_mask=tree_mask, window=window)
     o = o.reshape(B, T, nh * hd)
     if tp_axis is not None:
         # full heads before the (column-sharded) wo contraction, then
@@ -1156,25 +1477,29 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
     if cfg.moe is not None:
         # serving MoE FFN (ISSUE 17): dense-dispatch on a single chip,
         # expert-parallel over dp when the stacks arrive E-sharded
-        return (x + _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
-                             dp_axis=dp_axis),
-                cache_k, cache_v, cache_ks, cache_vs)
+        ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
+                             dp_axis=dp_axis, valid=moe_valid,
+                             experts=experts, layer=layer,
+                             use_kernel=use_kernel)
+        return x + ff, cache_k, cache_v, cache_ks, cache_vs, stats
     g = jax.nn.silu((h2 @ _w(lp, "wg", x.dtype)).astype(
         jnp.float32)).astype(x.dtype)
     u = h2 @ _w(lp, "wu", x.dtype)
     if tp_axis is not None:
         gu = _tp_allgather(g * u, tp_axis, 2)
         ff = _tp_allgather(gu @ _w(lp, "wd", x.dtype), tp_axis, 2)
-        return x + ff, cache_k, cache_v, cache_ks, cache_vs
+        return x + ff, cache_k, cache_v, cache_ks, cache_vs, None
     return (x + (g * u) @ _w(lp, "wd", x.dtype), cache_k, cache_v,
-            cache_ks, cache_vs)
+            cache_ks, cache_vs, None)
 
 
 def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
                     max_len: int, use_kernel=None, rpos=None,
                     kstart=None, logits_at=None, logits_all=False,
                     tp_axis=None, dp_axis=None, fused=False,
-                    adapters=None, adapter_slots=None, tree_mask=None):
+                    adapters=None, adapter_slots=None, tree_mask=None,
+                    pos_w=None, kstart_w=None, moe_valid=None,
+                    with_stats=False):
     """tokens (B, T) at cache positions [pos, pos+T) -> (logits_last
     (B, V), updated cache). ``logits_at``: optional TRACED row index
     into ``tokens`` — logits are taken there instead of at row T-1
@@ -1184,41 +1509,101 @@ def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
     the greedy target at all draft positions. ``tp_axis``: run as one
     shard of a tensor-parallel serving mesh (see :func:`_block_infer`);
     the vocab-sharded lm_head's partial logits all-gather at the end —
-    the single logits collective the tp decode path pays."""
+    the single logits collective the tp decode path pays.
+
+    ``cache`` holds one set of arrays a layer kind (:func:`init_cache`).
+    ``pos_w`` / ``kstart_w``: where the tokens sit in the SLIDING
+    layers' arrays and those arrays' first valid slot, when they differ
+    from ``pos`` / ``kstart`` (the chunk program's narrower window
+    cache); rope positions are the same for both. ``with_stats``: a
+    third result, the ``_moe_ffn`` stats summed over layers, counted
+    over the rows ``moe_valid`` marks."""
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-    cos, sin = rope_tables(max_len, cfg.hd, cfg.rope_theta)
+    period = cfg.period
+    plen = len(period)
+    layers_n = cfg.num_layers
+    kinds = tuple(dict.fromkeys(period))
+    rope = llama.rope_tables_by_kind(cfg, max_len)
+    at = {"full": (pos, kstart),
+          "sliding": (pos if pos_w is None else pos_w,
+                      kstart if kstart_w is None else kstart_w)}
     quant = "ks" in cache
+    names = ("k", "v", "ks", "vs") if quant else ("k", "v")
+    slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
     aslot = asc = None
     if adapters is not None:
         aslot, asc = _adapter_prep(adapters, adapter_slots, cfg)
 
-    def body(carry, layer_in):
-        xc = carry
-        layer_in = list(layer_in)
-        ad_l = None
-        if adapters is not None:
-            ad_l, layer_in = layer_in[-4:], layer_in[:-4]
-        if quant:
-            lp, ck, cv, cks, cvs = layer_in
-        else:
-            lp, ck, cv = layer_in
-            cks = cvs = None
-        y, nk, nv, nks, nvs = _block_infer(
-            xc, lp, ck, cv, pos, cos, sin, cfg, use_kernel=use_kernel,
-            rpos=rpos, kstart=kstart, cache_ks=cks, cache_vs=cvs,
-            tp_axis=tp_axis, dp_axis=dp_axis, fused=fused, ad_l=ad_l,
-            aslot=aslot, ascale=asc, tree_mask=tree_mask)
-        return y, ((nk, nv, nks, nvs) if quant else (nk, nv))
+    def body(xc, xs):
+        # one period: its runs of one layer kind in order (:func:`_runs`),
+        # each against its kind's cache; a run of several layers is a
+        # scan of its own over one layer body
+        lps_i, caches, ads_i, i = xs
 
-    xs = [params["layers"], cache["k"], cache["v"]]
-    if quant:
-        xs += [cache["ks"], cache["vs"]]
-    if adapters is not None:
-        xs += [adapters["aq"], adapters["bq"], adapters["ao"],
-               adapters["bo"]]
-    x, new = lax.scan(body, x, tuple(xs))
-    new_cache = ({"k": new[0], "v": new[1], "ks": new[2], "vs": new[3]}
-                 if quant else {"k": new[0], "v": new[1]})
+        def one(xc, j, kind, c):
+            xc, *out, st = _block_infer(
+                xc, take_lp(lps_i, i, j), c["k"], c["v"],
+                at[kind][0], *rope[kind], cfg, use_kernel=use_kernel,
+                rpos=rpos, kstart=at[kind][1], cache_ks=c.get("ks"),
+                cache_vs=c.get("vs"), tp_axis=tp_axis, dp_axis=dp_axis,
+                fused=fused, ad_l=take_ad(ads_i, i, j), aslot=aslot,
+                ascale=asc, tree_mask=tree_mask,
+                window=cfg.window_of(kind), moe_valid=moe_valid,
+                experts=experts, layer=i * plen + j)
+            return xc, dict(zip(names, out)), st
+
+        if plen == 1:
+            xc, new, stats = one(xc, 0, period[0], caches[period[0]])
+            new = {period[0]: new}
+        else:
+            new = {kind: {n: [] for n in names} for kind in kinds}
+            stats = None
+            for kind, first, count in _runs(period):
+                mine = {n: a[slot_of[first]:slot_of[first] + count]
+                        for n, a in caches[kind].items()}
+                if count == 1:
+                    xc, out, st = one(xc, first, kind,
+                                      {n: a[0] for n, a in mine.items()})
+                    out = {n: a[None] for n, a in out.items()}
+                else:
+                    def step(carry, xs_, kind=kind):
+                        xc, out, st = one(carry[0], xs_[0], kind, xs_[1])
+                        return (xc, None if st is None
+                                else carry[1] + st), out
+                    (xc, st), out = lax.scan(
+                        step,
+                        (xc, None if cfg.moe is None
+                         else jnp.zeros((3,), jnp.int32)),
+                        (first + jnp.arange(count, dtype=jnp.int32), mine))
+                for n in names:
+                    new[kind][n].append(out[n])
+                if st is not None:
+                    stats = st if stats is None else stats + st
+            new = {kind: {n: (a[0] if len(a) == 1 else jnp.concatenate(a))
+                          for n, a in d.items()} for kind, d in new.items()}
+        return xc, (new if stats is None else (new, stats))
+
+    def stacked(kind, a):
+        return _by_period(a, period.count(kind), plen)
+
+    scanned, experts = _split_experts(params["layers"])
+    lps, take_lp = _layer_feed(scanned, plen)
+    ads, take_ad = _layer_feed(
+        None if adapters is None else tuple(
+            adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
+    xs = (lps,
+          {kind: {n: stacked(kind, a)
+                  for n, a in _of_kind(cache, kind).items()}
+           for kind in kinds},
+          ads, jnp.arange(layers_n // plen, dtype=jnp.int32))
+    x, new = lax.scan(body, x, xs)
+    stats = None
+    if cfg.moe is not None:
+        new, stats = new[0], jnp.sum(new[1], axis=0)
+    new_cache = {n + KIND_SUFFIX[kind]: a.reshape(
+        cache[n + KIND_SUFFIX[kind]].shape)
+        for kind in kinds for n, a in new[kind].items()}
+    extra = (stats,) if with_stats else ()
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if logits_at is not None:
         idx = jnp.clip(jnp.asarray(logits_at, jnp.int32).reshape(()),
@@ -1236,11 +1621,11 @@ def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
         logits = (x @ head).astype(jnp.float32)
         if gather:
             logits = _tp_allgather(logits, tp_axis, 2)
-        return logits, new_cache
+        return (logits, new_cache) + extra
     logits = (x[:, -1] @ head).astype(jnp.float32)
     if gather:
         logits = _tp_allgather(logits, tp_axis, 1)
-    return logits, new_cache
+    return (logits, new_cache) + extra
 
 
 def precompute_prompt_cache(params, prefix: jax.Array, cfg: LlamaConfig, *,

@@ -39,6 +39,25 @@ from . import moe as _moe
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (the published ``rope_parameters`` keys of the
+    same names): below the ``beta_fast`` correction dimension a frequency
+    is kept, above the ``beta_slow`` one it is divided by ``factor``, a
+    linear ramp blends the two between, and cos and sin are both
+    multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` when the
+    file gives none)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+#: the two kinds of attention layer a ``layer_pattern`` may name
+LAYER_KINDS = ("sliding", "full")
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -64,10 +83,52 @@ class LlamaConfig:
     # sweep (tools/perf_sweep.py b4_pallas) showing >= parity.
     fused_kernels: str = "xla"
     moe: Optional["_moe.MoEConfig"] = None  # experts replace the dense MLP
+    # layers that differ by position: ONE period of attention kinds
+    # ("sliding" / "full"), repeated num_layers / len(layer_pattern)
+    # times. Weights stay stacked (L, ...); the trunk and the serving
+    # forwards scan over periods with the period's layers unrolled
+    # inside. None is a period of one full layer: the plain decoder.
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    # keys a sliding layer's query sees, ITSELF INCLUDED: i - j < window
+    sliding_window: Optional[int] = None
+    # rotary parameters by layer kind: sliding layers rotate by
+    # ``rope_theta_sliding`` (None: ``rope_theta``) unscaled, full
+    # layers by ``rope_theta`` under ``yarn`` when it is given
+    rope_theta_sliding: Optional[float] = None
+    yarn: Optional[YarnRope] = None
+
+    def __post_init__(self):
+        pat = self.layer_pattern
+        if pat is None:
+            return
+        if not pat or any(k not in LAYER_KINDS for k in pat):
+            raise ValueError(
+                f"layer_pattern={pat!r}: a period names each layer "
+                f"'sliding' or 'full'")
+        if self.num_layers % len(pat):
+            raise ValueError(
+                f"num_layers={self.num_layers} is not a whole number of "
+                f"periods of {len(pat)} layers")
+        if "sliding" in pat and not self.sliding_window:
+            raise ValueError(
+                "layer_pattern names sliding layers: sliding_window "
+                "must say how many keys they see")
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The kinds of one period's layers, in order."""
+        return tuple(self.layer_pattern) if self.layer_pattern else ("full",)
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == "sliding" else None
+
+    def kind_layers(self, kind: str) -> int:
+        """Layers of this kind in the whole model."""
+        return (self.num_layers // len(self.period)) * self.period.count(kind)
 
     # ---- presets (sizes follow the public Llama-2 family) ----
     @staticmethod
@@ -439,11 +500,59 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
 
 
 def rope_tables(seq_len: int, hd: int, theta: float,
-                dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:
+                dtype=jnp.float32, yarn: Optional[YarnRope] = None
+                ) -> Tuple[jax.Array, jax.Array]:
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    scale = None
+    if yarn is not None:
+        # the dimension at which ``r`` rotations fit the original context
+        def dim_of(r):
+            return hd * math.log(yarn.original_max_position_embeddings
+                                 / (r * 2 * math.pi)) / (2 * math.log(theta))
+        low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+        high = min(math.ceil(dim_of(yarn.beta_slow)), hd - 1)
+        ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / yarn.factor * ramp + inv * (1.0 - ramp)
+        scale = (yarn.attention_factor if yarn.attention_factor is not None
+                 else 0.1 * math.log(yarn.factor) + 1.0)
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)                      # (S, hd/2)
-    return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    return cos.astype(dtype), sin.astype(dtype)
+
+
+def rope_tables_by_kind(cfg: LlamaConfig, seq_len: int
+                        ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    """cos and sin for each kind of layer in the config's period."""
+    out = {}
+    for kind in dict.fromkeys(cfg.period):
+        if kind == "sliding":
+            out[kind] = rope_tables(seq_len, cfg.hd,
+                                    cfg.rope_theta_sliding or cfg.rope_theta)
+        else:
+            out[kind] = rope_tables(seq_len, cfg.hd, cfg.rope_theta,
+                                    yarn=cfg.yarn)
+    return out
+
+
+def period_stack(tree, period_len: int):
+    """Stacked layer leaves ``(L, ...)`` as ``(L / p, p, ...)``, for a
+    scan over periods; a period of one is the tree itself."""
+    if period_len == 1:
+        return tree
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // period_len, period_len)
+                            + a.shape[1:]), tree)
+
+
+def period_layer(tree, j: int, period_len: int):
+    """Layer ``j`` of one period's slice of :func:`period_stack`."""
+    if period_len == 1:
+        return tree
+    return jax.tree.map(lambda a: a[j], tree)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -457,13 +566,15 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
-def _attention(q, k, v, causal=True, mesh_axes=None):
+def _attention(q, k, v, causal=True, mesh_axes=None, window=None):
     """(B,S,H,hd) attention; Pallas flash where
     :func:`~paddle_tpu.ops.pallas.flash_attention.flash_eligible` says
     so, fused jnp elsewhere. Under a mesh the kernel runs per shard
     (batch over the data axes, heads over tp): Mosaic kernels are not
-    partitioned by GSPMD, and attention needs no cross-shard traffic."""
-    if _fa.flash_eligible(q.shape[1], q.shape[-1]):
+    partitioned by GSPMD, and attention needs no cross-shard traffic.
+    A sliding ``window`` takes the jnp path: the flash kernel has no
+    lower bound on its keys."""
+    if window is None and _fa.flash_eligible(q.shape[1], q.shape[-1]):
         attn = partial(_fa.flash_attention, causal=causal)
         if mesh_axes is not None:
             mesh = mesh_axes["mesh"]
@@ -478,12 +589,13 @@ def _attention(q, k, v, causal=True, mesh_axes=None):
                 in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
         return attn(q, k, v)
-    return _attention_jnp(q, k, v, causal)
+    return _attention_jnp(q, k, v, causal, window)
 
 
-def _attention_jnp(q, k, v, causal=True):
+def _attention_jnp(q, k, v, causal=True, window=None):
     """The fused-softmax jnp attention: the path off the chip, and the
-    reference the flash kernel is checked against on it."""
+    reference the flash kernel is checked against on it. ``window``:
+    query i sees keys j with ``i - j < window`` only."""
     b, sq, h, hd = q.shape
     hk = k.shape[2]
     if hk != h:
@@ -494,16 +606,19 @@ def _attention_jnp(q, k, v, causal=True):
                    preferred_element_type=jnp.float32) * scale
     if causal:
         mask = jnp.tril(jnp.ones((sq, sq), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sq), bool), -window)
         s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None):
+def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None,
+           window=None):
     """One decoder layer. lp = per-layer params (no leading L axis).
     ``attn_axes``: mesh axes for the per-shard flash kernel alone, for a
     caller that places activations itself (the pipeline stages);
-    defaults to ``mesh_axes``."""
+    defaults to ``mesh_axes``. ``window``: the layer's sliding window."""
     B, S, H = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
 
@@ -547,8 +662,8 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None):
         o = attn(q, k, v).reshape(B, S, nh * hd)
     else:
         o = _attention(q, k, v, causal=True,
-                       mesh_axes=attn_axes or mesh_axes).reshape(
-                           B, S, nh * hd)
+                       mesh_axes=attn_axes or mesh_axes,
+                       window=window).reshape(B, S, nh * hd)
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "attn_out")
     x = sp(x + o @ lp["wo"])
@@ -593,25 +708,36 @@ def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
             x, NamedSharding(mesh_axes["mesh"],
                              P(mesh_axes["data"], mesh_axes.get("cp"),
                                mesh_axes["tp"])))
-    cos, sin = rope_tables(S, cfg.hd, cfg.rope_theta)
+    period = cfg.period
+    tables = rope_tables_by_kind(cfg, S)
 
-    def block(carry, lp):
-        return _block(carry, lp, cos, sin, cfg, mesh_axes)
+    def block(kind):
+        def f(carry, lp):
+            return _block(carry, lp, *tables[kind], cfg, mesh_axes,
+                          window=cfg.window_of(kind))
+        if cfg.remat:
+            policies = {
+                "nothing": jax.checkpoint_policies.nothing_saveable,
+                "attn": jax.checkpoint_policies.save_only_these_names(
+                    "attn_out"),
+                "dots":
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            }
+            f = jax.checkpoint(f, policy=policies[cfg.remat_policy])
+        return f
 
-    if cfg.remat:
-        policies = {
-            "nothing": jax.checkpoint_policies.nothing_saveable,
-            "attn": jax.checkpoint_policies.save_only_these_names(
-                "attn_out"),
-            "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        }
-        block = jax.checkpoint(block, policy=policies[cfg.remat_policy])
+    blocks = {kind: block(kind) for kind in tables}
 
-    def body(carry, lp):
-        x, aux = block(carry, lp)
+    def body(x, lps):
+        # one period: its layers in order, each of its own kind
+        aux = jnp.float32(0.0)
+        for j, kind in enumerate(period):
+            x, a = blocks[kind](x, period_layer(lps, j, len(period)))
+            aux = a if len(period) == 1 else aux + a
         return x, aux
 
-    x, auxs = jax.lax.scan(body, x, params["layers"])
+    x, auxs = jax.lax.scan(body, x,
+                           period_stack(params["layers"], len(period)))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps,
                  pallas=_pallas_fused(cfg))
     return x, jnp.sum(auxs)

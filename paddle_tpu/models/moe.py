@@ -12,6 +12,13 @@ variable-length NCCL alltoall. Experts carry a leading E axis sharded over
 the "ep" mesh axis; the dispatch einsum reshards tokens→experts and XLA
 lowers it to AllToAll over ICI. Router in fp32; top-1 (Switch) and top-2
 (GShard) with load-balance aux loss + router z-loss.
+
+This module is the TRAINER's expert layer (``llama._block`` calls
+``moe_ffn``): a token over an expert's capacity is dropped, and the loop
+over k is written for small k. The SERVING path does not come here:
+``models/generate.py:_moe_ffn`` routes without capacity or drops and
+applies the experts as one grouped matmul a projection. The two agree
+where nothing is dropped (``capacity_factor >= num_experts / top_k``).
 """
 from __future__ import annotations
 
@@ -25,6 +32,9 @@ from jax.sharding import PartitionSpec as P
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``num_experts`` and ``top_k`` are the model's and both paths read
+    them; capacity and the loss weights are the trainer's router's alone
+    (serving never drops a token)."""
     num_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25

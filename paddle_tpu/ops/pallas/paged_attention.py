@@ -84,7 +84,8 @@ def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
-                              *, scale=None, ks_pages=None, vs_pages=None):
+                              *, scale=None, ks_pages=None, vs_pages=None,
+                              window=None):
     """Pure-lax paged decode attention (CPU tier-1 semantics anchor).
 
     q:            (B, H, D) single-token queries
@@ -92,6 +93,10 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
     block_tables: (B, ppseq) int32 page ids (logical-position order)
     lengths:      (B,) valid lengths INCLUDING the current token
     ks/vs_pages:  (P, page, HK) per-row dequant scales for int8 pools
+    window:       a sliding layer's: the query (position ``length - 1``)
+                  sees the keys at ``kpos > length - 1 - window`` only;
+                  table entries of pages wholly below that may hold any
+                  page id (the allocator's trash page)
 
     The math after the gather is kept OP-FOR-OP identical to
     ``models/generate._attn_with_cache`` so a paged decode is
@@ -123,6 +128,8 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
     kpos = lax.broadcasted_iota(jnp.int32, s.shape, 3)
     qpos = (lengths[:, None, None, None] - 1)
     s = jnp.where(kpos <= qpos, s, -1e30)
+    if window is not None:
+        s = jnp.where(kpos > qpos - window, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(cv.dtype), cv)
     return o[:, 0]                                 # (B, H, D)
@@ -157,7 +164,7 @@ def _in_hbm(pool):
 
 
 def _paged_kernel(bt_ref, cnt_ref, len_ref, q_ref, off_ref, k_hbm, v_hbm,
-                  *rest, scale, page, G, quant):
+                  *rest, scale, page, G, quant, window):
     """One request row per grid step; inside it a loop over the row's
     LIVE page groups, ``ceil(cnt/G)`` of them.
 
@@ -184,7 +191,13 @@ def _paged_kernel(bt_ref, cnt_ref, len_ref, q_ref, off_ref, k_hbm, v_hbm,
 
     The buffers are zeroed once, so what a partial group leaves in the
     slots it did not fetch is zeros or an earlier page's finite data,
-    and ``p == 0`` there contributes nothing."""
+    and ``p == 0`` there contributes nothing.
+
+    ``window`` (static; None for a full layer): the wrapper has already
+    moved the row's table and length to start at the window's first
+    live page, so the loop walks the window's pages alone; the mask
+    adds the lower bound ``position >= length - window`` inside that
+    first page."""
     if quant:
         ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, slot_ref = rest
         streams = ((k_hbm, kbuf), (v_hbm, vbuf),
@@ -239,7 +252,10 @@ def _paged_kernel(bt_ref, cnt_ref, len_ref, q_ref, off_ref, k_hbm, v_hbm,
                 preferred_element_type=jnp.float32) * scale
             if quant:
                 s = s * ksbuf[slot, g][:, :rows]
-            live = (j * G + g) * page + off < length
+            pos = (j * G + g) * page + off
+            live = pos < length
+            if window is not None:
+                live &= pos >= length - window
             ss.append(jnp.where(live, s, _fa.DEFAULT_MASK_VALUE))
             lives.append(live)
         m_cur = functools.reduce(
@@ -270,7 +286,8 @@ def _paged_kernel(bt_ref, cnt_ref, len_ref, q_ref, off_ref, k_hbm, v_hbm,
 
 
 def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
-                           scale=None, ks_pages=None, vs_pages=None):
+                           scale=None, ks_pages=None, vs_pages=None,
+                           window=None):
     """Pallas paged decode attention; same contract as
     :func:`paged_attention_reference` (pool layout (P, page, HK, D),
     per-row int8 scales (P, page, HK)), with work and HBM reads sized by
@@ -282,7 +299,12 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     A group's keys enter one softmax step, so the f32 sums run in
     another order than the reference's: the results agree to
     ``rtol = atol = 2e-5`` in f32, not bit for bit. A row of length 0
-    attends to nothing and returns zeros."""
+    attends to nothing and returns zeros.
+
+    ``window``: a sliding layer's (static). Each row's table is cut to
+    the ``ceil(window/page) + 1`` entries from the window's first live
+    page on and its length counted from there, so the same body reads
+    only the window's pages, whatever the row's context."""
     B, H, D = q.shape
     P, page, HK = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     assert H % HK == 0
@@ -296,6 +318,13 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     quant = ks_pages is not None
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)  # clamp -1
+    if window is not None:
+        first = jnp.maximum(lengths - window, 0) // page       # (B,)
+        ppseq = min(ppseq, -(-window // page) + 1)
+        bt = jnp.take_along_axis(bt, jnp.minimum(
+            first[:, None] + jnp.arange(ppseq, dtype=jnp.int32)[None, :],
+            bt.shape[1] - 1), axis=1)
+        lengths = lengths - first * page
     # live pages per row; >= 1 so every row has a group to finish on
     cnt = jnp.clip(-(-lengths // page), 1, ppseq).astype(jnp.int32)
 
@@ -328,7 +357,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
 
     return pl.pallas_call(
         functools.partial(_paged_kernel, scale=s, page=page, G=G,
-                          quant=quant),
+                          quant=quant, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B,), in_specs=in_specs,
             out_specs=row_block, scratch_shapes=scratch),
@@ -340,7 +369,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     scale=None, ks_pages=None, vs_pages=None,
-                    use_kernel=None):
+                    use_kernel=None, window=None):
     """Paged decode attention: Pallas kernel on real TPU (or when forced
     — interpret mode in tests), pure-lax gather fallback elsewhere so
     tier-1 CPU runs exercise dense-decode-identical numerics."""
@@ -349,7 +378,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     if use_kernel:
         return paged_attention_kernel(
             q, k_pages, v_pages, block_tables, lengths, scale=scale,
-            ks_pages=ks_pages, vs_pages=vs_pages)
+            ks_pages=ks_pages, vs_pages=vs_pages, window=window)
     return paged_attention_reference(
         q, k_pages, v_pages, block_tables, lengths, scale=scale,
-        ks_pages=ks_pages, vs_pages=vs_pages)
+        ks_pages=ks_pages, vs_pages=vs_pages, window=window)

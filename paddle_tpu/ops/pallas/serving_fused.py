@@ -280,7 +280,8 @@ def fused_paged_decode_attention(q, cos_row, sin_row, k_pages, v_pages,
 
 def _chunk_softmax_step(q, k, v, kstart, o_ref, acc, m_sc, l_sc, *,
                         scale, block_k, rep, qoff, seq_len,
-                        k_scale=None, v_scale=None, anc=None):
+                        k_scale=None, v_scale=None, anc=None,
+                        window=None):
     """Online-softmax step for MULTI-TOKEN queries against one
     (block_k, D) cache block: query row r (= t*rep + h_rep) attends to
     columns ``kstart <= col <= qoff + t`` — the exact masks of
@@ -319,6 +320,8 @@ def _chunk_softmax_step(q, k, v, kstart, o_ref, acc, m_sc, l_sc, *,
         qpos = qoff + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0) // rep
         ok = (cols <= qpos) & (cols >= kstart)
+        if window is not None:
+            ok &= cols > qpos - window
     else:
         # tree verify: select each query row's ancestor bitmask (T is
         # small and static — an unrolled select chain, no gather), then
@@ -354,17 +357,17 @@ def _chunk_softmax_step(q, k, v, kstart, o_ref, acc, m_sc, l_sc, *,
 
 
 def _chunk_kernel(q_ref, k_ref, v_ref, kst_ref, o_ref, acc, m_sc, l_sc,
-                  *, scale, block_k, rep, qoff, seq_len):
+                  *, scale, block_k, rep, qoff, seq_len, window=None):
     _chunk_softmax_step(q_ref[0], k_ref[0], v_ref[0],
                         kst_ref[pl.program_id(0)],
                         o_ref, acc, m_sc, l_sc, scale=scale,
                         block_k=block_k, rep=rep, qoff=qoff,
-                        seq_len=seq_len)
+                        seq_len=seq_len, window=window)
 
 
 def _chunk_kernel_rowq(q_ref, k_ref, v_ref, sk_ref, sv_ref, kst_ref,
                        o_ref, acc, m_sc, l_sc, *, scale, block_k, rep,
-                       qoff, seq_len):
+                       qoff, seq_len, window=None):
     """int8 temp-cache variant: per-row dequant scales ride (block_k, 1)
     VMEM blocks and broadcast over D — the dequanted fp copy of the
     gathered context never reaches HBM."""
@@ -373,7 +376,7 @@ def _chunk_kernel_rowq(q_ref, k_ref, v_ref, sk_ref, sv_ref, kst_ref,
                         o_ref, acc, m_sc, l_sc, scale=scale,
                         block_k=block_k, rep=rep, qoff=qoff,
                         seq_len=seq_len, k_scale=sk_ref[0],
-                        v_scale=sv_ref[0])
+                        v_scale=sv_ref[0], window=window)
 
 
 def _chunk_kernel_tree(q_ref, k_ref, v_ref, kst_ref, anc_ref, o_ref,
@@ -402,7 +405,8 @@ def _chunk_kernel_rowq_tree(q_ref, k_ref, v_ref, sk_ref, sv_ref,
 
 def flash_chunk_attention_reference(q, ck, cv, length, kstart, *,
                                     scale=None, k_rows=None,
-                                    v_rows=None, tree_mask=None):
+                                    v_rows=None, tree_mask=None,
+                                    window=None):
     """Pure-lax reference — op-for-op the jnp composition of
     ``generate._attn_with_cache`` (same einsums, f32 accumulation,
     -1e30 masks, dequant-then-cast), so the CPU fallback is
@@ -410,8 +414,11 @@ def flash_chunk_attention_reference(q, ck, cv, length, kstart, *,
     optional (B, T, T) ancestor-or-self matrix replacing the
     intra-chunk causal triangle for TREE verify (committed columns
     below the chunk stay fully visible; a chain tree reproduces the
-    causal mask exactly)."""
+    causal mask exactly). ``window``: a sliding layer's lower bound,
+    ``kpos > qpos - window``; not with a tree."""
     B, T, H, D = q.shape
+    if window is not None and tree_mask is not None:
+        raise ValueError("flash_chunk_attention: tree_mask with a window")
     if (k_rows is None) != (v_rows is None):
         raise ValueError(
             "flash_chunk_attention: k_rows and v_rows must be passed "
@@ -430,6 +437,8 @@ def flash_chunk_attention_reference(q, ck, cv, length, kstart, *,
     if tree_mask is None:
         qpos = (length - T) + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos <= qpos, s, -1e30)
+        if window is not None:
+            s = jnp.where(kpos > qpos - window, s, -1e30)
     else:
         Smax = ck.shape[1]
         allow = jnp.concatenate(
@@ -444,7 +453,8 @@ def flash_chunk_attention_reference(q, ck, cv, length, kstart, *,
 
 def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
                                  scale=None, k_rows=None, v_rows=None,
-                                 block_k: int = 512, tree_mask=None):
+                                 block_k: int = 512, tree_mask=None,
+                                 window=None):
     """Pallas flash attention for the multi-token serving programs.
 
     q:       (B, T, H, D) rotated chunk queries
@@ -462,8 +472,25 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
     packs into per-node int32 BITMASKS riding SMEM next to ``kstart``
     (hence T <= 32 in tree mode — comb trees are shallow and narrow),
     and only the mask predicate changes inside the step.
+
+    window: a sliding layer's (static). The key loop is bounded below
+    as well as above: the cache is cut to the blocks from the one that
+    holds the first query's oldest key (``qoff - window + 1``) on, and
+    the mask adds ``col > qpos - window``.
     """
     B, T, H, D = q.shape
+    if window is not None:
+        if tree_mask is not None:
+            raise ValueError(
+                "flash_chunk_attention: tree_mask with a window")
+        bk0 = min(block_k, ck.shape[1])
+        cut = max(int(length) - T - window + 1, 0) // bk0 * bk0
+        if cut:
+            ck, cv = ck[:, cut:], cv[:, cut:]
+            if k_rows is not None:
+                k_rows, v_rows = k_rows[:, cut:], v_rows[:, cut:]
+            length = int(length) - cut
+            kstart = jnp.maximum(jnp.asarray(kstart, jnp.int32) - cut, 0)
     W, HK = ck.shape[1], ck.shape[2]
     assert H % HK == 0
     rep = H // HK
@@ -503,6 +530,8 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
             _chunk_kernel_rowq_tree
     else:
         kernel_plain, kernel_quant = _chunk_kernel, _chunk_kernel_rowq
+        if window is not None:
+            tkw = {"window": window}
     if quant:
         def rows(sc):   # (B, W, HK) -> (B*HK, W, 1)
             return jnp.asarray(sc, jnp.float32).transpose(
@@ -551,7 +580,7 @@ def flash_chunk_attention_kernel(q, ck, cv, length, kstart, *,
 
 def flash_chunk_attention(q, ck, cv, length, kstart, *, scale=None,
                           k_rows=None, v_rows=None, use_kernel=None,
-                          tree_mask=None):
+                          tree_mask=None, window=None):
     """Dispatcher for the multi-token serving attention: Pallas flash
     kernel on real TPU or when forced (interpret mode in tests),
     pure-lax reference — bit-identical to the unfused
@@ -564,7 +593,7 @@ def flash_chunk_attention(q, ck, cv, length, kstart, *, scale=None,
     if use_kernel:
         return flash_chunk_attention_kernel(
             q, ck, cv, length, kstart, scale=scale, k_rows=k_rows,
-            v_rows=v_rows, tree_mask=tree_mask)
+            v_rows=v_rows, tree_mask=tree_mask, window=window)
     return flash_chunk_attention_reference(
         q, ck, cv, length, kstart, scale=scale, k_rows=k_rows,
-        v_rows=v_rows, tree_mask=tree_mask)
+        v_rows=v_rows, tree_mask=tree_mask, window=window)

@@ -422,7 +422,7 @@ class ReplicaNode:
             # the balanced-allocator gate (chaos soak): standing
             # prefix-trie pages are intentionally resident — release
             # them so num_used == 0 is assertable after a drain
-            cache.prefix.drop_all(alloc)
+            cache.prefix.drop_all(cache._trie_alloc)
         out["allocator"] = alloc.stats()
         if self.fabric is not None:
             out["fabric_client"] = {

@@ -29,6 +29,15 @@ jitted prefill/decode programs. Page id 0 is RESERVED as the trash page:
 the single jitted ragged-decode program runs every slot each step with
 static shapes, and retired/empty slots route their (masked, garbage)
 KV writes there instead of clobbering live pages.
+
+A config whose layers mix sliding-window and full attention
+(``LlamaConfig.layer_pattern``) has TWO pools, one per layer kind, each
+with an allocator and a block table a row of its own
+(:class:`PagedKVCache`). The full layers' is what the paragraphs above
+describe. The sliding layers' holds a window and a chunk a row: a page is
+granted when the context first reaches it and goes back to the free list
+in the commit of the step that slid the window past it, so a 10k-token
+row holds the same twenty-odd pages there as a 2k-token one.
 """
 from __future__ import annotations
 
@@ -491,6 +500,40 @@ class PrefixCache:
             stack.extend(node.children.values())
 
 
+class _TwinAllocator:
+    """What the prefix trie is handed as its allocator by a cache with a
+    sliding-layer pool: a reference on a full-pool page also takes one
+    on the page's twin, the sliding-pool page that holds the same
+    tokens, where the row that publishes the page still holds it
+    (``offer``). So a page the trie holds keeps its sliding-layer half
+    and sliding out of a row's window does not release it; dropping the
+    trie's reference drops both halves. ``measure`` is the allocator
+    whose free list an eviction is to fill."""
+
+    def __init__(self, full: BlockAllocator, window: BlockAllocator):
+        self.full, self.window, self.measure = full, window, full
+        self.twin: Dict[int, int] = {}      # full page -> its twin
+        self.offer: Dict[int, int] = {}
+
+    num_pages = property(lambda self: self.measure.num_pages)
+    num_free = property(lambda self: self.measure.num_free)
+
+    def share(self, pages: Sequence[int]):
+        self.full.share(pages)
+        for p in pages:
+            w = self.offer.get(int(p))
+            if w is not None:
+                self.window.share([w])
+                self.twin[int(p)] = w
+
+    def free(self, pages: Sequence[int]):
+        self.full.free(pages)
+        for p in pages:
+            w = self.twin.pop(int(p), None)
+            if w is not None:
+                self.window.free([w])
+
+
 class PagedKVCache:
     """Device page pools + per-slot block tables + the allocator.
 
@@ -516,12 +559,30 @@ class PagedKVCache:
     block tables, defrag remaps) is replicated and runs UNCHANGED; only
     the device bytes split. ``pool_specs`` carries the per-array
     PartitionSpecs for the engine's shard_map programs, and
-    ``pool_bytes_per_shard`` the adjusted page-byte accounting."""
+    ``pool_bytes_per_shard`` the adjusted page-byte accounting.
+
+    A config with sliding layers (``self.window``: their window, else
+    None) adds the second pool: ``self.pool`` holds its arrays under the
+    ``_w`` names (``models/generate.KIND_SUFFIX``), ``window_allocator``
+    its free list and ``window_tables`` each slot's page ids there, by
+    the same logical page index as ``block_tables`` with the trash page
+    where nothing is held. The pool is sized by the cache, not the
+    caller: ``window_ring = ceil(window/page) + ceil(prefill_chunk/page)
+    + 1`` pages is the most a row can hold (the window behind a chunk's
+    first query, the chunk, one page of misalignment), times
+    ``max_batch``, plus the trash page. The engine drives it:
+    :meth:`window_extend` before a program writes new positions,
+    :meth:`window_release` / :meth:`window_step` in the commit after.
+    Admission reserves the full layers' pages for prompt and answer as
+    ever; the ring needs no reservation, since no row can hold more
+    than ``window_ring`` and only trie-held twins, which an allocation
+    evicts under pressure, take the rest. ``prefill_chunk`` (tokens;
+    None: whole prompts) only sizes that ring."""
 
     def __init__(self, cfg, max_batch: int, max_len: int,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  kv_dtype=None, enable_prefix_cache: bool = True,
-                 mesh=None):
+                 mesh=None, prefill_chunk: Optional[int] = None):
         from ..models import generate as _gen
         if max_len % page_size:
             max_len = (max_len // page_size + 1) * page_size
@@ -553,10 +614,20 @@ class PagedKVCache:
             tp = int(mesh.shape[ax])
         else:
             ax, tp = None, None
+        self.window = (cfg.sliding_window
+                       if "sliding" in cfg.period else None)
+        self.window_ring = self.window_pages = None
+        if self.window:
+            self.window_ring = min(
+                self.pages_per_seq,
+                self.pages_for(self.window)
+                + self.pages_for(prefill_chunk or max_len) + 1)
+            self.window_pages = 1 + max_batch * self.window_ring
         # init_paged_cache(tp=...) validates head divisibility LOUDLY
         # (and expands the head extent on the GQA replication path)
         self.pool = _gen.init_paged_cache(cfg, num_pages, page_size,
-                                          kv_dtype=kv_dtype, tp=tp)
+                                          kv_dtype=kv_dtype, tp=tp,
+                                          window_pages=self.window_pages)
         if mesh is not None:
             import jax
             from jax.sharding import NamedSharding
@@ -569,6 +640,18 @@ class PagedKVCache:
                 for n, a in self.pool.items()}
         self.allocator = BlockAllocator(num_pages)
         self.prefix = PrefixCache(page_size) if enable_prefix_cache else None
+        # whom the trie takes and drops its references through
+        self._trie_alloc = self.allocator
+        if self.window:
+            self.window_allocator = BlockAllocator(self.window_pages)
+            self._trie_alloc = _TwinAllocator(self.allocator,
+                                              self.window_allocator)
+            self.window_tables = np.full(
+                (max_batch, self.pages_per_seq), TRASH_PAGE, np.int32)
+            # a slot holds the window pages of logical pages
+            # [_win_first, _win_next)
+            self._win_first = np.zeros((max_batch,), np.int64)
+            self._win_next = np.zeros((max_batch,), np.int64)
         self.cow_copies = 0
         self._cow_fn = None                     # jitted CoW row copier
         self._scatter_fn = None                 # jitted page-import scatter
@@ -633,7 +716,88 @@ class PagedKVCache:
         overrides this to DEMOTE each dropped full page's bytes to
         host RAM before the reference goes — here they simply die and
         re-prefill on the next miss."""
-        return self.prefix.evict(self.allocator, need)
+        return self.prefix.evict(self._trie_alloc, need)
+
+    # ---- the sliding layers' pool ----
+    def _refuse_window(self, what: str):
+        """The features that copy a row's pages as one list of ids."""
+        if self.window:
+            raise ValueError(
+                f"{what} is not supported on a config with "
+                f"sliding-window layers: it walks one pool's pages")
+
+    def _window_alloc(self, n: int) -> List[int]:
+        """``n`` pages of the sliding layers' pool. No row holds more
+        than ``window_ring``, so what is missing is held by the trie
+        alone: drop trie references (both halves go) until it fits, a
+        ring's worth at a time so the trie is not walked every step."""
+        wa = self.window_allocator
+        if n > wa.num_free and self.prefix is not None:
+            self._trie_alloc.measure = wa
+            try:
+                self.prefix.evict(self._trie_alloc,
+                                  max(n, self.window_ring) - wa.num_free)
+            finally:
+                self._trie_alloc.measure = self.allocator
+        return wa.alloc(n)
+
+    def window_extend(self, slot: int, upto: int):
+        """Grant ``slot`` the sliding-pool pages of every logical page
+        below token position ``upto`` that it does not hold yet: call
+        before a program writes positions up to ``upto - 1``."""
+        last = min(self.pages_for(upto), self.pages_per_seq)
+        nxt = int(self._win_next[slot])
+        if last <= nxt:
+            return
+        self.window_tables[slot, nxt:last] = self._window_alloc(last - nxt)
+        self._win_next[slot] = last
+
+    def window_release(self, slot: int, pos: int) -> int:
+        """Drop ``slot``'s references on the sliding-pool pages that lie
+        wholly below what a query at position ``pos`` (and any later
+        one) sees, ``pos - window + 1``. Returns the pages that went
+        back to the free list; one the trie still holds stays."""
+        first = min(max(pos - self.window + 1, 0) // self.page_size,
+                    int(self._win_next[slot]))
+        old = int(self._win_first[slot])
+        if first <= old:
+            return 0
+        wa = self.window_allocator
+        before = wa.num_free
+        wa.free(self.window_tables[slot, old:first].tolist())
+        self.window_tables[slot, old:first] = TRASH_PAGE
+        self._win_first[slot] = first
+        return wa.num_free - before
+
+    def window_step(self, slots: np.ndarray) -> int:
+        """After a decode commit advanced ``lengths[slots]``: the page
+        the next token opens, and the pages the window slid past.
+        Returns the pages released to the free list."""
+        lens = self.lengths[slots]
+        page = self.page_size
+        opens = lens % page == 0
+        passed = (np.maximum(lens - self.window + 1, 0) // page
+                  > self._win_first[slots])
+        freed = 0
+        for s, n in zip(slots[opens | passed].tolist(),
+                        lens[opens | passed].tolist()):
+            freed += self.window_release(s, n)
+            self.window_extend(s, n + 1)
+        return freed
+
+    def _window_twins(self, shared: List[int], tail, tokens: int):
+        """The sliding-pool pages a prefix hit of ``tokens`` tokens
+        needs: the twins of the matched pages from the one that holds
+        position ``tokens - window + 1`` on, and the tail donor's. None
+        where the trie does not hold one of them (its row had let it
+        slide out before it published the prompt): the hit cannot be
+        served."""
+        twin = self._trie_alloc.twin
+        p0 = max(tokens - self.window + 1, 0) // self.page_size
+        need = shared[p0:] + ([tail[0]] if tail is not None else [])
+        if any(int(f) not in twin for f in need):
+            return None
+        return p0, [twin[int(f)] for f in need]
 
     def _install(self, slot: int, pages: List[int]) -> np.ndarray:
         self._slot_pages[slot] = pages
@@ -670,14 +834,26 @@ class PagedKVCache:
         if self.prefix is None or prompt.size == 0:
             return self._install(slot, self._alloc_with_evict(n)), 0
         shared, tail = self.prefix.match(prompt)
+        twins = None
+        if self.window and (shared or tail is not None):
+            # a hit maps both halves of a page, or nothing is shared
+            twins = self._window_twins(
+                shared, tail, len(shared) * self.page_size
+                + (tail[1] if tail is not None else 0))
+            if twins is None:
+                shared, tail = [], None
         # pin the matched pages FIRST: the eviction a fresh-page alloc
         # may trigger must not recycle the span we are about to map
         self.allocator.share(shared)
+        if twins is not None:
+            self.window_allocator.share(twins[1])
         try:
             fresh = self._alloc_with_evict(n - len(shared))
         except PoolExhausted:
             if shared:
                 self.allocator.free(shared)
+            if twins is not None:
+                self.window_allocator.free(twins[1])
             raise
         shared_tokens = len(shared) * self.page_size
         if tail is not None and fresh:
@@ -685,11 +861,26 @@ class PagedKVCache:
             self._cow_copy(donor, fresh[0], rows)
             shared_tokens += rows
             self.cow_copies += 1
-        return self._install(slot, shared + fresh), shared_tokens
+        table = self._install(slot, shared + fresh)
+        if twins is not None:
+            p0, held = twins
+            m = len(shared)
+            self.window_tables[slot, p0:m] = held[:m - p0]
+            self._win_first[slot], self._win_next[slot] = p0, m
+            if tail is not None:
+                # the donor's twin was pinned for the copy alone
+                if fresh:
+                    self.window_extend(slot, shared_tokens)
+                    self._cow_copy(held[-1], self.window_tables[slot, m],
+                                   tail[1], kind="sliding")
+                self.window_allocator.free(held[-1:])
+        return table, shared_tokens
 
-    def _cow_copy(self, src_page: int, dst_page: int, rows: int):
+    def _cow_copy(self, src_page: int, dst_page: int, rows: int,
+                  kind: str = "full"):
         """Device-copy the first ``rows`` token rows of ``src_page``
-        into ``dst_page`` for every pool array (all layers): the
+        into ``dst_page`` for every array of the ``kind`` layers' pool
+        (all its layers): the
         copy-on-write that lets an admission reuse a donor's partial
         prompt page without re-prefilling those rows, while decode
         appends into its OWN copy. Runs as ONE jitted program with the
@@ -713,8 +904,12 @@ class PagedKVCache:
                         jnp.where(keep, srcp, dstp))
                 return out
             self._cow_fn = jax.jit(f, donate_argnums=(0,))
-        self.pool = self._cow_fn(self.pool, jnp.int32(src_page),
-                                 jnp.int32(dst_page), jnp.int32(rows))
+        from ..models.generate import KIND_SUFFIX, _of_kind
+        mine = {n + KIND_SUFFIX[kind]: a
+                for n, a in _of_kind(self.pool, kind).items()}
+        self.pool.update(self._cow_fn(mine, jnp.int32(src_page),
+                                      jnp.int32(dst_page),
+                                      jnp.int32(rows)))
 
     def register_prefix(self, slot: int, prompt):
         """Publish a fully prefilled prompt's pages into the prefix
@@ -724,8 +919,15 @@ class PagedKVCache:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0 or not self.active[slot]:
             return
+        if self.window:
+            # the trie takes both halves of a page where this row still
+            # holds the sliding one
+            lo, hi = int(self._win_first[slot]), int(self._win_next[slot])
+            self._trie_alloc.offer = dict(zip(
+                self._slot_pages[slot][lo:hi],
+                self.window_tables[slot, lo:hi].tolist()))
         self.prefix.register(prompt, self._slot_pages[slot],
-                             self.allocator)
+                             self._trie_alloc)
 
     def release(self, slot: int):
         """Retire a request: drop its page references (shared pages
@@ -733,6 +935,12 @@ class PagedKVCache:
         if self._slot_pages[slot]:
             self.allocator.free(self._slot_pages[slot])
         self._slot_pages[slot] = []
+        if self.window:
+            lo, hi = int(self._win_first[slot]), int(self._win_next[slot])
+            self.window_allocator.free(
+                self.window_tables[slot, lo:hi].tolist())
+            self.window_tables[slot] = TRASH_PAGE
+            self._win_first[slot] = self._win_next[slot] = 0
         self.block_tables[slot] = TRASH_PAGE
         self.lengths[slot] = 0
         self.active[slot] = False
@@ -796,6 +1004,7 @@ class PagedKVCache:
         restart (in-flight sessions replay from the journal instead;
         their pages are recomputed). Returns None when the prefix
         cache is disabled or empty."""
+        self._refuse_window("checkpoint_prefix (drain)")
         if self.prefix is None:
             return None
         ids = sorted(set(self.prefix.pages()))
@@ -818,6 +1027,7 @@ class PagedKVCache:
         (alloc/free symmetry: the trie ends up owning exactly one
         reference per page, as :meth:`register_prefix` would leave
         it). Returns the number of pages restored."""
+        self._refuse_window("restore_prefix (restore)")
         if self.prefix is None:
             raise ValueError(
                 "restore_prefix into a cache with prefix caching "
@@ -908,6 +1118,7 @@ class PagedKVCache:
         same pool bytes land at the same logical positions); geometry
         is validated as loudly. The source slot is read-only — the
         exporting engine still owns it until ``finish_handoff``."""
+        self._refuse_window("import_request_direct (the fabric's direct handoff)")
         if not src_cache.active[src_slot]:
             raise ValueError(
                 f"import_request_direct: source slot {src_slot} is "
@@ -966,6 +1177,7 @@ class PagedKVCache:
         views + dtype/shape metadata so extension dtypes (bf16) and
         cross-host transports round-trip exactly. Pure read — the
         slot's pages, tables and refcounts are untouched."""
+        self._refuse_window("export_request (the fabric's handoff)")
         if not self.active[slot]:
             raise ValueError(f"export_request of inactive slot {slot}")
         length = int(self.lengths[slot])
@@ -1006,6 +1218,7 @@ class PagedKVCache:
         payload raises
         :class:`~paddle_tpu.serving.CorruptionDetected` with nothing
         committed (ISSUE 13)."""
+        self._refuse_window("import_request (the fabric's handoff)")
         from .resilience import _np_dtype, verify_checksums
         verify_checksums(payload["arrays"], payload.get("checksums"),
                          "handoff_import")
@@ -1070,6 +1283,7 @@ class PagedKVCache:
         Keeps long-running servers' pools dense after many
         admit/retire cycles (the allocator's ``fragmentation()`` stat
         measures the holes this closes)."""
+        self._refuse_window("defrag")
         used = {p for pages in self._slot_pages for p in pages}
         if self.prefix is not None:
             used |= set(self.prefix.pages())

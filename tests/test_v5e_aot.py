@@ -112,6 +112,60 @@ def test_paged_decode_kernel(v5e, shape, kv, in_place):
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
+# Mellum2-12B-A2.5B's published widths (chipbench/configs): 32 query and 4
+# kv heads of 128, pages of 64, a window of 1,024 in the sliding layers'
+# pool of 673 pages, the full layers' of 5,121; the pools of ALL layers of
+# a kind as one run of pages, as the decode step hands them over
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("window,layers,pages", [(1024, 9, 673),
+                                                 (None, 3, 5121)])
+def test_paged_decode_kernel_mellum_widths(v5e, kv, window, layers, pages):
+    B, H, HK, D, page, ppseq = 32, 32, 4, 128, 64, 160
+    d = v5e[0]
+    pool = _on(d, (layers * pages, page, HK, D),
+               jnp.int8 if kv == "int8" else jnp.bfloat16)
+    args = [_on(d, (B, H, D), jnp.bfloat16), pool, pool,
+            _on(d, (B, ppseq), jnp.int32), _on(d, (B,), jnp.int32)]
+    if kv == "int8":
+        args += [_on(d, (layers * pages, page, HK), jnp.float32)] * 2
+
+    def f(q, k, v, bt, lens, *sc):
+        ks, vs = sc if sc else (None, None)
+        return paged_attention.paged_attention(
+            q, k, v, bt + 2 * pages, lens, ks_pages=ks, vs_pages=vs,
+            window=window)
+    compiled = _compile(f, *args)
+    if kv == "bf16":
+        # the kernel reads the whole pool in place: no copy of it
+        pool_bytes = layers * pages * page * HK * D * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("items", [32 * 8, 256 * 8])
+def test_grouped_expert_matmul_mellum_widths(v5e, items):
+    """The serving expert layer at a decode step's and a chunk's item
+    counts: three grouped matmuls over the stacks of all twelve layers as
+    they are stored, none of which may be copied."""
+    from paddle_tpu.models import generate as gen
+    L, E, H, I = 12, 64, 2304, 896
+    d = v5e[0]
+    dt = jnp.bfloat16
+    stacks = (_on(d, (L, E, H, I), dt), _on(d, (L, E, H, I), dt),
+              _on(d, (L, E, I, H), dt))
+
+    def f(x, le, layer, wg, wu, wd):
+        return gen._expert_apply(
+            x, jnp.arange(items, dtype=jnp.int32) // 8, le,
+            (wg, wu, wd), layer, use_kernel=True)
+    with fa.force_compiled_lowering():
+        compiled = jax.jit(f).lower(
+            _on(d, (items // 8, H), jnp.bfloat16), _on(d, (items,), jnp.int32),
+            _on(d, (), jnp.int32), *stacks).compile()
+    assert compiled.as_text().count("grouped_expert_matmul") >= 3
+    one_layer = E * H * I * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 4
+
+
 def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
     """block_rows=256 x width 4096 needed 19.93 MiB of the 16 MiB scoped
     VMEM, forward and backward; the row block now follows the width."""

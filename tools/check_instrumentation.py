@@ -117,6 +117,19 @@ REQUIRED = {
         # the counters fed where the engine already knows the numbers
         ('.count("prompt_tokens_total"', 1),
         ('.count("prefix_hit_tokens_total"', 1),
+        # sliding-window layers and routed experts (ISSUE 28): the
+        # program's period among build_program's fields; pages of the
+        # sliding layers' pool released in the chunk and the decode
+        # commit; the expert counters, summed on the device and split
+        # off the decode step's one read; both pools' peaks in stats()
+        ("period=len(self.cfg.period)", 1),
+        ('.count("window_pages_released_total"', 2),
+        ('"moe_routed_items_total"', 1),
+        ('"moe_experts_hit_total"', 1),
+        ('"moe_max_expert_load_total"', 1),
+        ('"moe_layer_steps_total"', 1),
+        ('s["full_pool_used_peak"]', 1),
+        ('s["window_pool_used_peak"]', 1),
     ],
     "paddle_tpu/observability/hooks.py": [
         # the ISSUE 20 hook families themselves: the predictor entries
