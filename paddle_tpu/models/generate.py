@@ -19,7 +19,8 @@ order — paged: ``(layers of the kind, pages, page, nkv, hd)``, two pools
 with page ids and block tables of their own. A plain config has the one
 set. Every forward scans over periods (``_layer_feed``); inside a period
 each run of one layer kind (``_runs``) is one layer body, scanned when the
-run has several layers; a period of one is the scan over layers.
+run has several layers; a period of one is the scan over layers (the
+paged decode step carries the pools through it whole and in place).
 """
 from __future__ import annotations
 
@@ -415,7 +416,8 @@ def _by_period(a, n: int, plen: int):
 def _layer_feed(tree, plen: int):
     """How a scan over periods reaches a stacked ``(L, ...)`` tree of layer
     arrays: ``(what to put among the scan's xs, take)`` with
-    ``take(slice, i, j)`` the arrays of layer ``j`` of period ``i``. A
+    ``take(slice, i, j)`` the arrays of layer ``j`` of period ``i``
+    (weights, adapters; paged pools ride in the carry instead). A
     period of one is the scan's own slice. With several layers a period
     the stack stays outside the scan and a layer is cut out of it by its
     number, which is what the scan does itself; a period's slice cut
@@ -907,6 +909,12 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                   page and their logits are garbage to be ignored
     returns (logits (B, V) f32, updated pools).
 
+    One way through the layers for every config: a kind's pools ride
+    whole in the layer scan's carry as ``(L_kind * P, page, ...)``; a
+    layer writes its B rows at ``dst + base * page`` and attends through
+    ``table + base``, ``base`` its first page. Donated pools are written
+    in place (the compiled temp is gated in tests/test_v5e_aot.py).
+
     Math is kept op-for-op identical to the dense decode
     (:func:`_block_infer` + ``_attn_with_cache``-equivalent paged
     attention), so greedy tokens match the dense path exactly.
@@ -1019,15 +1027,13 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     # where in its kind's slice of a period a layer's pool sits
     slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
 
-    def layer(xc, lp, kind, kp, vp, ksp, vsp, ad_l, at_layer, base):
-        # kp .. vsp: pages (P', page, ...) holding this layer's, either
-        # its own slice of the pool (``base`` None) or the whole pool of
-        # its kind with the layer's pages from page ``base`` on
+    def layer(xc, lp, kind, held, ad_l, at_layer, base):
+        # held: the whole pools of the layer's kind as pages (layers * P,
+        # page, ...), this layer's from page ``base`` on
+        kp, vp, ksp, vsp = (held.get(n) for n in ("k", "v", "ks", "vs"))
         cos, sin = rope[kind]
         window = cfg.window_of(kind)
-        dst, table = dsts[kind], tables[kind]
-        if base is not None:
-            dst, table = dst + base * page, table + base
+        dst, table = dsts[kind] + base * page, tables[kind] + base
         # the fused kernel has no window: a sliding layer's call is the
         # unfused one
         fuse = fused and window is None
@@ -1051,6 +1057,14 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
             # what keeps the dp-replicated pools bit-identical
             if dp_axis is not None:
                 rows = _tp_allgather(rows, dp_axis, 0)
+            if pool.ndim == 3:
+                # a scale pool as the kernel reads it (below): a scatter
+                # would re-lay it whole, so write row after row in place
+                return lax.fori_loop(
+                    0, rows.shape[0], lambda r, p: lax.dynamic_update_slice(
+                        p, lax.dynamic_slice_in_dim(rows, r, 1)[None],
+                        (dst[r] // page, 0, dst[r] % page * rows.shape[1])),
+                    pool)
             return pool.reshape((-1,) + pool.shape[2:]).at[dst].set(
                 rows).reshape(pool.shape)
 
@@ -1069,7 +1083,9 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
             vp = _pool_write(vp, vq[:, 0].astype(jnp.int8))
             ksp = _pool_write(ksp, sc[:, 0].astype(jnp.float32))
             vsp = _pool_write(vsp, vc[:, 0].astype(jnp.float32))
+            ksa, vsa = (a.reshape(kp.shape[:3]) for a in (ksp, vsp))
         else:
+            ksa = vsa = None
             kp = _pool_write(kp, k[:, 0].astype(kp.dtype))
             vp = _pool_write(vp, v[:, 0].astype(vp.dtype))
         if fuse:
@@ -1083,12 +1099,12 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                 2 * B * nh * hd * jnp.dtype(cfg.dtype).itemsize)
             o = _sf.fused_paged_decode_attention(
                 q[:, 0], *rope_row[kind], kp, vp, table,
-                lengths + 1, ks_pages=ksp, vs_pages=vsp,
+                lengths + 1, ks_pages=ksa, vs_pages=vsa,
                 use_kernel=use_kernel)
         else:
             o = _pa.paged_attention(
                 q[:, 0], kp, vp, table, lengths + 1,
-                ks_pages=ksp, vs_pages=vsp, use_kernel=use_kernel,
+                ks_pages=ksa, vs_pages=vsa, use_kernel=use_kernel,
                 window=window)
         o = o.reshape(B, 1, nh * hd)
         if tp_axis is not None:
@@ -1120,7 +1136,7 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                                        tp_axis, 2)
             else:
                 y = xo + (g * u) @ _w(lp, "wd", xc.dtype)
-        return y, (kp, vp, ksp, vsp), stats
+        return y, dict(zip(names, (kp, vp, ksp, vsp))), stats
 
     scanned, experts = _split_experts(params["layers"])
     pools = {kind: _of_kind(paged, kind) for kind in kinds}
@@ -1129,63 +1145,46 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         None if adapters is None else tuple(
             adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
     index = jnp.arange(cfg.num_layers // plen, dtype=jnp.int32)
-    if plen == 1:
-        # A plain decoder: the scan slices each layer's pool out of the
-        # stack and stacks the written slices again (the program the
-        # dense cells have run since PR 21).
-        def body(xc, xs):
-            lp, pool, ad_l, i = xs
-            xc, out, st = layer(xc, lp, period[0], pool["k"], pool["v"],
-                                pool.get("ks"), pool.get("vs"), ad_l, i,
-                                None)
-            return xc, (dict(zip(names, out)), st)
-        x, (new, stats) = lax.scan(
-            body, x, (lps, pools[period[0]], ads, index))
-        new = {period[0]: new}
-    else:
-        # Layers of two kinds: the pools ride in the scan's carry WHOLE,
-        # viewed as (layers * pages, page, ...): a layer writes its rows
-        # and the kernel reads its pages where they lie, by block tables
-        # moved up to the layer's first page, and no slice of a pool is
-        # copied out or back. Inside a period each run of one kind
-        # (:func:`_runs`) is a scan of its own over one layer body, so a
-        # program traces and lowers one body a run, not one a layer.
-        pool_pages = {kind: d["k"].shape[1] for kind, d in pools.items()}
-        zero = (jnp.zeros((3,), jnp.int32) if cfg.moe is not None
-                else None)
+    # The pools ride in the scan's carry WHOLE: a layer writes its rows and
+    # the kernel reads its pages where they lie, by block tables moved up
+    # to the layer's first page, and no slice of a pool is copied out or
+    # back. Inside a period each run of one kind (:func:`_runs`) is a scan
+    # of its own over one layer body; a plain decoder's period is one
+    # layer, and the scan over periods is its layer loop.
+    pool_pages = {kind: d["k"].shape[1] for kind, d in pools.items()}
+    zero = jnp.zeros((3,), jnp.int32) if cfg.moe is not None else None
 
-        def body(carry, xs):
-            lps_i, ads_i, i = xs
+    def body(carry, xs):
+        lps_i, ads_i, i = xs
 
-            def one(carry, j, kind, first):
-                # layer j of the period; ``first``: the period's first
-                # layer of this run, at slot ``slot_of[first]`` of its kind
-                xc, held, stats = carry
-                c = held[kind]
-                base = ((i * period.count(kind) + slot_of[first]
-                         + (j - first)) * pool_pages[kind])
-                xc, out, st = layer(
-                    xc, take_lp(lps_i, i, j), kind, c["k"], c["v"],
-                    c.get("ks"), c.get("vs"), take_ad(ads_i, i, j),
-                    i * plen + j, base)
-                held = {**held, kind: dict(zip(names, out))}
-                return xc, held, (stats if st is None else stats + st)
+        def one(carry, j, kind, first):
+            # layer j of the period; ``first``: the period's first layer
+            # of this run, at slot ``slot_of[first]`` of its kind
+            xc, held, stats = carry
+            base = ((i * period.count(kind) + slot_of[first]
+                     + (j - first)) * pool_pages[kind])
+            xc, out, st = layer(
+                xc, take_lp(lps_i, i, j), kind, held[kind],
+                take_ad(ads_i, i, j), i * plen + j, base)
+            return (xc, {**held, kind: out},
+                    stats if st is None else stats + st)
 
-            for kind, first, n in _runs(period):
-                if n == 1:
-                    carry = one(carry, first, kind, first)
-                else:
-                    carry, _ = lax.scan(
-                        lambda cr, j, kind=kind, first=first: (
-                            one(cr, j, kind, first), None),
-                        carry, first + jnp.arange(n, dtype=jnp.int32))
-            return carry, None
-        flat = {kind: {n: a.reshape((-1,) + a.shape[2:])
-                       for n, a in d.items()} for kind, d in pools.items()}
-        (x, new, stats), _ = lax.scan(body, (x, flat, zero),
-                                      (lps, ads, index))
-    if plen == 1 and stats is not None:
-        stats = jnp.sum(stats, axis=0)
+        for kind, first, n in _runs(period):
+            if n == 1:
+                carry = one(carry, first, kind, first)
+            else:
+                carry, _ = lax.scan(
+                    lambda cr, j, kind=kind, first=first: (
+                        one(cr, j, kind, first), None),
+                    carry, first + jnp.arange(n, dtype=jnp.int32))
+        return carry, None
+    # K/V pools as (layers * pages, page, ...); the int8 tier's scale pools
+    # as (layers * pages, 1, page * heads) lane rows, the layout the kernel
+    # reads: laid out once a step here, not whole in every layer call.
+    flat = {kind: {n: a.reshape((-1, 1, a.shape[2] * a.shape[3])
+                                if a.ndim == 4 else (-1,) + a.shape[2:])
+                   for n, a in d.items()} for kind, d in pools.items()}
+    (x, new, stats), _ = lax.scan(body, (x, flat, zero), (lps, ads, index))
     new_paged = {n + KIND_SUFFIX[kind]: a.reshape(
         paged[n + KIND_SUFFIX[kind]].shape)
         for kind in kinds for n, a in new[kind].items()}

@@ -154,10 +154,13 @@ def _pages_per_group(ppseq, page, HK, D, itemsize):
 
 def _in_hbm(pool):
     """The kernel streams pages from HBM, and its roofline is HBM's. Inside
-    a compiled program XLA otherwise parks a scanned layer's K pool on
-    chip across the call (100 MiB of the v5e's 128 of VMEM), and the copies
-    then read VMEM: say where the pools are read from. The constraint
-    exists only under ``jit`` and for the compiled kernel."""
+    a compiled program XLA may otherwise park a pool operand that fits on
+    chip across the call (a 100 MiB K pool of one layer in the v5e's 128
+    of VMEM), and the copies then read VMEM: say where the pools are read
+    from. The decode step hands over the pools of all layers as one run
+    of pages, too large to park; a caller with a small pool still can be.
+    The constraint exists only under ``jit`` and for the compiled
+    kernel."""
     if _fa._interpret_mode() or not isinstance(pool, jax.core.Tracer):
         return pool
     return pltpu.with_memory_space_constraint(pool, pltpu.HBM)

@@ -141,6 +141,46 @@ def test_paged_decode_kernel_mellum_widths(v5e, kv, window, layers, pages):
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
 
 
+# The dense serving cells' WHOLE decode step (chipbench: InternLM2-1.8B at
+# its published widths, 24 layers, 801 pages of 64, batch 32, 64 pages a
+# row), pools donated as the engine donates them. The pools ride in the
+# layer scan's carry and are written where they lie; a program that slices
+# a layer's pool out of the stack and stacks it back (the scan's xs/ys,
+# until PR 29) holds a second copy of both pools in its temp. Temp beside
+# the pools' bytes as compiled here: bf16 322,560 B / 5.04 GB (5.46 GB
+# before); int8, which no benchmark cell times, 999,936 B / 2.60 GB (2.82 GB
+# before; 0.79 GB with the scale pools carried as (pages, page, heads),
+# which the kernel's wrapper lays out anew in every layer call).
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_dense_decode_step_writes_pools_in_place(v5e, kv):
+    from paddle_tpu.models import generate as gen
+    cfg = llama.LlamaConfig(
+        vocab_size=92544, hidden_size=2048, intermediate_size=8192,
+        num_layers=24, num_heads=16, num_kv_heads=8, head_dim=128,
+        max_seq_len=4096, rope_theta=1e6, rms_eps=1e-5, dtype=jnp.bfloat16,
+        tie_embeddings=False)
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                               jax.random.key(0)))
+    pools = on(jax.eval_shape(lambda: gen.init_paged_cache(
+        cfg, 801, 64, kv_dtype="int8" if kv == "int8" else None)))
+    B, pps = 32, 64
+
+    def decode(params, last, paged, tables, lengths, active):
+        logits, paged = gen.paged_decode_forward(
+            params, last, paged, tables, lengths, cfg, active=active,
+            use_kernel=True)
+        return jnp.argmax(logits, -1), paged
+    compiled = _compile(
+        jax.jit(decode, donate_argnums=(2,)), params, _on(d, (B,), jnp.int32),
+        pools, _on(d, (B, pps), jnp.int32), _on(d, (B,), jnp.int32),
+        _on(d, (B,), jnp.bool_))
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pools))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
 @pytest.mark.parametrize("items", [32 * 8, 256 * 8])
 def test_grouped_expert_matmul_mellum_widths(v5e, items):
     """The serving expert layer at a decode step's and a chunk's item

@@ -390,9 +390,12 @@ def test_tp2_mesh_serves_the_single_devices_tokens(model):
 
 
 # ---- (g) the period scan leaves a plain decoder as it was ----
+@pytest.mark.parametrize("moe", [False, True])
 @pytest.mark.parametrize("kv", [None, "int8"])
-def test_a_period_of_full_layers_is_the_plain_decoder(kv):
-    cfg = llama.LlamaConfig.tiny(num_layers=4, max_seq_len=128)
+def test_a_period_of_full_layers_is_the_plain_decoder(kv, moe, monkeypatch):
+    cfg = llama.LlamaConfig.tiny(
+        num_layers=4, max_seq_len=128,
+        moe=MoEConfig(num_experts=4, top_k=2) if moe else None)
     params = llama.init_params(jax.random.key(3), cfg)
     prompts = prompts_of(PROMPTS)
     _, plain = serve(params, cfg, prompts, kv_cache_dtype=kv)
@@ -406,3 +409,27 @@ def test_a_period_of_full_layers_is_the_plain_decoder(kv):
                            max_new_tokens=NEW)
         np.testing.assert_array_equal(np.asarray(out)[0, prompts[0].size:],
                                       plain[0])
+    if not moe:
+        return
+    # a plain period with experts: the decode step hands back ONE (3,)
+    # stats vector, the sum over layers of what each layer's _moe_ffn
+    # counted over the live rows
+    seen = []
+    inner = gen._moe_ffn
+
+    def spy(*a, **k):
+        y, st = inner(*a, **k)
+        jax.debug.callback(lambda v: seen.append(np.asarray(v)), st)
+        return y, st
+    monkeypatch.setattr(gen, "_moe_ffn", spy)
+    paged = gen.init_paged_cache(cfg, 9, 8, kv_dtype=kv)
+    tables = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    _, _, stats = gen.paged_decode_forward(
+        params, jnp.asarray([5, 6, 7], jnp.int32), paged, tables,
+        jnp.asarray([3, 0, 9], jnp.int32), cfg, active=active,
+        with_stats=True)
+    jax.effects_barrier()
+    assert stats.shape == (3,) and len(seen) == cfg.num_layers
+    np.testing.assert_array_equal(np.asarray(stats), np.sum(seen, axis=0))
+    assert int(stats[0]) == cfg.num_layers * 2 * cfg.moe.top_k
