@@ -1,7 +1,6 @@
 """Pipeline-parallel training with the hand-written VPP (interleaved
-1F1B) schedule, plus the pp × MoE composition — the round-5 recipe
-winners (PERF_NOTES schedule sweep: 31.0 GB/chip on the 13B recipe vs
-223 GB for AD-backed VPP; pp2×ep4×tp2 MoE at 33.4 GB/chip).
+1F1B) schedule, plus the pp × MoE composition. No schedule has run on
+a chip yet (ROADMAP D5).
 
 Run: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
      python examples/train_pp_vpp_moe.py
@@ -14,7 +13,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from paddle_tpu.models import llama, moe, train, train_pp
 
 # ---- dense Llama under VPP (dp × pp × tp) ------------------------------
-mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 2, 2),
+# dp=1 here: with dp AND tp sharded XLA:CPU aborts the VPP step
+# (tests/test_pp_moe.py::test_interleave_1f1b_on_ep2_tp2); on chips the
+# recipe is dp × pp × tp
+mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(1, 2, 4),
             ("dp", "pp", "tp"))
 cfg = llama.LlamaConfig.tiny(num_layers=4, hidden_size=64, num_heads=4,
                              num_kv_heads=4, intermediate_size=128,

@@ -460,9 +460,10 @@ def pipeline_interleave_1f1b(stage_fn: Callable, loss_fn: Callable,
     backward — the memory contract of ``pipeline_1f1b`` at the bubble of
     ``pipeline_interleave``.
 
-    Motivation (round-5 AOT sweep, PERF_NOTES): AD through the interleave
-    wavefront keeps every in-flight microbatch residual alive until the
-    reverse wavefront — 223 GB/chip on the 13B recipe. Here the combined
+    Motivation: AD through the interleave wavefront keeps every
+    in-flight microbatch residual alive until the reverse wavefront (an
+    AOT compile of a 13B recipe predicted 223 GB a chip: ROADMAP D5).
+    Here the combined
     scan runs one forward AND one backward VIRTUAL-STAGE unit per tick
     (the shared ``_interleave_1f1b_core``), stashing only raw stage
     inputs in a (2V-1)-slot ring (V = P*C virtual stages), so activation

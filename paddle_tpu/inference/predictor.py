@@ -578,8 +578,9 @@ class ContinuousBatchingEngine:
         # Pallas kernels (ops/pallas/serving_fused.py — in-VMEM q-RoPE
         # + KV dequant for decode, flash chunk attention for
         # prefill/verify). Default OFF, same contract as
-        # LlamaConfig.fused_kernels: flip only with a sweep showing >=
-        # parity (the decode_fused_speedup bench rider measures it);
+        # LlamaConfig.fused_kernels: never timed on a chip; what
+        # decides is a cell of BENCHMARK.json on each side, run by the
+        # driver (ROADMAP D3), after which the loser and this flag go;
         # off-TPU the fused path is the bit-identical reference, and
         # the kernels themselves are gated token-identical per tier
         # (tests/test_lowbit_decode.py) + Mosaic-lowered by

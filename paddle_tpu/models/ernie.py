@@ -5,7 +5,7 @@ post-LN transformer encoder, learned position + segment embeddings,
 masked-language-model head tied to the word embedding, pooler + NSP head
 (reference architecture surface: python/paddle/nn/layer/transformer.py
 TransformerEncoder; the ERNIE models themselves live out-of-tree in
-PaddleNLP but BASELINE.md config 5 targets the ERNIE family).
+PaddleNLP).
 
 TPU-native design mirrors ``models/llama.py``: stacked (L, ...) parameter
 leaves scanned with ``lax.scan``, GSPMD dp/fsdp/tp sharding declared in
@@ -73,11 +73,6 @@ class ErnieConfig:
         heads = (h * h + h + 2 * h + self.vocab_size) + (h * h + h) \
             + (2 * h + 2)
         return L * per_layer + emb + heads
-
-    def flops_per_token(self, seq_len: int) -> float:
-        n = self.num_params()
-        attn = 12 * self.num_layers * self.num_heads * self.hd * seq_len
-        return 6.0 * n + attn
 
 
 # ---------------- init ----------------

@@ -69,14 +69,6 @@ class GPTConfig:
         return (L * per_layer + self.vocab_size * h
                 + self.max_seq_len * h + 2 * h)
 
-    def flops_per_token(self, seq_len: int) -> float:
-        n = self.num_params() - self.vocab_size * self.hidden_size \
-            - self.max_seq_len * self.hidden_size
-        # tied head matmul flops
-        n += self.vocab_size * self.hidden_size
-        attn = 12 * self.num_layers * self.num_heads * self.hd * seq_len
-        return 6.0 * n + attn
-
 
 def init_params(key: jax.Array, cfg: GPTConfig) -> Dict[str, Any]:
     h, i, L, v = (cfg.hidden_size, cfg.intermediate_size, cfg.num_layers,

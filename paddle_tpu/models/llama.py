@@ -77,10 +77,11 @@ class LlamaConfig:
     # "dots" = save all matmul outputs (max speed, max memory)
     remat_policy: str = "nothing"
     # rms_norm/rope/swiglu implementation: "xla" (default) = jnp left to
-    # XLA fusion — measured best on the headline bench; "auto" = Pallas
-    # kernels (ops/pallas/fused.py) on TPU; "pallas" forces the kernels
-    # (interpret mode off-TPU — tests). Flip the default only with a
-    # sweep (tools/perf_sweep.py b4_pallas) showing >= parity.
+    # XLA fusion; "auto" = Pallas kernels (ops/pallas/fused.py) on TPU;
+    # "pallas" forces the kernels (interpret mode off-TPU — tests).
+    # "pallas" has never run on a chip (it compiles by AOT); what flips
+    # the default is the train cell of BENCHMARK.json on each side, run
+    # by the driver (ROADMAP D3).
     fused_kernels: str = "xla"
     moe: Optional["_moe.MoEConfig"] = None  # experts replace the dense MLP
     # layers that differ by position: ONE period of attention kinds
@@ -161,17 +162,6 @@ class LlamaConfig:
                      + mlp + 2 * h)                                # 2 rmsnorm
         emb = v * h * (1 if self.tie_embeddings else 2)
         return L * per_layer + emb + h
-
-    def flops_per_token(self, seq_len: int) -> float:
-        """Training FLOPs/token (fwd+bwd ≈ 6*N_matmul + attention term).
-
-        The input-embedding table is a gather, not a matmul, so it is
-        excluded from N (the lm_head matmul is real compute and stays).
-        """
-        n = self.num_params() - self.vocab_size * self.hidden_size * (
-            0 if self.tie_embeddings else 1)
-        attn = 12 * self.num_layers * self.num_heads * self.hd * seq_len
-        return 6.0 * n + attn
 
 
 # ---------------- init ----------------
@@ -268,8 +258,8 @@ def param_specs(cfg: LlamaConfig) -> Dict[str, Any]:
 # an exact concatenation and a column-subset matmul computes each output
 # element with the full, identically-ordered contraction — whereas a
 # row-parallel psum of partial matmuls reassociates the reduction and
-# drifts in the last mantissa bits. Decode is HBM-bound (PERF_NOTES):
-# the win is weight + KV BYTES per shard (all seven layer matrices and
+# drifts in the last mantissa bits. Decode is HBM-bound (PERF.md
+# section 5): the win is weight + KV BYTES per shard (all seven layer matrices and
 # lm_head shard 1/tp), and the (B, ·) decode activations the gathers
 # move are noise next to that, so buying exactness with two extra
 # gathers per layer costs ~nothing on the hot path.

@@ -13,9 +13,8 @@ TPU build:
   cost when disabled: one module-flag read per call site, no
   allocation (``hooks.span`` hands back a shared nullcontext).
 - :mod:`timeline` — merges profiler spans + metrics into one per-phase
-  summary dict (``Profiler.phase_summary()``; ``bench.py`` attaches it
-  under each round's ``phases`` key) + the shared sort-stable Chrome
-  trace exporter (``chrome_trace``).
+  summary dict (``Profiler.phase_summary()``) + the shared sort-stable
+  Chrome trace exporter (``chrome_trace``).
 - :mod:`tracing` — request-scoped distributed tracing for the serving
   plane: a trace minted at submission rides the request handle through
   queue/prefill/handoff/swap/decode/recovery, stitching cross-replica

@@ -4,8 +4,9 @@ Every training/serving hot path (pipeline engine, predictor, generate,
 dataloader, collectives, watchdog) calls into THIS module instead of
 touching the registry or the profiler collector directly, so the
 disabled-path cost is one module-attribute read (``hooks.enabled``) per
-call site — no allocation, no string formatting, no lock (the contract
-ISSUE telemetry demands and ``tools/check_instrumentation.py`` lints).
+call site — no allocation, no string formatting, no lock. Readers:
+the Prometheus / JSON export and tests; the benchmark reads
+``observability/spans.py`` (ROADMAP D8).
 
 Two independent switches feed two sinks:
 
@@ -530,8 +531,7 @@ def serving_fault_recovery(t0_ns: int, sessions: int,
     :func:`generate_begin` anchor): teardown + pool rebuild + journal
     restore. ``replay_tokens`` is the continuation-prefill bill the
     restored sessions will pay (prompt + committed tokens minus one,
-    per admitted session) — the recovery-cost model's x-axis
-    (PERF_NOTES: recovery time ∝ resident tokens)."""
+    per admitted session): recovery time should grow with it."""
     if not t0_ns:
         return
     now = time.perf_counter_ns()
@@ -632,8 +632,7 @@ def serving_drain_restore(t0_ns: int, nbytes: int, sessions: int,
 def serving_wal_append(t0_ns: int, nbytes: int):
     """One CRC-framed record appended to the on-disk write-ahead
     journal: append counter + bytes counter + latency histogram — the
-    per-record half of the fsync-ladder overhead model (PERF_NOTES
-    'Durability')."""
+    per-record half of what an fsync policy costs."""
     if not enabled:
         return
     _m.counter("serving_wal_appends_total",
@@ -729,7 +728,7 @@ def serving_swap_out(t0_ns: int, nbytes: int, pages: int):
     """Close one preemption SWAP-OUT opened at ``t0_ns``: the victim's
     live KV pages gathered device→host before its device pages freed.
     Latency histogram + bytes/pages counters — the 'bytes moved' half
-    of the swap-vs-replay crossover model (PERF_NOTES)."""
+    of the swap-against-replay comparison."""
     if not t0_ns:
         return
     now = time.perf_counter_ns()
@@ -922,8 +921,7 @@ def serving_adapter_gather(nbytes: int):
     factor bytes the compiled program gathers out of the adapter pool
     (per-row A/B slices, all layers). Fires at TRACE time like
     :func:`serving_tp_allgather` — once per compile, which is exactly
-    the per-step adapter-bandwidth bill of the multi-LoRA path (the
-    PERF_NOTES rank-r bytes/token model reads this)."""
+    the per-step adapter-bandwidth bill of the multi-LoRA path."""
     if not enabled:
         return
     _m.counter("serving_adapter_gather_calls_total",
@@ -1001,8 +999,8 @@ def serving_tree_verify(t0_ns: int, out, rows: int, nodes: int,
     whole token tree scored in ONE forward. ``nodes``/``accepted``
     count tree nodes offered vs accepted along the committed root
     paths; ``paths`` is the per-row committed path length (accepted +
-    1 — the path-length histogram is the quantity the (width, depth)
-    expected-gain model in PERF_NOTES is fit against). Same
+    1 — the path-length histogram is what a choice of (width, depth)
+    would be made from: ROADMAP D4). Same
     device-fence contract as :func:`serving_spec_verify`."""
     if not t0_ns:
         return
@@ -1073,8 +1071,7 @@ def serving_fused_dispatch(kernel: str, bytes_saved: int):
     rotated-q round-trip, the materialized f32 score/prob tensors, the
     host-staged page payload) in each COMPILED program, once per
     compile — exactly the per-step fusion bill. ``bytes_saved`` also
-    feeds the per-kernel bytes-saved gauge the PERF_NOTES roofline
-    model reads."""
+    feeds the per-kernel bytes-saved gauge."""
     if not enabled:
         return
     _m.counter("serving_fused_dispatch_total",
@@ -1093,9 +1090,8 @@ def serving_fused_dispatch(kernel: str, bytes_saved: int):
 def serving_fused_latency(kernel: str, t0_ns: int, out):
     """Close one HOST-timed fused-path step opened at ``t0_ns`` (the
     engine's decode/prefill/verify step with fusion on, or one fused
-    page move): blocks on ``out`` so the histogram holds real device
-    wall time per kernel — the ``decode_fused_speedup`` bench rider's
-    per-kernel breakdown."""
+    page move): blocks on ``out`` so the histogram holds device wall
+    time per kernel on the host's clock."""
     if not t0_ns:
         return
     _block(out)
@@ -1187,8 +1183,8 @@ def serving_router_replica(replica: int, queued: int, occupancy: float,
 def serving_handoff_export(t0_ns: int, nbytes: int, pages: int):
     """Close one prefill→decode KV export opened at ``t0_ns`` (a
     :func:`generate_begin` anchor): latency histogram + bytes/pages
-    counters — the numerator of the handoff cost model (page bytes
-    moved vs the replay-prefill FLOPs they replace; PERF_NOTES)."""
+    counters — page bytes moved, against the replay-prefill FLOPs they
+    replace."""
     if not t0_ns:
         return
     now = time.perf_counter_ns()
@@ -1547,9 +1543,8 @@ def serving_rpc_call(method: str, t0_ns: int, bytes_out: int,
                      bytes_in: int):
     """Close one client-side RPC exchange opened at ``t0_ns`` (a
     :func:`generate_begin` anchor): per-method call counter, frame
-    bytes in both directions, latency histogram — the numerator of the
-    multi-process cost model (PERF_NOTES: RPC frame bytes per step vs
-    handoff payload bytes)."""
+    bytes in both directions, latency histogram (RPC frame bytes per
+    step against handoff payload bytes)."""
     if not t0_ns:
         return
     now = time.perf_counter_ns()
@@ -1654,8 +1649,7 @@ def serving_fabric_demote(t0_ns: int, nbytes: int):
 def serving_fabric_promote(t0_ns: int, nbytes: int, hit: bool):
     """Close one PROMOTE from the shared KV fabric opened at ``t0_ns``:
     hit/miss counters and, on a hit, the payload bytes that replaced a
-    cold prefill (the fabric-hit vs cold-prefill crossover in
-    PERF_NOTES)."""
+    cold prefill."""
     if not t0_ns:
         return
     now = time.perf_counter_ns()

@@ -3,9 +3,9 @@
 Merges the host spans collected by the profiler's ``_Collector`` (the
 HostTracer analog) with the metrics registry snapshot into ONE
 structured dict, so a single ``Profiler`` run yields a chrome trace AND
-a machine-readable per-phase breakdown — the piece BENCH_r*.json rounds
-were missing (totals with no attribution). ``bench.py`` attaches this
-dict under each round's ``phases`` key.
+a machine-readable per-phase breakdown. Host clock, fenced: the
+benchmark reads ``observability/spans.py`` on the profiler's clock
+instead (PERF.md section 3).
 
 Phase mapping: the reference Model-Summary event types (Forward /
 Backward / Optimization / DataLoader) plus the serving phases carried in

@@ -1,8 +1,9 @@
 """Fused Pallas serving kernels (ISSUE 11): the decode hot loop's
 remaining kernel seams collapsed into single launches.
 
-Decode on the serving tower is HBM-bandwidth-bound (PERF_NOTES), so
-every intermediate a step writes to HBM and re-reads is tokens/s lost.
+Decode on the serving tower is HBM-bandwidth-bound (PERF.md section
+5: the weights and the live KV are read once a step), so every
+intermediate a step writes to HBM and re-reads is tokens/s lost.
 Two fusions live here (the third — the fused page gather/scatter — is a
 plain donated XLA program in ``serving/paged_cache._pool_move``):
 

@@ -325,8 +325,7 @@ class Profiler:
         forward/backward/optimizer/dataloader plus the serving phases
         (prefill/decode/inference) and pipeline buckets — merged with
         the metrics-registry snapshot (observability.timeline). The
-        machine-readable counterpart of :meth:`summary`; ``bench.py``
-        attaches it under each round's ``phases`` key."""
+        machine-readable counterpart of :meth:`summary`."""
         from ..observability.timeline import phase_summary
         return phase_summary(self.events(), self._step_times)
 

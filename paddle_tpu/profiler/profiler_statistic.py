@@ -8,8 +8,8 @@ reference's HostStatisticNode tree); the DEVICE tier parses the XLA
 trace (``jax.profiler`` xplane via ``jax.profiler.ProfileData``) into a
 ranked per-op table plus op-category shares — the reference's Kernel
 Summary, with categories chosen for the TPU roofline (MXU matmuls vs
-vector/elementwise vs collectives vs copies) so the table feeds the MFU
-residual accounting directly (PERF_NOTES.md).
+vector/elementwise vs collectives vs copies) so the table shows where
+the time that is not matmul goes.
 """
 from __future__ import annotations
 

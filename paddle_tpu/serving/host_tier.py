@@ -3,9 +3,9 @@ paged allocator, swap-in preemption resume, and a standing prefix store.
 
 The PR 2–9 stack treats HBM as the ONLY KV tier: ``PoolExhausted``
 means evict-and-replay — a preempted victim pays a replay prefill
-proportional to its resident tokens (the ``O(replay)`` cost PERF_NOTES
-documents), and a prefix-trie chain evicted under pool pressure is
-simply recomputed on its next admission. This module adds the tier
+proportional to its resident tokens, and a prefix-trie chain evicted
+under pool pressure is simply recomputed on its next admission. This
+module adds the tier
 below HBM, the same device↔host discipline the training side proves out
 in the ZeRO-3 offload path (tests/test_offload.py):
 
@@ -52,8 +52,7 @@ lost/duplicated requests (tools/chaos_soak.py).
 
 Telemetry: the ``serving_swap_*`` family (out/in counters + bytes,
 transfer-latency histograms), the ``serving_host_pool_*`` occupancy
-gauges and the demote/promote counters — linted by
-tools/check_instrumentation.py like every serving hot path.
+gauges and the demote/promote counters.
 """
 from __future__ import annotations
 

@@ -48,7 +48,7 @@ pieces, all HOST-side (no new device programs):
 Recovery cost model: the journal replays ``prompt + tokens[:-1]``
 through the continuation-prefill program — exactly the PR 4 resume
 cost — so recovery time is proportional to RESIDENT tokens, not to the
-wall-clock already served (PERF_NOTES "Fault-tolerant serving").
+wall-clock already served (not measured on a chip).
 
 Determinism note: greedy decode (``temperature == 0``) is bit-identical
 across recovery by construction (replay never re-samples). For sampled

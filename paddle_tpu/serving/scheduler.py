@@ -134,7 +134,7 @@ class ServingScheduler:
         #: engine's own (dispatch, wait, commit) and come out in stats()
         self.spans = engine.spans
         #: host-overhead telemetry mirrors (readable without the
-        #: metrics registry — the bench rider's source): fraction of
+        #: metrics registry): fraction of
         #: the last step's wall time spent on EXPOSED host work (host
         #: bookkeeping not hidden under an in-flight device program),
         #: derived from the span totals in :meth:`step`

@@ -27,16 +27,16 @@ module makes overload behavior a MEASURED, regression-gated quantity:
 
 - :class:`SLOReport` — first-class goodput-under-SLO metrics: p50/p99
   TTFT, p50/p99 per-token latency, deadline-met fraction, goodput
-  (tokens of SLO-met requests per WALL second — the bench tier's
-  headline) and the rejection split (ratelimit / infeasible /
+  (tokens of SLO-met requests per WALL second) and the rejection split (ratelimit / infeasible /
   overload), plus the autoscaler's up/down event counts when one is
   attached.
 
 The harness drives :class:`~paddle_tpu.serving.ServingCluster` (the
 production surface) but accepts anything with ``submit``/``step`` —
 tools/chaos_soak.py --traffic points it at an autoscaling cluster with
-corruption + handoff faults armed, and bench.py's
-``decode_slo_goodput`` tier records its report with provenance.
+corruption + handoff faults armed. A virtual-time simulator for those
+gates, not a measurement: open-loop traffic on a chip is
+``chipbench/traffic/gen.py``'s.
 """
 from __future__ import annotations
 

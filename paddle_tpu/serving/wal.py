@@ -17,9 +17,9 @@ module moves the source of truth to disk:
   highest overhead), ``"group"`` flushes every append to the OS and
   fsyncs at commit boundaries amortized over ``group_interval_s`` (the
   classic group-commit window, default 250 ms: state survives PROCESS
-  death immediately and host power loss up to one window behind —
-  measured < 5% step overhead by the ``decode_durability_overhead``
-  bench rider), ``"off"`` flushes to the OS only. A failed append
+  death immediately and host power loss up to one window behind; its
+  cost a step is not measured on a chip), ``"off"`` flushes to the OS
+  only. A failed append
   ROLLS BACK the file to the last frame boundary, so only real process
   death can leave a torn tail.
 

@@ -29,72 +29,43 @@ def _enable_compilation_cache():
     enable_compile_cache()
 
 
+def _pre_cache(it):
+    """Rank of a test that must run before any compilation-cache
+    activity in its process, else None. test_host_tier moves KV through
+    host memory like the offload suite and carries the same segfault
+    guard (ISSUE 10): offload first (its module fixture assumes a
+    completely cache-naive process)."""
+    path = str(getattr(it, "fspath", it.nodeid))
+    if "test_offload" in path:
+        return 0
+    if "test_host_tier" in path:
+        return 1
+    return None
+
+
 def pytest_collection_modifyitems(config, items):
-    """Run tests/test_offload.py FIRST, then arm the compilation cache
-    to switch on for everything after it: once the cache machinery has
-    been active in a process, the offload suite's host-memory-space
-    programs segfault XLA:CPU (even with the cache re-disabled for
-    that module) — so offload runs before any cache activity and the
-    REST of the suite (including the heavy op sweeps and distributed
-    files) gets the dedup win. PADDLE_TPU_TEST_NO_COMPCACHE=1 opts
-    out (cache never enabled; original order kept)."""
+    """Run tests/test_offload.py and tests/test_host_tier.py FIRST: once
+    the cache machinery has been active in a process, their
+    host-memory-space programs segfault XLA:CPU (even with the cache
+    re-disabled for that module). The rest of the suite keeps its
+    collected order. PADDLE_TPU_TEST_NO_COMPCACHE=1 opts out (cache
+    never enabled; original order kept)."""
     if os.environ.get("PADDLE_TPU_TEST_NO_COMPCACHE") or not items:
         return
-
-    def _pre_cache(it):
-        # test_host_tier moves KV through host memory like the offload
-        # suite and carries the same segfault guard (ISSUE 10): both
-        # run before any compilation-cache activity, offload first
-        # (its module fixture assumes a completely cache-naive process)
-        path = str(getattr(it, "fspath", it.nodeid))
-        if "test_offload" in path:
-            return 0
-        if "test_host_tier" in path:
-            return 1
-        return None
-
     pre = sorted((it for it in items if _pre_cache(it) is not None),
                  key=_pre_cache)
-    rest = [it for it in items if _pre_cache(it) is None]
-    if not rest:
-        return
-    # newest gate files LAST (ISSUE 12, extended by ISSUE 13): the
-    # suite has brushed its tier-1 watchdog since PR 8, so a slow-box
-    # run that gets truncated should lose the NEWEST gates first and
-    # keep the long-established prefix comparable run-to-run — the
-    # overlap/traffic gates still run (and pass) whenever the box
-    # keeps pace. Order within the tail: older first, newest dead last.
-    def _tail_rank(it):
-        path = str(getattr(it, "fspath", it.nodeid))
-        if "test_overlap" in path:
-            return 0
-        if "test_traffic" in path:
-            return 1
-        if "test_adapters" in path:
-            return 2
-        if "test_wal" in path:
-            return 3
-        if "test_tracing" in path:
-            return 4
-        if "test_tp2d" in path:
-            return 5
-        if "test_multiproc" in path:    # ISSUE 19 (the only spawner
-            return 6                    # of worker process trees)
-        if "test_tree_spec" in path:    # ISSUE 20: newest, dead last
-            return 7
-        return None
-    tail = sorted((it for it in rest if _tail_rank(it) is not None),
-                  key=_tail_rank)
-    if tail and tail != rest:
-        rest = [it for it in rest if _tail_rank(it) is None] + tail
-    items[:] = pre + rest
-    config._compcache_boundary = rest[0].nodeid
+    items[:] = pre + [it for it in items if _pre_cache(it) is None]
+    config._compcache_pending = True
 
 
 def pytest_runtest_setup(item):
-    boundary = getattr(item.config, "_compcache_boundary", None)
-    if boundary is not None and item.nodeid == boundary:
-        item.config._compcache_boundary = None
+    """Switch the cache on at this PROCESS's first test that is not an
+    offload / host-tier one: under xdist every worker meets such a test
+    after whatever share of those two files it was handed (a worker runs
+    its items in collected order)."""
+    if getattr(item.config, "_compcache_pending", False) \
+            and _pre_cache(item) is None:
+        item.config._compcache_pending = False
         _enable_compilation_cache()
 
 

@@ -45,6 +45,7 @@ from paddle_tpu.serving import (AdapterPool, AdapterPoolExhausted,
                                 dfa_from_regex, dfa_from_sequences,
                                 init_lora, json_schema_dfa, merge_lora,
                                 rejection_sample_tokens)
+from tools.chaos_soak import _speculator
 
 _CFG = llama.LlamaConfig.tiny(num_layers=2, max_seq_len=64)
 _PARAMS = llama.init_params(jax.random.key(1), _CFG)
@@ -393,7 +394,11 @@ class TestRejectionSampling:
         obs.enable()
         try:
             p = np.tile(_prompts([4], seed=7)[0], 4)
-            eng = _engine(temperature=0.7, spec_k=3)
+            # a speculator that drafts at every step: the n-gram one
+            # stops drafting once a sampled token leaves the motif,
+            # which at this seed is the first, and nothing is counted
+            eng = _engine(temperature=0.7, spec_k=3,
+                          speculator=_speculator(3))
             r = eng.submit(p, max_new_tokens=10)
             eng.run()
             snap = obs.REGISTRY.to_json()

@@ -605,18 +605,14 @@ class TestVisionTransformsExtra:
 
 
 class TestModelsQuantTextExtras:
-    def test_new_model_variants_forward(self):
+    @pytest.mark.parametrize("ctor", [
+        "shufflenet_v2_x0_33", "shufflenet_v2_swish", "resnext50_64x4d"])
+    def test_new_model_variants_forward(self, ctor):
         from paddle_tpu.vision import models as M
         x = paddle.randn([1, 3, 64, 64])
-        m = M.shufflenet_v2_x0_33(num_classes=10)
+        m = getattr(M, ctor)(num_classes=10)
         m.eval()
         assert m(x).shape == [1, 10]
-        m2 = M.shufflenet_v2_swish(num_classes=10)
-        m2.eval()
-        assert m2(x).shape == [1, 10]
-        r = M.resnext50_64x4d(num_classes=10)
-        r.eval()
-        assert r(x).shape == [1, 10]
 
     def test_quantization_bases(self):
         from paddle_tpu.quantization import (BaseObserver, BaseQuanter,

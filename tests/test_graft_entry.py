@@ -11,7 +11,7 @@ import subprocess
 import sys
 import time
 
-pytestmark = pytest.mark.slow  # subprocess/integration heavies (tools/run_tests.sh --fast skips)
+pytestmark = pytest.mark.slow  # subprocess/integration heavies: not in tier-1
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,26 +51,6 @@ def test_dryrun_multichip_8_under_wallclock(capfd):
         "a mesh compiled with GSPMD full-remat fallback")
 
 
-def test_bench_smoke_cpu_prints_json():
-    """The explicit CPU smoke prints one parseable JSON line with no
-    device utilization in it; without the switch, no chip is an error."""
-    env = dict(os.environ, PADDLE_TPU_BENCH_PLATFORM="cpu")
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-                          text=True, timeout=300, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout[-2000:]
-    parsed = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert parsed["metric"] == "llama_train_tokens_per_sec_per_chip"
-    assert parsed["value"] > 0 and parsed["extra"]["mfu"] is None
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_TPU_BENCH_PLATFORM", None)
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                          text=True, timeout=300, env=env, cwd=REPO)
-    assert proc.returncode != 0 and not proc.stdout.strip()
-    assert "no TPU" in proc.stderr
-
-
 def test_aot_validate_7b_smoke():
     """tools/aot_validate.py must keep lowering the north-star 7B recipe
     and emitting the HBM-budget JSON (VERDICT r3 weak #5)."""
@@ -85,29 +65,3 @@ def test_aot_validate_7b_smoke():
     assert rows and rows[0]["config"] == "llama2_7b_tp8_zero"
     assert rows[0]["fits_v5p"] is True
     assert rows[0]["resident_gb_per_chip"] > 0
-
-
-def test_benchmark_recipes_smoke():
-    """The BASELINE.md benchmark recipes (benchmarks/) must run and emit
-    a JSON metric on the virtual CPU mesh (tiny preset)."""
-    import json
-    import os
-    import subprocess
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = root
-    for script in ("gpt2_dp.py", "moe_ep.py",
-                   "llama_tp_sharding.py", "llama_3d.py",
-                   "resnet_fit.py", "ernie_mlm.py"):
-        proc = subprocess.run(
-            [sys.executable, os.path.join(root, "benchmarks", script),
-             "--iters", "2"],
-            env=env, text=True, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, timeout=420)
-        assert proc.returncode == 0, (script, proc.stdout[-1500:])
-        last = proc.stdout.strip().splitlines()[-1]
-        parsed = json.loads(last)
-        assert parsed["value"] > 0 and "metric" in parsed, (script, last)

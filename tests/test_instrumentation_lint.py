@@ -1,8 +1,11 @@
-"""Fast tier-1 guard: the hot-path telemetry hooks must stay in place
-(tools/check_instrumentation.py — a dropped hook silently blinds every
-future BENCH_r*.json per-phase breakdown)."""
+"""Fast tier-1 guard of two conditions of the serving design
+(tools/check_instrumentation.py): every declared fault site is threaded
+through a hot-path module, and the dispatch path holds no device-to-host
+read."""
 import importlib.util
 import os
+
+import pytest
 
 
 def _load_checker():
@@ -15,18 +18,40 @@ def _load_checker():
     return mod, root
 
 
-def test_hot_paths_keep_their_telemetry_hooks():
+def test_fault_sites_threaded_and_dispatch_path_sync_free():
     mod, root = _load_checker()
     problems = mod.check(root)
     assert problems == [], "\n".join(problems)
 
 
-def test_checker_flags_a_dropped_hook(tmp_path):
-    """The lint itself must fail loudly when a hook disappears."""
-    mod, root = _load_checker()
-    fake = tmp_path / "paddle_tpu" / "distributed"
-    fake.mkdir(parents=True)
-    (fake / "watchdog.py").write_text("def tick(self): pass\n")
-    problems = mod.check(str(tmp_path))
-    assert any("watchdog" in p and "_obs.watchdog_tick(" in p
-               for p in problems)
+def _plant_unthreaded_site(tree):
+    (tree / "paddle_tpu/serving/resilience.py").write_text(
+        'ENGINE_SITES = ("decode_step", "never_threaded")\n'
+        'CLUSTER_SITES = ()\n')
+    (tree / "paddle_tpu/inference/predictor.py").write_text(
+        'fault_point("decode_step")\n')
+    return "never_threaded"
+
+
+def _plant_sync_in_dispatch(tree):
+    (tree / "paddle_tpu/inference/predictor.py").write_text(
+        "class E:\n"
+        "    def decode_dispatch(self):\n"
+        "        nxt = np.asarray(self._step(tok))\n"
+        "    def _decode_commit(self):\n"
+        "        return np.asarray(nxt)\n")
+    return "decode_dispatch"
+
+
+@pytest.mark.parametrize("rule, plant", [
+    ("check_fault_sites", _plant_unthreaded_site),
+    ("check_sync_points", _plant_sync_in_dispatch)])
+def test_checker_flags_a_planted_violation(tmp_path, rule, plant):
+    """The lint itself must fail, and name the culprit, when a declared
+    site loses its fault_point or a dispatch function reads the device."""
+    mod, _ = _load_checker()
+    for d in ("paddle_tpu/serving", "paddle_tpu/inference"):
+        (tmp_path / d).mkdir(parents=True)
+    culprit = plant(tmp_path)
+    hits = [p for p in getattr(mod, rule)(str(tmp_path)) if culprit in p]
+    assert len(hits) == 1, hits

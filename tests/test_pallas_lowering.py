@@ -3,9 +3,9 @@
 Every Pallas kernel is lowered for the REAL TPU platform via
 ``jax.export(platforms=['tpu'])`` on this CPU host — no device, no
 execution. This catches the interpret-passes-but-won't-lower bug class
-machine-side: the round-2/3 incident (PERF_NOTES) was rms/swiglu kernels
-green in interpret mode that failed Mosaic lowering on silicon (lane-dim
-slice); nothing in CI would have caught it before a live window.
+machine-side: rms/swiglu kernels once passed in interpret mode and failed
+Mosaic lowering on the chip (a lane-dim slice); nothing in the tests would
+have caught it before a chip run.
 
 The assert is twofold: export succeeds AND the module actually contains
 a Mosaic custom call (``tpu_custom_call``) — a kernel that silently fell
@@ -31,7 +31,7 @@ def _lower_tpu(fn, *args, expect_mosaic=True):
     return mlir
 
 
-# headline-bench-shaped operands, small but real tilings
+# small operands, real tilings
 B, S, H, HK, D = 2, 1024, 4, 2, 128
 
 
@@ -56,19 +56,34 @@ class TestFlashLowering:
             return o.astype(jnp.float32).sum()
         _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
-    def test_bwd_retune_blocks_lower(self):
-        """Every tiling in the flash_bench sweep must lower — the sweep
-        runs unattended in a live window; a config that cannot lower
-        would waste it."""
-        import tools.flash_bench as fb
+    @pytest.mark.parametrize("bq, bk, bqb, bkb", [
+        (512, 1024, None, None),     # the kernel's default
+        (512, 1024, 256, 1024),
+        (512, 1024, 512, 512),
+        (512, 1024, 1024, 512),
+        (512, 1024, 256, 512),
+        (512, 1024, 1024, 1024),
+        (1024, 1024, None, None),
+        (512, 2048, 512, 1024),
+        # backward-focused: smaller q tiles cut the dkv kernel's
+        # re-streamed q traffic, larger k tiles amortize the dq pass
+        (256, 1024, 256, 1024),
+        (512, 512, 512, 512),
+        (256, 1024, 256, 2048),
+        (512, 1024, 128, 1024),
+    ])
+    def test_bwd_retune_blocks_lower(self, bq, bk, bqb, bkb):
+        """Forward and backward tilings a retune of the flash kernel
+        (ROADMAP S3) would try must lower for the chip: a tiling that
+        cannot lower would waste the chip run that compares it."""
         q, k, v = _qkv()
-        for bq, bk, bqb, bkb in fb.CONFIGS:
-            def loss(q, k, v, bq=bq, bk=bk, bqb=bqb, bkb=bkb):
-                o = fa.flash_attention(q, k, v, causal=True, block_q=bq,
-                                       block_k=bk, block_q_bwd=bqb,
-                                       block_k_bwd=bkb)
-                return o.astype(jnp.float32).sum()
-            _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+        def loss(q, k, v):
+            o = fa.flash_attention(q, k, v, causal=True, block_q=bq,
+                                   block_k=bk, block_q_bwd=bqb,
+                                   block_k_bwd=bkb)
+            return o.astype(jnp.float32).sum()
+        _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
     def test_noncausal_and_gqa_lower(self):
         q, k, v = _qkv()

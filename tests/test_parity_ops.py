@@ -371,8 +371,11 @@ class TestLBFGS:
 
     def test_matches_torch_on_least_squares(self):
         import torch
-        X = rng.randn(20, 5).astype("float32")
-        y = rng.randn(20, 1).astype("float32")
+        # its own stream: the module's shared one gives this test other
+        # data in every order of tests, and every xdist split is an order
+        rs = np.random.RandomState(7)
+        X = rs.randn(20, 5).astype("float32")
+        y = rs.randn(20, 1).astype("float32")
         w = paddle.to_tensor(np.zeros((5, 1), "float32"),
                              stop_gradient=False)
         opt = paddle.optimizer.LBFGS(learning_rate=1.0, max_iter=10,
@@ -399,8 +402,12 @@ class TestLBFGS:
         for _ in range(3):
             opt.step(closure)
             topt.step(tclosure)
+        # both stop where float32 no longer resolves the loss: at its
+        # floor of about 1.2 one ulp is 1.2e-7, and with a Hessian near 2
+        # that is sqrt(1.2e-7 * 1.2 / 2) = 2.7e-4 in w (measured over 12
+        # seeds: 4e-9 to 1.7e-4 apart, each within 1.9e-4 of lstsq)
         np.testing.assert_allclose(w.numpy(), tw.detach().numpy(),
-                                   rtol=1e-3, atol=1e-4)
+                                   rtol=1e-3, atol=5e-4)
 
 
 class TestFractionalPooling:

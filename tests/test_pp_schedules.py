@@ -226,7 +226,7 @@ def test_interleave_1f1b_matches_sequential(data):
 def test_interleave_1f1b_residency_bounded_by_depth():
     """The point of the hand-written VPP backward: temp memory must stay
     ~flat as M grows (ring of 2V-1 slots), unlike AD-VPP whose residuals
-    grow with M (223 GB/chip on the 13B recipe, PERF_NOTES)."""
+    grow with M (ROADMAP D5 keeps the prediction for a 13B recipe)."""
     mesh = _mesh()
     chunks = 2
     per_stage = _stage_params(P_ * chunks)

@@ -60,8 +60,7 @@ def _aligned(params, draft_layers=1, damp=1e-3):
     the truncated draft TRACKS the full target — acceptance becomes
     high without touching what either model is: identity gates stay
     exact (both engines see the same damped params) while the 1+k
-    compression actually engages. Same recipe as bench.py's
-    _align_draft_params."""
+    compression actually engages."""
     layers = dict(params["layers"])
     for n in ("wo", "wd"):
         layers[n] = layers[n].at[draft_layers:].multiply(damp)

@@ -6,7 +6,8 @@ devices>).compile()`` runs the real XLA:TPU and Mosaic compilers, so a
 kernel that overflows scoped VMEM or a Mosaic call left to GSPMD fails
 HERE and not on the first chip run. The CPU meshes cannot show either:
 there the flash kernel is not eligible and every test takes the jnp path.
-It proves compilation only; ``chip_smoke.py`` proves the run.
+It proves compilation only; a cell of ``python3 -m chipbench.run`` on the
+chip proves the run.
 """
 import os
 
@@ -21,8 +22,8 @@ from paddle_tpu.models import llama, train, train_pp
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import fused, paged_attention
 
-# the flagship width of chip_smoke.py / bench.py, and a narrow twin with
-# the same 128-wide heads for the tier-1 train steps
+# a 664M Llama-shaped width, and a narrow twin with the same 128-wide
+# heads for the tier-1 train steps
 FLAGSHIP = dict(vocab_size=32000, hidden_size=1536, intermediate_size=4096,
                 num_heads=12, num_kv_heads=12, max_seq_len=4096)
 NARROW = dict(vocab_size=512, hidden_size=256, intermediate_size=512,

@@ -31,6 +31,7 @@ from paddle_tpu.inference import ContinuousBatchingEngine
 from paddle_tpu.serving import (EngineSupervisor, HostPageStore,
                                 ServingCluster, WriteAheadLog,
                                 recover_state)
+from paddle_tpu.serving.resilience import ENGINE_SITES
 from paddle_tpu.serving.constraints import (ConstraintState, TokenDFA,
                                             dfa_from_sequences)
 from paddle_tpu.serving.wal import (_HDR, MAGIC, WalTorn,
@@ -447,24 +448,31 @@ class TestCrashPointSweep:
     recover_from_disk: token-identical replays, zero lost/duplicated,
     balanced allocators."""
 
-    def test_every_engine_site_fp(self):
-        rep = _SOAK.run_crash_sweep()
-        from paddle_tpu.serving.resilience import ENGINE_SITES
-        assert set(rep["sites"]) == set(ENGINE_SITES)
-        assert all(v["deaths"] >= 1 and v["fired"] >= 1
-                   for v in rep["sites"].values())
+    @pytest.mark.parametrize("site", ENGINE_SITES)
+    @pytest.mark.parametrize("kv", [None, "int8"], ids=["fp", "int8"])
+    def test_every_engine_site(self, kv, site, tmp_path):
+        rep = _SOAK.run_crash_sweep(sites=[site], kv_cache_dtype=kv,
+                                    wal_root=str(tmp_path))
+        assert rep["sites"][site]["deaths"] >= 1
+        assert rep["sites"][site]["fired"] >= 1
 
-    def test_every_engine_site_int8(self):
-        rep = _SOAK.run_crash_sweep(kv_cache_dtype="int8")
-        assert all(v["deaths"] >= 1 for v in rep["sites"].values())
+    def test_the_sweep_covers_every_engine_site(self):
+        """The cases above ARE resilience.ENGINE_SITES, both KV tiers:
+        narrowing the parameter list fails here."""
+        marks = {m.args[0]: m.args[1] for m in
+                 type(self).test_every_engine_site.pytestmark}
+        assert tuple(marks["site"]) == ENGINE_SITES
+        assert list(marks["kv"]) == [None, "int8"]
 
-    def test_tp2_representative_sites(self):
+    @pytest.mark.parametrize("site", [
+        "decode_step", "prefill_chunk", "swap_in", "wal_append",
+        "checkpoint_write"])
+    def test_tp2_representative_sites(self, site, tmp_path):
         if len(jax.devices()) < 2:
             pytest.skip("needs >= 2 devices (8-device host platform)")
-        rep = _SOAK.run_crash_sweep(
-            tp=2, sites=["decode_step", "prefill_chunk", "swap_in",
-                         "wal_append", "checkpoint_write"])
-        assert all(v["deaths"] >= 1 for v in rep["sites"].values())
+        rep = _SOAK.run_crash_sweep(tp=2, sites=[site],
+                                    wal_root=str(tmp_path))
+        assert rep["sites"][site]["deaths"] >= 1
 
     @pytest.mark.slow
     def test_tp2_every_engine_site(self):
@@ -601,18 +609,3 @@ class TestHostStoreDiskBound:
     def test_validation(self):
         with pytest.raises(ValueError, match="max_disk_bytes"):
             HostPageStore(page_size=8, max_disk_bytes=0)
-
-
-class TestDurabilityRider:
-    def test_rider_shape(self):
-        """The decode_durability_overhead bench rider measures all
-        three fsync rungs against the journal-off baseline and reports
-        the direct WAL fraction of a step."""
-        import bench
-        rider = bench._durability_rider(_PARAMS, _CFG, 2, 12, 4, 8)
-        assert rider["fsync_policy"] == "group"
-        assert set(rider["steps_per_sec"]) == {"journal_off", "group",
-                                               "commit"}
-        assert rider["wal_ms_per_step"] >= 0
-        assert rider["wal_frac_of_step"] is not None
-        assert rider["overhead_frac"]["commit"] is not None

@@ -2,8 +2,7 @@
 
 Measures pull/push rows/s against a REAL server process over the RPC
 wire, across table sizes and batch sizes, for the sync path and the
-async/geo communicator tiers — the numbers PERF_NOTES.md records
-against the reference's brpc tier
+async/geo communicator tiers, to set against the reference's brpc tier
 (paddle/fluid/distributed/ps/service/brpc_ps_client.h).
 
   python tools/ps_bench.py [--dim 64] [--rows 100000] [--batch 2048]
