@@ -342,22 +342,24 @@ def _named_jit(f, name: str, **jit_kw):
 
 
 class InFlightStep:
-    """One dispatched-but-uncommitted decode/verify program (ISSUE 12).
+    """One dispatched-but-uncommitted decode/verify program.
 
-    The async overlapped runtime splits every engine step into a
-    DISPATCH half (launch the jitted program — JAX dispatch is
-    asynchronous, so this returns while the device works) and a COMMIT
-    half (the single device→host fetch plus all host bookkeeping). The
-    handle carries everything commit needs: the device output array,
-    the mask, and a SNAPSHOT of the per-slot request ids AND seat
-    generations at dispatch time — commit only applies a slot's result
-    when the slot still holds the same SEATING of the same request (a
-    slot preempted-and-readmitted between dispatch and commit must not
-    receive the old seating's token, even when the re-admission seated
-    the SAME request back into its own slot — its pages and lengths
-    were reset, so the in-flight token belongs to freed pages; the
-    victim re-decodes the dropped token on resume, greedy-identically,
-    so no stream ever forks)."""
+    Every engine step has a DISPATCH half (launch the jitted program —
+    JAX dispatch is asynchronous, so this returns while the device
+    works — and advance every piece of host state that no token's
+    VALUE decides: lengths, token counts, the sliding pool's pages) and
+    a COMMIT half (the device→host read and what hangs on the values:
+    ``req.tokens``, ``eos``, retirement). The handle carries what the
+    commit needs: the device output array, the mask, and a SNAPSHOT of
+    the per-slot request ids AND seat generations at dispatch time —
+    commit only applies a slot's result when the slot still holds the
+    same SEATING of the same request. A row whose earlier token turned
+    out to be ``eos`` was retired before this step's commit and its
+    result is dropped here; so is that of a slot that changed hands
+    (even when the re-admission seated the SAME request back into its
+    own slot — its pages and lengths were reset, so the in-flight
+    token belongs to freed pages; the victim re-decodes the dropped
+    token on resume, greedy-identically, so no stream ever forks)."""
     __slots__ = ("kind", "mask", "rids", "seats", "out", "drafts",
                  "dlen", "t0", "t0f", "raw", "ttr", "qs", "rows")
 
@@ -550,16 +552,14 @@ class ContinuousBatchingEngine:
         self.temperature = float(temperature)
         self.eos_token_id = eos_token_id
         self.use_kernel = use_kernel
-        # --- async overlapped runtime (ISSUE 12): overlap=True marks
-        # this engine for the double-buffered scheduler pipeline — a
-        # ServingScheduler attached without its own overlap= knob
-        # inherits it, and the host tier's swap-out DMAs go
-        # NON-BLOCKING (issued at preemption, fenced at the next
-        # commit) so the device→host copy rides under the in-flight
-        # decode step. The dispatch/commit split itself is always
-        # available (decode_step == dispatch immediately followed by
-        # commit), so the synchronous path stays the bit-identity
-        # reference.
+        # overlap=True sends the host tier's swap-out DMAs NON-BLOCKING
+        # (issued at preemption, fenced at the next commit) so the
+        # device→host copy rides under the decode steps that follow.
+        # How deep the decode pipeline runs is NOT this knob's to say:
+        # the dispatch/commit split is always there (decode_step ==
+        # dispatch immediately followed by commit, the bit-identity
+        # reference) and :meth:`pipeline_depth` decides from what the
+        # engine holds.
         self.overlap = bool(overlap)
         # --- low-bit decode tiers (ISSUE 11): weight_bits quantizes the
         # weights at construction (8 = per-channel int8, 4 = per-group
@@ -731,17 +731,34 @@ class ContinuousBatchingEngine:
         # re-seated (even into its own slot, rid unchanged) between
         # dispatch and commit never receives the stale seating's token
         self._seat = np.zeros((max_batch,), np.int64)
+        # tokens a row has been LAUNCHED for (>= len(req.tokens), which
+        # counts those read): the max_len finish is decided from this
+        # at dispatch, so no row is launched past its count
         self._ntok = np.zeros((max_batch,), np.int64)
         self._maxnew = np.zeros((max_batch,), np.int64)
         self._eos = np.full((max_batch,), -1, np.int64)
-        # in-flight dispatched-but-uncommitted work (overlap pipeline):
-        # at most ONE decode/verify program plus this step's prefill
-        # chunk handles — committed in dispatch order by commit_inflight
-        self._inflight: Optional[InFlightStep] = None
-        self._inflight_chunks: List[Dict] = []
+        # dispatched-but-uncommitted work in LAUNCH order, as
+        # ``(launch_seq, handle)``: prefill chunk handles (dicts) and
+        # decode/verify programs (InFlightStep) — commit_inflight takes
+        # them from the front, so a caller can leave the newest step
+        # running
+        self._inflight: List[tuple] = []
+        self.launch_seq = 0
+        # every row's newest token AS THE DEVICE HOLDS IT: the decode
+        # program's own output (inactive rows carried through), with a
+        # final chunk's first token written into its row. The next
+        # decode program takes a row's token from here wherever the
+        # host has not read it yet (_rows_on_device)
+        self._tok_dev = None
+        self._put_row_fn = None
         # span totals and counters of this engine (and of the scheduler
         # that owns it); they come out in stats()
         self.spans = SpanTotals()
+        for name in ("decode_launches_total",
+                     "decode_launches_pipelined_total",
+                     "pipeline_rows_dropped_total",
+                     "pipeline_fences_total"):
+            self.spans.count(name, 0)
         self._launched: set = set()     # program keys already called once
         self._next_rid = 0
         self._steps = 0
@@ -1057,8 +1074,15 @@ class ContinuousBatchingEngine:
                     + ("adapters", "batch") * ad_on,
                     out_kinds=("rep", "pool") + ("rep",) * moe)
 
+            nrows = self.max_batch
+
             def f(params, last, paged, tables, lengths, active, key,
-                  *extra):
+                  prev, *extra):
+                # ``last`` is the host's copy of every row's newest
+                # token, -1 where the host has not read it yet: that
+                # row's comes from ``prev``, the output of the decode
+                # program launched before this one (tokens first)
+                last = jnp.where(last >= 0, last, prev[:nrows])
                 # extra layout (engine-config-static): what ``fwd``
                 # takes (sliding block tables, adapter arrays and
                 # slots), then [the (B, V) allowed-token mask] when
@@ -1082,6 +1106,9 @@ class ContinuousBatchingEngine:
                 else:
                     nxt = jax.random.categorical(
                         key, logits / temp, axis=-1).astype(jnp.int32)
+                # a row that did not decode keeps its token, so the
+                # output is every row's newest token for the next launch
+                nxt = jnp.where(active, nxt, last)
                 if moe:
                     # the counters ride behind the tokens: one read
                     nxt = jnp.concatenate(
@@ -1518,10 +1545,20 @@ class ContinuousBatchingEngine:
         the transient ``preempted`` until the resume completes;
         ``done`` stays False. Returns the number of pages actually
         returned to the free list."""
+        # the victim's committed state (tokens, lengths, what a swap
+        # copies out) has to be final first: read what is in flight
+        self.fence()
         slot = req.slot
         if slot is None or self._slots[slot] is not req:
             raise ValueError(
                 f"preempt_request: request {req.rid} is not running")
+        return self._evict_seated(req)
+
+    def _evict_seated(self, req: GenerationRequest) -> int:
+        """:meth:`preempt_request` after its fence. A program still in
+        flight for this seating (only a caller that skips the fence
+        leaves one) has its row dropped at commit by the seat guard."""
+        slot = req.slot
         swap = self.swap_candidate(req)
         self._pending.pop(slot, None)
         t_tr = _obs.serving_trace_now()
@@ -1566,6 +1603,9 @@ class ContinuousBatchingEngine:
         if req.done:
             return
         if req.slot is not None and self._slots[req.slot] is req:
+            self.fence()        # it may finish by itself in there
+            if req.done:
+                return
             self._pending.pop(req.slot, None)
             self._retire(req, reason)
             return
@@ -1608,14 +1648,17 @@ class ContinuousBatchingEngine:
 
     def prefill_dispatch(self, slot: Optional[int] = None,
                          max_tokens: Optional[int] = None) -> int:
-        """DISPATCH half of :meth:`prefill_step` (ISSUE 12): launch one
-        pending admission's next static-shape chunk program and queue
-        an in-flight handle; ALL host mutation (the ``done`` cursor,
-        prefix registration, first-token sampling) waits for
-        :meth:`commit_prefills`. On a FINAL chunk the first token is
-        argmax/sampled ON DEVICE here — the PRNG split happens at
-        dispatch, so the sync and overlapped paths split keys in the
-        same order — and only the scalar fetch is deferred to commit.
+        """DISPATCH half of :meth:`prefill_step`: launch one pending
+        admission's next static-shape chunk program, queue an in-flight
+        handle, and advance what no token's value decides — the
+        ``done`` cursor, the sliding pool's pages and, on a FINAL
+        chunk, the row's length and its place among the decode-ready
+        rows (the next decode program may be launched behind it at
+        once). On a FINAL chunk the first token is argmax/sampled ON
+        DEVICE here — the PRNG split happens at dispatch, so the
+        synchronous and pipelined paths split keys in the same order —
+        and goes into the device's token row; the scalar fetch, the
+        prefix registration and ``req.tokens`` wait for the commit.
         Returns the width actually scheduled (0 when nothing was)."""
         if not self._pending:
             return 0
@@ -1623,11 +1666,8 @@ class ContinuousBatchingEngine:
         if slot is None:
             slot = min(self._pending,
                        key=lambda s: self._pending[s][0].rid)
-        req, seq, done = self._pending[slot]
-        if any(h["slot"] == slot for h in self._inflight_chunks):
-            raise RuntimeError(
-                f"prefill_dispatch: slot {slot} already has an "
-                f"in-flight chunk — commit it first")
+        ent = self._pending[slot]
+        req, seq, done = ent
         S = seq.size
         page = cache.page_size
         remaining = S - done
@@ -1653,18 +1693,19 @@ class ContinuousBatchingEngine:
         _fault_point("prefill_chunk")
         t0 = _obs.generate_begin()
         with self.spans.span("engine.dispatch", kind="chunk"):
+            # host arrays go to the device as COPIES: on the CPU
+            # backend jnp.asarray may alias the numpy buffer, and the
+            # host writes these rows again while the program runs
             args = [self.params, jnp.asarray(chunk), cache.pool,
-                    jnp.asarray(cache.block_tables[slot]),
+                    jnp.asarray(cache.block_tables[slot].copy()),
                     jnp.int32(done), jnp.int32(take)]
             if cache.window:
                 # the sliding layers' pages for the chunk's positions
-                # (a copy: the commit writes this row while a chunk
-                # that no read waited for may still be running)
                 cache.window_extend(slot, done + take)
                 args += [jnp.asarray(cache.window_tables[slot].copy())]
             if self.adapters is not None:
                 args += [self.adapters.arrays,
-                         jnp.asarray(self._aslot[slot:slot + 1])]
+                         jnp.asarray(self._aslot[slot:slot + 1].copy())]
             if self._moe_acc is not None:
                 args += [self._moe_acc]
             logits, cache.pool, *acc = self._launch(
@@ -1687,27 +1728,85 @@ class ContinuousBatchingEngine:
                     # violation-avoided counter covers this commit path
                     # like the decode one.
                     rawmax = jnp.argmax(lg)
-                    lg = jnp.where(jnp.asarray(self._cmask[slot]), lg,
-                                   -jnp.inf)
+                    lg = jnp.where(jnp.asarray(self._cmask[slot].copy()),
+                                   lg, -jnp.inf)
                 if self.temperature == 0.0:
                     samp = jnp.argmax(lg)
                 else:
                     self._key, k = jax.random.split(self._key)
                     samp = jax.random.categorical(
                         k, lg / self.temperature)
-            self._inflight_chunks.append(
+            # ---- the half of the commit that no value decides ----
+            done += take
+            final = done >= S
+            if cache.window:
+                # what the next chunk's (or the first decode step's)
+                # first query no longer sees goes back to the sliding
+                # pool: a program that takes such a page is launched
+                # after this one, so it runs after it
+                self.spans.count("window_pages_released_total",
+                                 cache.window_release(slot, done))
+            if not final:
+                ent[2] = done
+            else:
+                del self._pending[slot]
+                cache.lengths[slot] = S
+                if cache.window:
+                    cache.window_extend(slot, S + 1)  # first decode write
+                if req.tokens:
+                    # preemption resume: the replay covered prompt +
+                    # tokens[:-1]; decode continues from the already-
+                    # sampled last token (its KV lands on the next
+                    # decode step, exactly as in the uninterrupted run)
+                    self._last[slot] = np.int32(req.tokens[-1])
+                else:
+                    self._ntok[slot] = 1
+                    self._tok_dev = self._put_row(slot, samp)
+            self._launched_handle(
                 {"slot": slot, "req": req,
                  "seat": int(self._seat[slot]), "take": take, "t0": t0,
+                 "done": done, "final": final,
                  "logits": logits, "samp": samp, "rawmax": rawmax,
                  "ttr": _obs.serving_trace_now()})
         return width
 
+    def _launched_handle(self, h):
+        """Queue the handle of a program just launched, in launch
+        order; returns it."""
+        self.launch_seq += 1
+        self._inflight.append((self.launch_seq, h))
+        return h
+
+    def _token_row(self):
+        """The device's copy of every row's newest token (zeros before
+        the first launch), on the engine's mesh where it has one."""
+        if self._tok_dev is None:
+            tok = jnp.zeros((self.max_batch + (
+                4 if self._moe_acc is not None else 0),), jnp.int32)
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+                tok = jax.device_put(
+                    tok, NamedSharding(self.mesh, PartitionSpec()))
+            self._tok_dev = tok
+        return self._tok_dev
+
+    def _put_row(self, slot: int, tok):
+        """The device's token row with ``tok`` (a device scalar, not
+        read) in ``slot``. One small program whatever the slot."""
+        if self._put_row_fn is None:
+            self._put_row_fn = _named_jit(
+                lambda row, i, t: row.at[i].set(t.astype(row.dtype)),
+                "put_token_row")
+        return self._put_row_fn(self._token_row(), jnp.int32(slot), tok)
+
     def _commit_chunk(self, h: Dict) -> int:
-        """COMMIT half of one dispatched prefill chunk: fence, advance
-        the ``done`` cursor, and on completion publish the prompt to
-        the prefix trie and seed decode — on a preemption RESUME the
-        next token is already known and is fed back into decode
-        instead of re-sampling (the resumed request must not fork)."""
+        """COMMIT half of one dispatched prefill chunk. A chunk that is
+        not the prompt's last has nothing to read and costs no wait;
+        the last one fetches the first token, publishes the prompt to
+        the prefix trie and records the token (``eos`` may retire the
+        row here). On a preemption RESUME the next token was known at
+        dispatch and nothing is sampled (the resumed request must not
+        fork)."""
         slot, req, take = h["slot"], h["req"], h["take"]
         with self.spans.span("engine.wait", kind="chunk"):
             # both obs calls fence the chunk logits when a sink is
@@ -1718,50 +1817,30 @@ class ContinuousBatchingEngine:
             _obs.serving_prefill_chunk(h["t0"], h["logits"], take)
             # a final chunk's first token: the ONE device→host fetch
             first = int(h["samp"]) if h["samp"] is not None else None
-        ent = self._pending.get(slot)
-        if (ent is None or ent[0] is not req
+        if (self._slots[slot] is not req
                 or int(self._seat[slot]) != h["seat"]):
-            # cancelled/expired — or preempted and RE-ADMITTED (even
-            # the same request: the seat generation moved, so this
-            # chunk's KV went to the old seating's freed pages) —
-            # between dispatch and commit: commit nothing; the fresh
-            # admission replays the span through its own chunks
+            # the slot changed hands between dispatch and commit (even
+            # to the same request: the seat generation moved, so this
+            # chunk's KV went to the old seating's freed pages):
+            # commit nothing; the fresh admission replays the span
+            # through its own chunks
             return 0
         with self.spans.span("engine.commit", rows=1):
-            return self._commit_chunk_host(h, ent, first)
+            return self._commit_chunk_host(h, first)
 
-    def _commit_chunk_host(self, h: Dict, ent: List, first) -> int:
+    def _commit_chunk_host(self, h: Dict, first) -> int:
         """The host bookkeeping of :meth:`_commit_chunk`, after the
-        read: the ``done`` cursor, prefix registration, the first
-        token."""
+        read: the trace span, prefix registration, the first token."""
         slot, req, take = h["slot"], h["req"], h["take"]
-        cache = self.cache
-        done = ent[2] + take
         _obs.serving_trace_span(
             req, "prefill_chunk", h.get("ttr", 0),
             replica=self.replica_id, slot=slot, seq=len(req.tokens),
-            meta={"take": int(take), "done": int(done)})
-        if cache.window:
-            # what the next chunk's (or the first decode step's) first
-            # query no longer sees goes back to the sliding pool
-            self.spans.count("window_pages_released_total",
-                             cache.window_release(slot, done))
-        if done < ent[1].size:
-            ent[2] = done
+            meta={"take": int(take), "done": int(h["done"])})
+        if not h["final"]:
             return take
-        del self._pending[slot]
-        cache.register_prefix(slot, req.prompt[0])
-        cache.lengths[slot] = ent[1].size
-        if cache.window:
-            cache.window_extend(slot, done + 1)   # the first decode write
+        self.cache.register_prefix(slot, req.prompt[0])
         req.finish_reason = None            # clears transient "preempted"
-        if req.tokens:
-            # preemption resume: the replay covered prompt +
-            # tokens[:-1]; decode continues from the already-sampled
-            # last token (its KV lands on the next decode step, exactly
-            # as in the uninterrupted run).
-            self._last[slot] = np.int32(req.tokens[-1])
-        else:
+        if first is not None:
             self._last[slot] = first
             # violation check against the PRE-advance slot mask with
             # the UNMASKED argmax, mirroring the decode commit — read
@@ -1782,15 +1861,6 @@ class ContinuousBatchingEngine:
                     time.perf_counter_ns() - t0m, viol, 1)
         return take
 
-    def commit_prefills(self) -> int:
-        """Commit every in-flight prefill chunk in dispatch order;
-        returns prompt tokens committed."""
-        n = 0
-        chunks, self._inflight_chunks = self._inflight_chunks, []
-        for h in chunks:
-            n += self._commit_chunk(h)
-        return n
-
     def prefill_step(self, slot: Optional[int] = None,
                      max_tokens: Optional[int] = None) -> int:
         """Advance ONE pending admission by one static-shape chunk
@@ -1803,11 +1873,12 @@ class ContinuousBatchingEngine:
         token) seed sampling — except on a preemption RESUME, where the
         next token is already known and is fed back into decode instead
         — and the completed prompt's pages are published to the prefix
-        trie for future admissions. Synchronous composition of
-        :meth:`prefill_dispatch` + :meth:`commit_prefills` — the
-        overlapped scheduler drives the halves separately."""
+        trie for future admissions. Synchronous composition: whatever
+        is in flight commits first, then :meth:`prefill_dispatch` +
+        :meth:`commit_inflight`."""
+        self.commit_inflight()
         width = self.prefill_dispatch(slot, max_tokens=max_tokens)
-        self.commit_prefills()
+        self.commit_inflight()
         return width
 
     def _record_token(self, req: GenerationRequest, tok: int):
@@ -1815,9 +1886,11 @@ class ContinuousBatchingEngine:
         if len(req.tokens) == 1:
             _obs.serving_trace_first_token(req)
         if req.slot is not None:
-            # keep the vectorized-commit mirror in sync on the scalar
-            # paths (prefill first-token, spec commit loop)
-            self._ntok[req.slot] = len(req.tokens)
+            # the launched-token count never lags the read one: the
+            # verify paths commit several tokens a launch and count
+            # them here (a decode launch counted its own already)
+            self._ntok[req.slot] = max(self._ntok[req.slot],
+                                       len(req.tokens))
         if req.eos_token_id is not None and tok == req.eos_token_id:
             self._retire(req, "eos")
         elif len(req.tokens) >= req.max_new_tokens:
@@ -1827,6 +1900,17 @@ class ContinuousBatchingEngine:
         req.done = True
         req.finish_reason = reason
         _obs.serving_trace_finish(req, reason, replica=self.replica_id)
+        # A row that ends by ``eos`` may have one more decode program
+        # in flight (launched before this token was read): that
+        # program writes the eos token's KV into a page released here.
+        # No later owner can read the stray row. It was launched before
+        # anything the next owner launches, so it runs before it
+        # (device programs run in launch order); the owner reads only
+        # positions below its own length, each of which it wrote
+        # itself, later; and the prefix trie holds only the rows a
+        # PROMPT filled (full prompt pages, and the first
+        # ``prompt % page`` rows of the tail page), while the stray row
+        # lies at position prompt + tokens, past all of them.
         self.cache.release(req.slot)
         self._clear_slot(req.slot)
         if self.adapters is not None and req.adapter_id:
@@ -1866,6 +1950,17 @@ class ContinuousBatchingEngine:
         _obs.serving_tp_logits_gather(t0, probe(x))
 
     # ---- prefill→decode KV handoff (ISSUE 9) ----
+    def handoff_candidates(self) -> List[GenerationRequest]:
+        """Seated requests whose prompt is in the pool and whose first
+        token has been read: what a prefill replica may hand to a
+        decode replica. Reads what is in flight first, so a request is
+        offered as soon as its final chunk has run, before it decodes
+        here."""
+        self.fence()
+        return [r for r in self._slots
+                if r is not None and not r.done and r.tokens
+                and r.slot not in self._pending]
+
     def export_prefilled(self, req: GenerationRequest,
                          with_kv: bool = True) -> Dict:
         """Export a fully prefilled, decode-ready request's KV pages as
@@ -1880,6 +1975,7 @@ class ContinuousBatchingEngine:
         importer copies them device-to-device through the fused
         :func:`~paddle_tpu.serving.paged_cache._pool_move` instead;
         the payload then carries only the slot metadata."""
+        self.fence()            # the last token and the length, read
         slot = req.slot
         if slot is None or self._slots[slot] is not req:
             raise ValueError(
@@ -1953,6 +2049,7 @@ class ContinuousBatchingEngine:
         keeps the prefill replica's trie warm for the tenant's next
         prompt. ``slot`` is the ORIGINAL slot from the export payload
         (``req.slot`` already points at the importing engine)."""
+        self.fence()
         if self._slots[slot] is not req:
             raise ValueError(
                 f"finish_handoff: slot {slot} does not hold request "
@@ -1966,35 +2063,91 @@ class ContinuousBatchingEngine:
 
     def ready_mask(self) -> np.ndarray:
         """(max_batch,) bool — slots whose sequence is fully in the
-        pool and can decode this step; slots mid-prefill hold pages
-        (active) but skip the decode program."""
-        ready = self.cache.active.copy()
+        pool (or will be when the chunks launched so far have run) and
+        that have tokens left to be launched for; slots mid-prefill
+        hold pages (active) but skip the decode program, and a row
+        launched for its last token waits for its commit."""
+        ready = self.cache.active & (self._ntok < self._maxnew)
         if self._pending:
             ready[list(self._pending)] = False
         return ready
 
-    # ---- dispatch / commit halves (ISSUE 12 overlapped runtime) ----
+    # ---- dispatch / commit halves: the decode pipeline ----
     def has_inflight(self) -> bool:
         """True while a dispatched decode/verify program or prefill
-        chunk awaits its commit — the overlapped scheduler's signal
-        that a commit fence is pending."""
-        return self._inflight is not None or bool(self._inflight_chunks)
+        chunk awaits its commit."""
+        return bool(self._inflight)
+
+    def pipeline_depth(self) -> int:
+        """How many decode steps may run ahead of the one being read:
+        1 wherever the next launch needs nothing of the tokens in
+        flight but their values on the device, 0 where the host has to
+        see them first — a proposer reads the committed history
+        (speculation, tree verification) and a grammar's next mask
+        follows from the token. Decided from what the engine holds,
+        each step; no caller's flag."""
+        if self.spec is not None:
+            return 0
+        if self.constraints and any(
+                r is not None and r.constraint is not None
+                for r in self._slots):
+            return 0
+        return 1
+
+    def fence(self) -> int:
+        """Read and commit everything in flight because what comes next
+        needs the committed state: a launch at depth 0, a preemption,
+        swap-out, cancel or handoff of a seated request, a drain.
+        Counted in ``pipeline_fences_total`` when there was something
+        to wait for. Returns the committed units."""
+        if not self._inflight:
+            return 0
+        self.spans.count("pipeline_fences_total", 1)
+        return self.commit_inflight()
+
+    def drop_inflight(self) -> None:
+        """Forget what is in flight without reading it (a supervisor
+        abandoning a poisoned engine)."""
+        self._inflight = []
+
+    def _rows_on_device(self) -> np.ndarray:
+        """(max_batch,) bool — rows whose newest token the host has not
+        read yet: launched in a decode program, or sampled by a final
+        chunk, that is still in flight for the slot's present seating.
+        The next decode program takes those from the device's token
+        row and every other from ``_last``."""
+        on = np.zeros((self.max_batch,), bool)
+        for _, h in self._inflight:
+            if isinstance(h, dict):
+                if (h["samp"] is not None
+                        and self._slots[h["slot"]] is h["req"]
+                        and int(self._seat[h["slot"]]) == h["seat"]):
+                    on[h["slot"]] = True
+            elif h.kind == "decode":
+                on |= (h.mask & (h.rids == self._rids)
+                       & (h.seats == self._seat))
+        return on
 
     def decode_dispatch(self, mask) -> Optional[InFlightStep]:
         """DISPATCH half of :meth:`decode_step`: launch the jitted
         ragged decode program for the ``mask`` slots and return the
-        in-flight handle WITHOUT fetching the result — the device works
-        while the caller plans the next step. The PRNG split happens
-        here (same order as the synchronous path). At most one
-        decode/verify program may be in flight."""
+        in-flight handle WITHOUT fetching the result. The program may
+        be launched BEHIND one whose tokens the host has not read: a
+        row's input token then comes from that program's output on the
+        device. What follows from the launch alone is booked here —
+        each row's length and launched-token count (so ``ready_mask``
+        stops a row at its ``max_new_tokens``), the sliding pool's page
+        for the next position and the pages the window slid past, the
+        PRNG split (same order as the synchronous path); what depends
+        on a token's value waits for the commit. At
+        :meth:`pipeline_depth` 0 everything in flight commits first."""
         cache = self.cache
         mask = np.asarray(mask, bool)
+        if self._inflight and not self.pipeline_depth():
+            self.fence()
+            mask = mask & self.ready_mask()
         if not mask.any():
             return None
-        if self._inflight is not None:
-            raise RuntimeError(
-                "decode_dispatch: a decode/verify program is already "
-                "in flight — commit_inflight() first")
         # resilience sites: step execution (before the launch), then
         # the dispatch seam (after it) — neither commits host state,
         # so a fault at either recovers by journal replay
@@ -2002,17 +2155,24 @@ class ContinuousBatchingEngine:
         t0f = _obs.generate_begin() if self.fused else 0
         with self.spans.span("engine.dispatch", kind="decode"):
             self._key, k = jax.random.split(self._key)
-            args = [self.params, jnp.asarray(self._last), cache.pool,
-                    jnp.asarray(cache.block_tables),
-                    jnp.asarray(cache.lengths),
-                    jnp.asarray(mask), k]
+            # host arrays go to the device as COPIES (np.where makes
+            # one): on the CPU backend jnp.asarray may alias the numpy
+            # buffer, and the host advances lengths and tables while
+            # the program runs
+            last = np.where(self._rows_on_device(), np.int32(-1),
+                            self._last)
+            args = [self.params, jnp.asarray(last), cache.pool,
+                    jnp.asarray(cache.block_tables.copy()),
+                    jnp.asarray(cache.lengths.copy()),
+                    jnp.asarray(mask), k, self._token_row()]
             if cache.window:
                 args += [jnp.asarray(cache.window_tables.copy())]
             if self.adapters is not None:
-                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+                args += [self.adapters.arrays,
+                         jnp.asarray(self._aslot.copy())]
             if self.constraints:
                 if self._cmask_dirty or self._cmask_dev is None:
-                    self._cmask_dev = jnp.asarray(self._cmask)
+                    self._cmask_dev = jnp.asarray(self._cmask.copy())
                     self._cmask_dirty = False
                 args += [self._cmask_dev]
             if self._moe_acc is not None:
@@ -2023,24 +2183,36 @@ class ContinuousBatchingEngine:
             if self.constraints:
                 out, raw = out
             _fault_point("dispatch")
-            self._inflight = InFlightStep(
+            self._tok_dev = out
+            self.spans.count("decode_launches_total", 1)
+            if any(isinstance(h, InFlightStep) for _, h in self._inflight):
+                self.spans.count("decode_launches_pipelined_total", 1)
+            h = self._launched_handle(InFlightStep(
                 "decode", mask, self._rids.copy(), self._seat.copy(),
-                out, t0f=t0f, raw=raw, ttr=_obs.serving_trace_now())
-        return self._inflight
+                out, t0f=t0f, raw=raw, ttr=_obs.serving_trace_now()))
+            # ---- the half of the commit that no value decides ----
+            slots = np.flatnonzero(mask)
+            cache.lengths[slots] += 1
+            if cache.window:
+                self.spans.count("window_pages_released_total",
+                                 cache.window_step(slots))
+            self._ntok[slots] += 1
+        return h
 
     def _decode_commit(self, h: InFlightStep) -> int:
         """COMMIT half of :meth:`decode_step`: the single device→host
-        fetch plus VECTORIZED host bookkeeping — lengths/last-token
-        scatter via one fancy-indexed update, eos/max_len finish
-        detection against the mirrored per-slot arrays, per-row Python
-        work only for the rows that actually finish. A slot whose
-        request changed since dispatch (preempt + readmit) is skipped
-        via the rid snapshot; the dropped token is re-decoded
-        greedy-identically on resume."""
+        fetch plus VECTORIZED host bookkeeping — the last-token scatter
+        via one fancy-indexed update, eos/max_len finish detection
+        against the mirrored per-slot arrays, per-row Python work only
+        for the append and for the rows that actually finish. A slot
+        whose seating changed since dispatch (retired by an earlier
+        step's ``eos``, or preempted and re-seated) is skipped via the
+        rid/seat snapshot and counted in
+        ``pipeline_rows_dropped_total``."""
         # resilience sites: the commit seam, then the device→host
-        # transfer — host state commits only after both, so a fault at
-        # either leaves the request handles at the previous step's
-        # committed state (the supervisor's recovery contract)
+        # transfer — request handles change only after both, so a fault
+        # at either leaves them at the previous step's committed state
+        # (the supervisor's recovery contract)
         _fault_point("commit")
         # the device-wait span OPENS before the observability calls:
         # serving_fused_latency fences h.out when metrics are on, and
@@ -2070,20 +2242,18 @@ class ContinuousBatchingEngine:
         valid = (h.mask & (self._rids == h.rids) & (h.rids >= 0)
                  & (self._seat == h.seats))
         slots = np.flatnonzero(valid)
+        self.spans.count("pipeline_rows_dropped_total", rows - slots.size)
         if slots.size:
             toks = nxt[slots]
-            cache.lengths[slots] += 1
-            if cache.window:
-                self.spans.count("window_pages_released_total",
-                                 cache.window_step(slots))
             self._last[slots] = toks
-            new_cnt = self._ntok[slots] + 1
-            self._ntok[slots] = new_cnt
-            fin_eos = (self._eos[slots] >= 0) & (toks == self._eos[slots])
-            fin_max = new_cnt >= self._maxnew[slots]
             sl, tl = slots.tolist(), toks.tolist()
+            cnt = []
             for s, t in zip(sl, tl):
-                self._slots[s].tokens.append(t)
+                tokens = self._slots[s].tokens
+                tokens.append(t)
+                cnt.append(len(tokens))
+            fin_eos = (self._eos[slots] >= 0) & (toks == self._eos[slots])
+            fin_max = np.asarray(cnt) >= self._maxnew[slots]
             if h.ttr:
                 # one decode_step span per committed row, closed at the
                 # commit fence (h.ttr anchored at dispatch). The
@@ -2127,9 +2297,7 @@ class ContinuousBatchingEngine:
         alloc = cache.allocator
         # occupancy reports the rows the DISPATCHED program computed
         # (mask), matching the synchronous path; the return counts only
-        # rows that passed the seat guard and actually committed —
-        # identical in sync mode (nothing re-seats between dispatch and
-        # commit there), honest under overlap preemption races
+        # rows that passed the seat guard and actually committed
         _obs.serving_step(rows, self.max_batch,
                           alloc.num_used, alloc.num_usable)
         if self._dp_axis is not None:
@@ -2141,17 +2309,19 @@ class ContinuousBatchingEngine:
         self._tp_observe()
         return int(slots.size)
 
-    def commit_inflight(self) -> int:
-        """Commit everything in flight, in dispatch order: prefill
-        chunks first (they were dispatched first — the decode program
-        chained behind them on device), then the decode/verify step;
-        finally fence any pending async swap-out DMAs into the host
-        store (ISSUE 12 satellite a). Returns the number of committed
-        units (prompt tokens + decode slots / verify tokens)."""
-        n = self.commit_prefills()
-        h, self._inflight = self._inflight, None
-        if h is not None:
-            n += (self._decode_commit(h) if h.kind == "decode"
+    def commit_inflight(self, upto: Optional[int] = None) -> int:
+        """Commit what is in flight, in launch order: everything, or
+        with ``upto`` what was launched no later than that
+        ``launch_seq`` (the pipelined scheduler leaves its newest step
+        running); finally fence any pending async swap-out DMAs into
+        the host store. Returns the number of committed units (prompt
+        tokens + decode slots / verify tokens)."""
+        n = 0
+        flight = self._inflight
+        while flight and (upto is None or flight[0][0] <= upto):
+            _, h = flight.pop(0)
+            n += (self._commit_chunk(h) if isinstance(h, dict)
+                  else self._decode_commit(h) if h.kind == "decode"
                   else self._tree_commit(h) if h.kind == "tree"
                   else self._spec_commit(h))
         fence = getattr(self.cache, "fence_swaps", None)
@@ -2165,9 +2335,11 @@ class ContinuousBatchingEngine:
         single jitted ragged decode program (callers pass
         :meth:`ready_mask` or a scheduler's budgeted subset of it).
         Returns the number of slots advanced (0 skips the program
-        entirely). Synchronous composition of :meth:`decode_dispatch`
-        + :meth:`commit_inflight` — the bit-identity reference the
-        overlapped scheduler is gated against."""
+        entirely). Synchronous composition — whatever is in flight
+        commits first, then :meth:`decode_dispatch` +
+        :meth:`commit_inflight`: the bit-identity reference the
+        pipelined scheduler is gated against."""
+        self.commit_inflight()
         if self.decode_dispatch(mask) is None:
             return 0
         return self.commit_inflight()
@@ -2390,36 +2562,10 @@ class ContinuousBatchingEngine:
         sequential writes at ``lengths`` overwrite them before the mask
         ever reaches them — no device copy, no page churn (the
         allocator never sees a verify)."""
+        self.commit_inflight()
         if self.spec_dispatch(mask, drafts) is None:
             return 0
         return self.commit_inflight()
-
-    def spec_plan_widths(self, mask) -> Dict[int, int]:
-        """Pessimistic per-row verify widths for budget planning when
-        drafts cannot be proposed yet: the OVERLAPPED scheduler plans
-        step N+1 before step N commits, so the history the n-gram
-        proposer needs is not final. Charging ``min(spec_k, room)``
-        per ready row keeps the token budget a hard ceiling (executed
-        drafts are trimmed to the planned allowance at dispatch);
-        rows with no token room are absent, exactly as in
-        :meth:`propose_drafts`. Tree speculation (ISSUE 20) charges
-        tree NODES — the verify program's width is the whole tree, so
-        the pessimistic width is ``width x depth`` (the planner's trim
-        then drops leaves first; the root path survives, so the token
-        ceiling stays hard)."""
-        if self.spec is None:
-            return {}
-        mask = np.asarray(mask, bool)
-        nodes = (self._tree_T - 1 if self.spec_tree is not None
-                 else self.spec_k)
-        out: Dict[int, int] = {}
-        for slot, req in enumerate(self._slots):
-            if req is None or not mask[slot]:
-                continue
-            room = req.max_new_tokens - len(req.tokens) - 1
-            if room > 0:
-                out[slot] = min(nodes, room)
-        return out
 
     def spec_dispatch(self, mask,
                       drafts: Optional[Dict] = None
@@ -2434,10 +2580,13 @@ class ContinuousBatchingEngine:
         mask = np.asarray(mask, bool)
         if not mask.any():
             return None
-        if self._inflight is not None:
-            raise RuntimeError(
-                "spec_dispatch: a decode/verify program is already "
-                "in flight — commit_inflight() first")
+        if self._inflight:
+            # depth 0: the proposals were drawn from the committed
+            # history, and so must the verify's last tokens be
+            self.fence()
+            mask = mask & self.ready_mask()
+            if not mask.any():
+                return None
         if drafts is None:
             drafts = self.propose_drafts(mask)
         if self.spec_tree is not None:
@@ -2472,18 +2621,20 @@ class ContinuousBatchingEngine:
         t0 = _obs.generate_begin()
         with self.spans.span("engine.dispatch", kind="spec"):
             args = [self.params, jnp.asarray(chunk), cache.pool,
-                    jnp.asarray(cache.block_tables),
-                    jnp.asarray(cache.lengths), jnp.asarray(mask)]
+                    jnp.asarray(cache.block_tables.copy()),
+                    jnp.asarray(cache.lengths.copy()), jnp.asarray(mask)]
             if self.adapters is not None:
-                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+                args += [self.adapters.arrays,
+                         jnp.asarray(self._aslot.copy())]
             out, cache.pool = self._launch(
                 self._spec_fn(ctx_cap, T), args, "spec", ctx_cap, T)
             _fault_point("dispatch")
-            self._inflight = InFlightStep(
+            self.spans.count("decode_launches_total", 1)
+            h = self._launched_handle(InFlightStep(
                 "spec", mask, self._rids.copy(), self._seat.copy(), out,
                 drafts=drafts, dlen=dlen, t0=t0,
-                ttr=_obs.serving_trace_now(), qs=qs)
-        return self._inflight
+                ttr=_obs.serving_trace_now(), qs=qs))
+        return h
 
     def _spec_commit(self, h: InFlightStep) -> int:
         """COMMIT half of :meth:`spec_step`: fetch the greedy targets,
@@ -2624,19 +2775,21 @@ class ContinuousBatchingEngine:
         t0 = _obs.generate_begin()
         with self.spans.span("engine.dispatch", kind="tree"):
             args = [self.params, jnp.asarray(chunk), cache.pool,
-                    jnp.asarray(cache.block_tables),
-                    jnp.asarray(cache.lengths), jnp.asarray(mask),
+                    jnp.asarray(cache.block_tables.copy()),
+                    jnp.asarray(cache.lengths.copy()), jnp.asarray(mask),
                     jnp.asarray(depths), jnp.asarray(anc)]
             if self.adapters is not None:
-                args += [self.adapters.arrays, jnp.asarray(self._aslot)]
+                args += [self.adapters.arrays,
+                         jnp.asarray(self._aslot.copy())]
             out, rows = self._launch(self._tree_fn(ctx_cap, T), args,
                                      "tree", ctx_cap, T)
             _fault_point("dispatch")
-            self._inflight = InFlightStep(
+            self.spans.count("decode_launches_total", 1)
+            h = self._launched_handle(InFlightStep(
                 "tree", mask, self._rids.copy(), self._seat.copy(), out,
                 drafts=trees, t0=t0, ttr=_obs.serving_trace_now(),
-                rows=rows)
-        return self._inflight
+                rows=rows))
+        return h
 
     def _tree_commit(self, h: InFlightStep) -> int:
         """COMMIT half of the tree step: fetch the per-node targets,

@@ -226,11 +226,11 @@ class ServingCluster:
         self.wal_dir = wal_dir
         self._recovering = bool(_recover)
         if overlap is not None:
-            # async overlapped runtime (ISSUE 12): every supervised
-            # replica's scheduler runs the double-buffered pipeline —
-            # threaded through scheduler_kw so supervisor rebuilds
-            # (failover, retirement replacements) keep the mode. None
-            # defers to the factory's engines (their overlap knob).
+            # every supervised replica's scheduler takes this
+            # ``overlap`` (False: the synchronous chain; True, like
+            # the default None: the decode pipeline) — threaded
+            # through scheduler_kw so supervisor rebuilds (failover,
+            # retirement replacements) keep the mode.
             kw = dict(self._sup_kw.get("scheduler_kw") or {})
             kw["overlap"] = bool(overlap)
             self._sup_kw["scheduler_kw"] = kw
@@ -687,10 +687,7 @@ class ServingCluster:
             if sup.health == "dead" or sup._draining:
                 continue
             eng = sup.engine
-            for req in list(eng.running_requests()):
-                if (req.done or not req.tokens
-                        or req.slot in eng._pending):
-                    continue
+            for req in eng.handoff_candidates():
                 try:
                     self._handoff_one(sup, req, decode)
                 except EngineDead:

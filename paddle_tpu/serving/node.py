@@ -325,11 +325,8 @@ class ReplicaNode:
     def rpc_handoff_ready(self, data, blobs):
         """Rids whose prefill completed and whose slot is not
         mid-chunk — the prefill side of the harvest scan."""
-        eng = self.sup.engine
-        rids = [int(r.rid) for r in eng.running_requests()
-                if not r.done and r.tokens
-                and r.slot not in eng._pending
-                and r.rid in self._reqs]
+        rids = [int(r.rid) for r in self.sup.engine.handoff_candidates()
+                if r.rid in self._reqs]
         return {"rids": rids}
 
     def rpc_export_prefilled(self, data, blobs):
@@ -345,6 +342,9 @@ class ReplicaNode:
         out["length"] = int(payload["length"])
         out["last"] = int(payload["last"])
         out["tokens"] = [int(t) for t in req.tokens]
+        # the controller takes this list whole: tokens the export's
+        # fence read just now must not come again as a step's delta
+        self._cursor[req.rid] = len(req.tokens)
         kv_data, oblobs = entry_to_wire(payload["kv"])
         out["kv"] = kv_data
         return out, oblobs
@@ -404,7 +404,7 @@ class ReplicaNode:
     def rpc_drain(self, data, blobs):
         """Retirement: checkpoint to ``path`` and hand back the live
         session records for the controller to rehome. Drain FIRST —
-        it commits any in-flight overlapped step and syncs the
+        it commits the decode pipeline's steps in flight and syncs the
         journal, so the records carry every token the device already
         produced."""
         summary = self.sup.drain(data["path"])

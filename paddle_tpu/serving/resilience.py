@@ -87,8 +87,8 @@ from .policy import FinishReason, Priority
 #: "dispatch" fires AFTER a decode/verify program launches (the
 #: in-flight handle is lost with the fault — nothing committed, the
 #: journal replays); "commit" fires at the top of the commit half,
-#: before the device→host fetch — the two seams the overlapped
-#: runtime (ISSUE 12) opens between launch and host-state commit
+#: before the device→host fetch — the two seams the decode pipeline
+#: opens between a launch and the commit of what hangs on its tokens
 ENGINE_SITES = ("alloc", "free", "decode_step", "prefill_chunk",
                 "verify_step", "transfer", "sched_tick", "swap_out",
                 "swap_in", "dispatch", "commit",
@@ -1050,11 +1050,11 @@ class EngineSupervisor:
         old._pending = {}
         old._queue = []
         # drop dispatched-but-uncommitted work with the poisoned engine
-        # (ISSUE 12): the journal holds the last COMMITTED state, so
-        # the lost in-flight result is recomputed by the replay —
-        # token-identically (the fault-between-dispatch-and-commit gate)
-        old._inflight = None
-        old._inflight_chunks = []
+        # (up to two decode steps of the pipeline): the journal holds
+        # the last COMMITTED state, so the lost in-flight results are
+        # recomputed by the replay — token-identically (the
+        # fault-between-dispatch-and-commit gate)
+        old.drop_inflight()
 
     def _snapshot_key(self):
         import jax
@@ -1503,10 +1503,10 @@ class EngineSupervisor:
         (gated in tests/test_wal.py)."""
         self._check_alive()
         t0 = _obs.generate_begin()
-        # the overlapped runtime (ISSUE 12) may hold a dispatched-but-
-        # uncommitted step: commit it so sessions checkpoint with every
-        # token the device already produced (no-op when synchronous)
-        self.engine.commit_inflight()
+        # the decode pipeline holds up to two dispatched-but-
+        # uncommitted steps: commit them so sessions checkpoint with
+        # every token the device already produced
+        self.engine.fence()
         self._sync_journal(force=True)
         self._snapshot_key()
         now = self.clock()

@@ -57,6 +57,12 @@ from .policy import (FinishReason, PreemptionPolicy, Priority, StepPlan,
 from .resilience import DEGRADED_MODES, fault_point
 
 
+#: the spans of a step that run under a program in flight when the
+#: step is pipelined (host_overhead_fraction leaves them out then)
+_HIDDEN_SPANS = ("sched.admit", "sched.plan", "engine.dispatch",
+                 "engine.commit")
+
+
 class ServingScheduler:
     """Request-lifecycle scheduler between callers and a
     :class:`~paddle_tpu.inference.ContinuousBatchingEngine`.
@@ -113,16 +119,20 @@ class ServingScheduler:
         # rung was only observable through the metrics registry, which
         # a router cannot read when metrics are disabled
         self.degraded_level = 0
-        # --- async overlapped runtime (ISSUE 12): overlap=True turns
-        # step() into the double-buffered pipeline — expire/admit/plan
-        # step N+1 WHILE step N's decode/verify program runs on device,
-        # commit step N (the single host fetch + bookkeeping) only when
-        # its result is needed (just before step N+1's dispatch), then
-        # dispatch N+1 and return with it in flight. None inherits the
-        # engine's own knob; False is the synchronous bit-identity
-        # reference the overlapped path is gated against.
-        self.overlap = bool(getattr(engine, "overlap", False)
-                            if overlap is None else overlap)
+        # --- the decode pipeline: unless ``overlap`` is False, step()
+        # launches decode step k+1 BEHIND step k, before k's tokens are
+        # read (the device holds them and feeds them on), and reads
+        # step k-1 meanwhile: the host's planning, launching and
+        # bookkeeping leave the device's critical path. Where the
+        # engine cannot run ahead (engine.pipeline_depth() == 0:
+        # speculation, grammar constraints) the same loop commits
+        # before it plans. ``overlap=False`` is the synchronous chain,
+        # each program committed in place: the token-identity
+        # reference the pipelined path is gated against.
+        self.overlap = overlap is None or bool(overlap)
+        # launch_seq of the last program launched BEFORE the newest
+        # step: what the next step() reads and commits first
+        self._mark = 0
         # deadline fast path: _expire_deadlines scans every queue each
         # step — pointless host work when no live request ever carried
         # a deadline (the common case); one counter skips it
@@ -281,6 +291,12 @@ class ServingScheduler:
             swappable=getattr(self.engine, "swap_candidate", None))
         if victim is None:
             return False
+        if self.engine.has_inflight():
+            # there is somebody to evict: read the tokens in flight
+            # first (a slot may free by itself in there) and let the
+            # caller try again against the committed state
+            self.engine.fence()
+            return True
         self.engine.preempt_request(victim)
         self.preemptions_total += 1
         victim.enqueued_at = self.clock()   # queue wait restarts here
@@ -397,21 +413,12 @@ class ServingScheduler:
         # width (1 + drafts) is charged against the budget before
         # anything executes; the proposals are stashed for this step's
         # execution (the engine must not re-propose under a different
-        # history). The OVERLAPPED pipeline plans before the previous
-        # step commits — the history the proposer needs is not final —
-        # so it charges the pessimistic per-row width instead
-        # (spec_plan_widths) and proposes real drafts post-commit,
-        # trimmed to the planned allowance (the budget stays a hard
-        # ceiling either way).
-        if getattr(eng, "spec", None) is None:
-            self._drafts = {}
-            widths = None
-        elif self.overlap:
-            self._drafts = None
-            widths = eng.spec_plan_widths(ready) or None
-        else:
-            self._drafts = eng.propose_drafts(ready)
-            widths = {s: d.size for s, d in self._drafts.items()} or None
+        # history). Such an engine runs at pipeline depth 0: step()
+        # has committed everything before it plans, so the history the
+        # proposer reads is final on both paths.
+        self._drafts = (eng.propose_drafts(ready)
+                        if getattr(eng, "spec", None) is not None else {})
+        widths = {s: d.size for s, d in self._drafts.items()} or None
         # 2-D serving mesh (ISSUE 17): slots split into contiguous
         # per-dp-shard row blocks, and the step's wall time is the max
         # over shards — tell the planner which block each slot rides
@@ -424,50 +431,32 @@ class ServingScheduler:
             decode, pending, chunk_cap=eng.prefill_chunk,
             spec_drafts=widths, reserved_tokens=reserved, dp_group=dpg)
 
-    def _trim_plan(self, plan: StepPlan) -> StepPlan:
-        """Reconcile an overlap-mode plan with the commit that just
-        landed: the plan was drawn against the PREDICTED post-commit
-        state, so slots whose request finished (eos at commit), was
-        preempted, or whose prefill completed are dropped. Trimming
-        only ever REMOVES work, so the budget ceiling the plan was
-        packed under still holds; per-request output is unaffected
-        (greedy decode is batch-composition independent — the standing
-        parity gates)."""
-        eng = self.engine
+    def _decode_args(self, plan: StepPlan) -> tuple:
+        """The plan's decode rows as the engine takes them: the mask,
+        and behind it, on a speculating step, the proposals trimmed to
+        the planner's per-row draft allowance (a row the budget
+        degraded to plain decode rides the verify batch with zero
+        drafts — it commits exactly its greedy token)."""
+        mask = np.zeros((self.engine.max_batch,), bool)
+        mask[plan.decode_slots] = True
+        if not plan.spec_drafts:
+            return (mask,)
+        return mask, {s: self._drafts[s][:k]
+                      for s, k in plan.spec_drafts.items()}
 
-        def alive(s):
-            req = eng._slots[s]
-            return (req is not None and not req.done
-                    and s not in eng._pending)
-        plan.decode_slots = [s for s in plan.decode_slots if alive(s)]
-        if plan.spec_drafts:
-            keep = set(plan.decode_slots)
-            plan.spec_drafts = {s: k for s, k in plan.spec_drafts.items()
-                                if s in keep}
-        plan.prefills = [(s, c) for s, c in plan.prefills
-                         if s in eng._pending]
-        return plan
-
-    def _dispatch_plan(self, plan: StepPlan) -> None:
+    def _dispatch_plan(self, plan: StepPlan) -> bool:
         """Launch the plan's programs WITHOUT committing: prefill
         chunks first (the decode program chains behind them on
-        device), then the masked decode/verify step. Speculative rows
-        propose their REAL drafts here — post-commit, so the history
-        is final — trimmed to the planner's per-row allowance."""
+        device), then the masked decode/verify step. True where
+        something was launched."""
         eng = self.engine
+        seq = eng.launch_seq
         for slot, cap in plan.prefills:
             eng.prefill_dispatch(slot, max_tokens=cap)
-        if not plan.decode_slots:
-            return
-        mask = np.zeros((eng.max_batch,), bool)
-        mask[plan.decode_slots] = True
-        if plan.spec_drafts and getattr(eng, "spec", None) is not None:
-            fresh = eng.propose_drafts(mask)
-            eng.spec_dispatch(mask, {
-                s: fresh[s][:k] for s, k in plan.spec_drafts.items()
-                if s in fresh})
-        else:
-            eng.decode_dispatch(mask)
+        if plan.decode_slots:
+            (eng.spec_dispatch if plan.spec_drafts
+             else eng.decode_dispatch)(*self._decode_args(plan))
+        return eng.launch_seq != seq
 
     def _execute_plan(self, plan: StepPlan) -> int:
         """The synchronous reference execution: each program dispatches
@@ -479,34 +468,44 @@ class ServingScheduler:
             eng.prefill_step(slot, max_tokens=cap)
             n += 1
         if plan.decode_slots:
-            mask = np.zeros((eng.max_batch,), bool)
-            mask[plan.decode_slots] = True
-            if plan.spec_drafts:
-                # execute the budgeted verify: proposals trimmed to the
-                # planner's per-row draft allowance (a row the budget
-                # degraded to plain decode rides the verify batch with
-                # zero drafts — it commits exactly its greedy token)
-                n += eng.spec_step(mask, {
-                    s: self._drafts[s][:k]
-                    for s, k in plan.spec_drafts.items()})
-            else:
-                n += eng.decode_step(mask)
+            n += (eng.spec_step if plan.spec_drafts
+                  else eng.decode_step)(*self._decode_args(plan))
         return n
 
     def step(self) -> bool:
         """One scheduler step: expire deadlines, admit (preempting if
-        needed), plan under the token budget, then execute. With
-        ``overlap=False`` execution is the synchronous chain (prefill
-        chunks, then the masked decode program, each committed in
-        place). With ``overlap=True`` the step is DOUBLE-BUFFERED: the
-        host phases above run while the PREVIOUS step's programs are
-        still in flight on device; that step commits only once its
-        result is actually needed (just before this step's dispatch),
-        the plan is trimmed against what the commit changed, and this
-        step's programs dispatch and are left in flight. Returns False
-        when no work remains (the overlapped path drains its last
-        in-flight step before saying so). ``last_plan`` holds the
-        step's :class:`~paddle_tpu.serving.policy.StepPlan`."""
+        needed), plan under the token budget, then execute.
+
+        With ``overlap=False`` execution is the synchronous chain
+        (prefill chunks, then the masked decode program, each committed
+        in place): a token is on its handle when the call that computed
+        it returns.
+
+        Otherwise the step is PIPELINED one decode step deep. With
+        steps k-1 and k on the device, the call (1) waits for and
+        commits step k-1 — its tokens are on the request handles from
+        here on, in this call, before anything else is done; (2)
+        admits and plans against the PREDICTED state: lengths, token
+        counts, ``max_len`` finishes and chunk cursors as they stand
+        once everything launched has run, none of which needs a
+        token's value; (3) launches step k+1 behind k — a row's input
+        token is k's output on the device — and returns with k and k+1
+        in flight. So a token is visible at the start of the SECOND
+        call after the one that launched it (about when the device
+        finishes it: the loop runs at the device's pace), a row whose
+        token turns out to be ``eos`` has one more row computed and
+        dropped, and a slot it frees is refilled one step later than
+        on the synchronous chain. Served tokens are the same. Where
+        the engine cannot run ahead (``engine.pipeline_depth() == 0``:
+        speculation, grammar constraints) or a seated request is
+        preempted, swapped out or cancelled, everything in flight is
+        committed first (``pipeline_fences_total``); a call that
+        launches nothing commits everything too, so a caller that
+        steps until its handles are done never spins.
+
+        Returns False when no work remains — nothing queued, seated or
+        in flight. ``last_plan`` holds the step's
+        :class:`~paddle_tpu.serving.policy.StepPlan`."""
         fault_point("sched_tick")
         eng = self.engine
         if eng.queued_requests():
@@ -519,16 +518,21 @@ class ServingScheduler:
                 "the scheduler attached — submit through "
                 "ServingScheduler.submit so priority admission is "
                 "not bypassed")
-        # host work done while a previous step is in flight on device
-        # is HIDDEN (off the critical path); the same work with the
-        # device idle is EXPOSED — the host_overhead_fraction gauge's
-        # numerator. The synchronous path never overlaps, so all its
-        # host time is exposed by construction.
-        hidden = self.overlap and eng.has_inflight()
         sp = self.spans
         step0, wait0 = sp.ns("sched.step"), sp.ns("engine.wait")
-        plan0 = sp.ns("sched.admit") + sp.ns("sched.plan")
+        busy0 = sum(sp.ns(n) for n in _HIDDEN_SPANS)
         with sp.span("sched.step", step=self._steps):
+            committed = 0
+            if self.overlap:
+                committed = eng.commit_inflight(upto=self._mark)
+                if not eng.pipeline_depth():
+                    committed += eng.fence()
+            # host work done while a step is in flight on the device is
+            # HIDDEN (off the critical path); the same work with the
+            # device idle is EXPOSED — the host_overhead_fraction
+            # gauge's numerator. The synchronous path never overlaps,
+            # so all its host time is exposed by construction.
+            hidden = eng.has_inflight()
             now = self.clock()
             with sp.span("sched.admit", queued=sum(
                     len(q) for q in self._queues.values())):
@@ -553,24 +557,23 @@ class ServingScheduler:
                             else self._swap_debt)
                 self._swap_debt -= reserved
                 plan = self._plan(reserved)
-            planned_ns = sp.ns("sched.admit") + sp.ns("sched.plan") - plan0
             if self.overlap:
-                # the ONE commit fence: step N's result is needed now —
-                # its sampled tokens seed step N+1's dispatch inputs
-                committed = eng.commit_inflight()
-                with sp.span("sched.plan"):
-                    plan = self._trim_plan(plan)
-                self._dispatch_plan(plan)
+                # what is in flight now is step k: the next call reads
+                # it only after it has launched its own
+                self._mark = eng.launch_seq
+                if not self._dispatch_plan(plan):
+                    committed += eng.commit_inflight()
             else:
                 committed = self._execute_plan(plan)
             self.last_plan = plan
             self.last_committed = committed
             self._steps += 1
-        # the step less device-wait less hidden planning, from the span
-        # totals: no second set of stamps
+        # the step less device-wait less what ran under a program in
+        # flight, from the span totals: no second set of stamps
         wall = max(1, sp.ns("sched.step") - step0)
         exposed = max(0, wall - (sp.ns("engine.wait") - wait0)
-                      - (planned_ns if hidden else 0))
+                      - (sum(sp.ns(n) for n in _HIDDEN_SPANS) - busy0
+                         if hidden else 0))
         frac = min(1.0, exposed / wall)
         self.last_host_frac = frac
         self.host_frac_ema = (frac if self.host_frac_ema is None
@@ -620,10 +623,11 @@ class ServingScheduler:
                 self._idle_fence()
 
     def flush(self) -> int:
-        """Commit any in-flight work immediately (the overlapped
-        path's explicit fence for callers that need every committed
-        token visible NOW — e.g. before reading ``req.tokens`` between
-        steps). No-op on the synchronous path."""
+        """Commit everything in flight now — both steps of the
+        pipeline — for a caller that needs every computed token on its
+        handle before the next :meth:`step` (which would show only the
+        older step's). No-op on the synchronous path, where a token is
+        visible when the step that computed it returns."""
         return self.engine.commit_inflight()
 
     def load_stats(self) -> Dict:

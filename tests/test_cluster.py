@@ -394,8 +394,8 @@ class TestRetireReplica:
             ref2 = np.asarray(eng.generate([p2], max_new_tokens=6)[0])
             cluster = _cluster(replicas=2)
             r1 = cluster.submit(p1, max_new_tokens=6, tenant="a")
-            for _ in range(3):
-                cluster.step()          # mid-decode
+            while len(r1.tokens) < 2:
+                cluster.step()          # mid-decode, steps in flight
             assert r1.tokens and not r1.done
             idx = cluster._owner[r1.rid]
             summary = cluster.retire_replica(idx)

@@ -709,6 +709,7 @@ class TestCluster:
                 cluster.step()
             # swap a out on its owner, then blow that replica's circuit
             owner = cluster.replicas[cluster._owner[a.rid]]
+            owner.engine.fence()        # by hand, so read first by hand
             owner.engine.cache.swap_out(a.slot, a.rid)
             owner.engine._slots[a.slot] = None
             a.slot = None

@@ -359,7 +359,10 @@ class TestSchedulerLifecycle:
             params, cfg, max_batch=1, page_size=8, max_len=32,
             prefill_chunk=8, enable_prefix_cache=False)
         t = [0.0]
-        sched = ServingScheduler(eng, clock=lambda: t[0])
+        # the synchronous chain: the eviction below is timed by the
+        # tokens a has when b arrives (pipelined, the fence before the
+        # eviction would read a's last two and free the slot by itself)
+        sched = ServingScheduler(eng, clock=lambda: t[0], overlap=False)
         a = sched.submit(_prompts(cfg, [20], seed=41)[0],
                          max_new_tokens=4, priority=Priority.LOW,
                          deadline_s=1.0)    # admitted well within it
@@ -449,7 +452,10 @@ class TestSchedulerLifecycle:
             params, cfg, max_batch=4, page_size=8, max_len=16,
             enable_prefix_cache=False)
         budget = 16                          # two prefill pages
-        sched = ServingScheduler(eng, token_budget=budget)
+        # the synchronous chain: ``deferred`` below counts on the LOW
+        # rows having three tokens to go when the HIGH one arrives
+        # (pipelined, two of them are on the device already)
+        sched = ServingScheduler(eng, token_budget=budget, overlap=False)
         reqs = [sched.submit(q, max_new_tokens=4, priority=Priority.LOW)
                 for q in _prompts(cfg, [4, 5, 6], seed=10)]
         while not all(r.slot is not None and len(r.tokens) > 0

@@ -92,7 +92,7 @@ _SYNC_FREE = {
     # not a regression
     "paddle_tpu/inference/predictor.py": (
         "decode_dispatch", "spec_dispatch", "prefill_dispatch",
-        "ready_mask", "propose_drafts", "spec_plan_widths",
+        "ready_mask", "propose_drafts", "_rows_on_device",
         "_tree_dispatch"),
     # the tracing layer (ISSUE 16) runs INSIDE the hot path on every
     # span close — it must never fetch a device value or fence; its
