@@ -603,6 +603,28 @@ class ContinuousBatchingEngine:
         # MoE configs shard their expert stacks over dp with per-token
         # all-to-all dispatch (llama.validate_serving_mesh accepts what
         # validate_serving_tp rejects). Host logic is still unchanged.
+        # --- state-space layers (LlamaConfig.hybrid): the model asks for
+        # a recurrent state a row beside its pages (cfg.cache_layers());
+        # the cache keeps it by slot and the decode and chunk programs
+        # take it with the pools. What walks pages and cannot yet walk
+        # state refuses such a config here, by name (the prefix cache, the
+        # fabric's handoff, drain/restore and defrag refuse in
+        # PagedKVCache); so does what would shard or extend its programs.
+        self._state_layers = cfg.cache_layers().get("state", 0)
+        if self._state_layers:
+            for on, what in ((host_tier, "host_tier"),
+                             (draft_layers, "draft_layers"),
+                             (spec_k or spec_tree, "speculative decoding"
+                              " (spec_k / spec_tree)"),
+                             (mesh is not None, "mesh"),
+                             (adapters is not None, "adapters"),
+                             (fused, "fused"),
+                             (enable_prefix_cache, "enable_prefix_cache")):
+                if on:
+                    raise ValueError(
+                        f"ContinuousBatchingEngine: {what} is not "
+                        f"supported on a config with state-space layers "
+                        f"(a recurrent state a row beside its pages)")
         self.mesh = mesh
         self._tp = None
         self._tp_axis = None
@@ -767,9 +789,23 @@ class ContinuousBatchingEngine:
         # expert load, expert layers run] to ``_moe_acc`` ON THE DEVICE;
         # the decode program hands the sum back packed behind its
         # tokens, so the one read of the step's tokens brings them
-        self._moe_acc = (jnp.zeros((4,), jnp.int32)
+        # (a hybrid config's expert layers hold a share of the experts:
+        # the first three count what is held and computed here, and a
+        # fourth the items routed to experts held elsewhere)
+        self._moe_names = (
+            ("moe_routed_items_total", "moe_experts_hit_total",
+             "moe_max_expert_load_total")
+            + (("moe_items_elsewhere_total",) if cfg.hybrid is not None
+               else ()) + ("moe_layer_steps_total",))
+        self._moe_layers = (cfg.kind_layers("experts")
+                            if cfg.hybrid is not None else cfg.num_layers)
+        self._moe_acc = (jnp.zeros((len(self._moe_names),), jnp.int32)
                          if cfg.moe is not None else None)
         self._moe_zero = self._moe_acc
+        if self._state_layers:
+            for name in ("ssm_state_rows_total", "ssm_chunk_tokens_total",
+                         "ssm_state_rebuilds_total"):
+                self.spans.count(name, 0)
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
         # supervisor; -1 renders as the "router" lane in exports
         self.replica_id = -1
@@ -1052,7 +1088,7 @@ class ContinuousBatchingEngine:
             ad_on, cons = self.adapters is not None, self.constraints
             win = self.cache.window is not None
             moe = cfg.moe is not None
-            nlayers = cfg.num_layers
+            nlayers = self._moe_layers
 
             def fwd(params, last, paged, tables, lengths, active, *rest):
                 # rest (engine-config-static): [the sliding layers'
@@ -1140,21 +1176,25 @@ class ContinuousBatchingEngine:
             # dispatch can still all-to-all over the dp axis
             ad_on = self.adapters is not None
             win = self.cache.window is not None
+            state = bool(self._state_layers)
             moe = cfg.moe is not None
-            nlayers = cfg.num_layers
+            nlayers = self._moe_layers
 
             def fwd(params, chunk, paged, table, ctx_len, chunk_len,
                     *rest):
-                # rest: [the sliding layers' block table], then
-                # [adapter arrays, adapter slot]
+                # rest: [the sliding layers' block table], then [the
+                # row's slot in the state pool], then [adapter arrays,
+                # adapter slot]
                 rest = list(rest)
                 wt = rest.pop(0) if win else None
+                st = rest.pop(0) if state else None
                 ad, aslot = (rest if ad_on else (None, None))
                 return gen.paged_prefill_chunk(
                     params, chunk, paged, table, cfg, ctx_cap=ctx_cap,
                     ctx_len=ctx_len, chunk_len=chunk_len, tp_axis=ax,
                     dp_axis=dpx, fused=fz, use_kernel=uk, adapters=ad,
-                    adapter_slot=aslot, window_table=wt, with_stats=moe)
+                    adapter_slot=aslot, window_table=wt, with_stats=moe,
+                    state_slot=st)
             if self.mesh is not None:
                 fwd = self._tp_map(
                     fwd, ("params", "rep", "pool", "rep", "rep", "rep")
@@ -1511,6 +1551,10 @@ class ContinuousBatchingEngine:
             # generated-token replay is NOT a prompt prefix miss (it
             # would collapse the dashboarded prefix hit rate)
             _obs.serving_resumed(1, seq.size - int(shared))
+            if self._state_layers:
+                # the row's recurrent state went with its slot: the
+                # replay's chunks rebuild it from zero with the pages
+                self.spans.count("ssm_state_rebuilds_total", 1)
         else:
             # full sequence size here — the prefix hit/miss split is
             # the serving_prefix pair's job, and the chunk-token
@@ -1703,6 +1747,11 @@ class ContinuousBatchingEngine:
                 # the sliding layers' pages for the chunk's positions
                 cache.window_extend(slot, done + take)
                 args += [jnp.asarray(cache.window_tables[slot].copy())]
+            if self._state_layers:
+                # the row's recurrent state goes in and comes out with
+                # the pools; tokens through the chunked scan, a layer
+                args += [jnp.int32(slot)]
+                self.spans.count("ssm_chunk_tokens_total", take)
             if self.adapters is not None:
                 args += [self.adapters.arrays,
                          jnp.asarray(self._aslot[slot:slot + 1].copy())]
@@ -1782,7 +1831,8 @@ class ContinuousBatchingEngine:
         the first launch), on the engine's mesh where it has one."""
         if self._tok_dev is None:
             tok = jnp.zeros((self.max_batch + (
-                4 if self._moe_acc is not None else 0),), jnp.int32)
+                len(self._moe_names) if self._moe_acc is not None
+                else 0),), jnp.int32)
             if self.mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
                 tok = jax.device_put(
@@ -2196,6 +2246,10 @@ class ContinuousBatchingEngine:
             if cache.window:
                 self.spans.count("window_pages_released_total",
                                  cache.window_step(slots))
+            if self._state_layers:
+                # row-layers of recurrent state this program advances
+                self.spans.count("ssm_state_rows_total",
+                                 slots.size * self._state_layers)
             self._ntok[slots] += 1
         return h
 
@@ -2225,10 +2279,7 @@ class ContinuousBatchingEngine:
             raw = np.asarray(h.raw) if self.constraints else None
         if self._moe_acc is not None:
             nxt, moe = nxt[:self.max_batch], nxt[self.max_batch:]
-            for name, n in zip(("moe_routed_items_total",
-                                "moe_experts_hit_total",
-                                "moe_max_expert_load_total",
-                                "moe_layer_steps_total"), moe.tolist()):
+            for name, n in zip(self._moe_names, moe.tolist()):
                 self.spans.count(name, n)
         rows = int(h.mask.sum())
         with self.spans.span("engine.commit", rows=rows):
@@ -3006,6 +3057,7 @@ class ContinuousBatchingEngine:
             wa = self.cache.window_allocator
             s["window_pool_used_peak"] = wa.peak_in_use
             s["window_pool_usable"] = wa.num_usable
+        s.update(self.cache.state_stats())
         if self.adapters is not None:
             s.update(self.adapters.stats())
         if getattr(self.cache, "host", None) is not None:

@@ -97,12 +97,14 @@ KIND_SUFFIX = {"full": "", "sliding": "_w"}
 
 
 def _kind_arrays(cfg: LlamaConfig, make) -> Dict:
-    """``make(kind)`` -> that kind's arrays by their plain names; all
-    kinds of the config's period in one dict, suffixed."""
+    """``make(kind, layers)`` -> the arrays of that kind of KV cache by
+    their plain names; every kind the model asks for
+    (:meth:`LlamaConfig.cache_layers`) in one dict, suffixed."""
     out = {}
-    for kind in dict.fromkeys(cfg.period):
-        out.update({n + KIND_SUFFIX[kind]: a
-                    for n, a in make(kind).items()})
+    for kind, layers in cfg.cache_layers().items():
+        if kind in KIND_SUFFIX:
+            out.update({n + KIND_SUFFIX[kind]: a
+                        for n, a in make(kind, layers).items()})
     return out
 
 
@@ -135,8 +137,7 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
             f"scales); a silently full-precision cache would misreport "
             f"the serving configuration")
 
-    def make(kind):
-        L = cfg.kind_layers(kind)
+    def make(kind, L):
         S = window_len if kind == "sliding" and window_len else max_len
         if kv_dtype is not None:
             return {
@@ -152,9 +153,15 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
     return _kind_arrays(cfg, make)
 
 
+#: the arrays of the recurrent-state pool (``init_paged_cache``): not paged
+STATE_ARRAYS = ("ssm", "conv")
+
+
 def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
                      kv_dtype=None, tp: Optional[int] = None,
-                     window_pages: Optional[int] = None) -> Dict:
+                     window_pages: Optional[int] = None,
+                     state_slots: Optional[int] = None,
+                     state_dtype=jnp.float32) -> Dict:
     """Paged KV cache: one global pool of fixed-size token pages per
     layer — ``(L, num_pages, page_size, nkv, hd)`` — indexed by
     per-request block tables instead of a dense ``(L, B, S_max, ...)``
@@ -181,16 +188,29 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
     (module docstring): the full layers' of ``num_pages`` pages and the
     sliding layers' of ``window_pages``, the second sized by the
     allocator for a window and a chunk a row
-    (:class:`~paddle_tpu.serving.PagedKVCache`), not for ``max_len``."""
+    (:class:`~paddle_tpu.serving.PagedKVCache`), not for ``max_len``.
+
+    A config with state-space layers gets a THIRD kind of cache beside
+    them, addressed by slot and not by page: ``ssm`` ``(state layers,
+    state_slots, head_dim, state, heads)`` the recurrence's state in
+    ``state_dtype`` (heads last: ``ops/pallas/ssm.py`` has the reason) and
+    ``conv`` ``(state layers, state_slots, conv_kernel - 1, conv_dim)``
+    the causal convolution's last columns in the model's dtype. The KV
+    pools hold layers only for the kinds that attend."""
     nkv, hd = cfg.num_kv_heads, cfg.hd
-    if "sliding" in cfg.period and window_pages is None:
+    needs = cfg.cache_layers()
+    if "sliding" in needs and window_pages is None:
         raise ValueError(
             "init_paged_cache: the config has sliding layers; "
             "window_pages must size their pool")
-    if "full" not in cfg.period:
+    if "full" not in needs:
         raise ValueError(
             "init_paged_cache: a period without a full layer is not "
             "served (block tables and admission follow the full pool)")
+    if "state" in needs and state_slots is None:
+        raise ValueError(
+            "init_paged_cache: the config has state-space layers; "
+            "state_slots must size their pool")
     if tp is not None:
         # validate_serving_mesh rather than validate_serving_tp: the
         # head contract is identical and MoE configs are legal on the
@@ -201,8 +221,7 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
             f"init_paged_cache: kv_dtype={kv_dtype!r} is not supported — "
             f"pass None (model dtype) or 'int8'")
 
-    def make(kind):
-        L = cfg.kind_layers(kind)
+    def make(kind, L):
         P = window_pages if kind == "sliding" else num_pages
         if kv_dtype is not None:
             return {
@@ -215,7 +234,14 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
             "k": jnp.zeros((L, P, page_size, nkv, hd), cfg.dtype),
             "v": jnp.zeros((L, P, page_size, nkv, hd), cfg.dtype),
         }
-    return _kind_arrays(cfg, make)
+    out = _kind_arrays(cfg, make)
+    if "state" in needs:
+        hy, L = cfg.hybrid, needs["state"]
+        out["ssm"] = jnp.zeros((L, state_slots, hy.ssm_head_dim,
+                                hy.ssm_state, hy.ssm_heads), state_dtype)
+        out["conv"] = jnp.zeros((L, state_slots, hy.conv_kernel - 1,
+                                 hy.conv_dim), cfg.dtype)
+    return out
 
 
 def _take_pages(pool, table):
@@ -258,7 +284,7 @@ EXPERT_STACKS = ("moe_wg", "moe_wu", "moe_wd")
 
 
 def _expert_apply(x_rows, item_row, le, stacks, layer, tp_axis=None,
-                  use_kernel=None):
+                  use_kernel=None, absent=False):
     """Expert SwiGLU over routed items, grouped by expert.
 
     ``x_rows`` (R, H) token rows, ``item_row`` (n,) the row each item
@@ -279,23 +305,39 @@ def _expert_apply(x_rows, item_row, le, stacks, layer, tp_axis=None,
     The outputs go back to item order. Under tp the stacks arrive
     column-sharded like the dense ``wg``/``wu``/``wd`` and the
     activations all-gather to full width before each contraction (the
-    ISSUE 7 exact-concat argument)."""
+    ISSUE 7 exact-concat argument).
+
+    Two stacks in place of three are the two-matrix expert
+    ``relu(x W1)^2 W2``. ``absent``: some items' experts are not held
+    here; their ``le`` is ``E_l``, past every group, so that they sort
+    behind the held items, count in no group, are multiplied by nothing
+    and come back as zeros."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
     n, dt = le.shape[0], x_rows.dtype
-    wg, wu, wd = stacks
-    sizes = jnp.zeros((wg.shape[1],), jnp.int32).at[le].add(1)
+    El = stacks[0].shape[1]
+    sizes = jnp.zeros((El,), jnp.int32).at[le].add(1)
     order = jnp.argsort(le)                     # stable: token order kept
     xs = jnp.take(x_rows, jnp.take(item_row, order), axis=0)
     mm = partial(grouped_matmul, sizes=sizes, layer=layer,
                  use_kernel=use_kernel)
-    g = jax.nn.silu(mm(xs, wg).astype(jnp.float32)).astype(dt)
-    u = mm(xs, wu)
-    gu = g * u
+    if len(stacks) == 2:
+        # the plain two-matrix expert, relu squared between
+        r = jnp.maximum(mm(xs, stacks[0]).astype(jnp.float32), 0.0)
+        gu, wd = (r * r).astype(dt), stacks[1]
+    else:
+        wg, wu, wd = stacks
+        g = jax.nn.silu(mm(xs, wg).astype(jnp.float32)).astype(dt)
+        u = mm(xs, wu)
+        gu = g * u
     if tp_axis is not None:
         gu = _tp_allgather(gu, tp_axis, 1)
     o = mm(gu, wd)
     if tp_axis is not None:
         o = _tp_allgather(o, tp_axis, 1)
+    if absent:
+        # rows past the last group belong to no expert held here: no
+        # grouped matmul wrote them
+        o = jnp.where((jnp.take(le, order) < El)[:, None], o, 0)
     inv = jnp.zeros((n,), jnp.int32).at[order].set(
         jnp.arange(n, dtype=jnp.int32), unique_indices=True)
     return jnp.take(o, inv, axis=0)
@@ -394,6 +436,68 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
     return y.astype(x.dtype).reshape(B, T, H), stats
 
 
+def _latent_moe_ffn(x, lp, cfg: LlamaConfig, experts, layer, valid=None,
+                    use_kernel=None):
+    """The hybrid model's expert layer (LatentMoE) with a SHARE of the
+    routed experts held here; the whole feed-forward part of one layer.
+
+    x: (B, T, H). ``lp`` carries the layer's ``router`` (H, E) and
+    ``router_bias`` (E,) in float32 over ALL E experts, the latent
+    projections ``w_down`` (H, l) / ``w_up`` (l, H), the shared expert
+    ``ws1`` / ``ws2`` at full width, and ``first_expert``; ``experts`` are
+    the two stacks ``(L, E_l, l, i)`` / ``(L, E_l, i, l)`` of the E_l
+    experts ``first_expert .. first_expert + E_l`` of every layer, read in
+    place by :func:`_expert_apply`.
+
+    Routing, in float32: ``s = sigmoid(x W_r)``; the ``top_k`` largest of
+    ``s + bias`` are chosen (the bias selects, it does not weigh); the
+    weights are ``routed_scale * s_chosen / sum(s_chosen)``. Every token
+    is routed over all E experts, as on every chip of the deployment; the
+    items whose expert is not held here are dropped before the sort and
+    contribute zero: the other chips' shares would add theirs through the
+    same ``w_up``, which is linear and has no bias. No ``dp`` exchange,
+    and nothing stands in for the absent chips. The shared expert is
+    every chip's own and is added once.
+
+    Returns ``(y, stats)``; ``stats`` is int32 ``[items computed here,
+    held experts hit, largest load of a held expert, items routed to
+    experts held elsewhere]`` over the rows ``valid`` marks."""
+    B, T, H = x.shape
+    k, dt = cfg.moe.top_k, x.dtype
+    El = experts[0].shape[1]
+    N = B * T
+    xf = x.reshape(N, H)
+    # float32 in fact: a TPU multiplies float32 operands as bfloat16 unless
+    # told otherwise, and a chosen expert's weight here does not fall off
+    # towards the k-th (sigmoid scores saturate), so an expert that flips
+    # in or out at the boundary moves the layer's output like any other
+    s = jax.nn.sigmoid(jnp.matmul(
+        xf.astype(jnp.float32), lp["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))                       # (N, E)
+    _, idx = lax.top_k(s + lp["router_bias"].astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)               # (N, k)
+    w = cfg.hybrid.routed_scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+    n = N * k
+    le = idx.reshape(-1).astype(jnp.int32) - lp["first_expert"]
+    held = (le >= 0) & (le < El)
+    le = jnp.where(held, le, El)
+    item_row = jnp.arange(n, dtype=jnp.int32) // k
+    live = (jnp.ones((n,), jnp.int32) if valid is None else
+            jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
+    load = jnp.zeros((El,), jnp.int32).at[le].add(live)
+    here = jnp.sum(live * held)
+    stats = jnp.stack([here, jnp.sum(load > 0), jnp.max(load),
+                       jnp.sum(live) - here])
+    lat = xf @ _w(lp, "w_down", dt)                             # (N, l)
+    items = _expert_apply(lat, item_row, le, experts, layer,
+                          use_kernel=use_kernel, absent=True)
+    r = jnp.sum(items.reshape(N, k, -1).astype(jnp.float32)
+                * w[:, :, None], axis=1).astype(dt)
+    hs = jnp.maximum((xf @ _w(lp, "ws1", dt)).astype(jnp.float32), 0.0)
+    y = r @ _w(lp, "w_up", dt) + (hs * hs).astype(dt) @ _w(lp, "ws2", dt)
+    return y.reshape(B, T, H), stats
+
+
 def _runs(period):
     """A period as its runs of one layer kind: ``[(kind, first layer of the
     run, layers in it), ...]``."""
@@ -441,6 +545,11 @@ def _split_experts(layers: Dict):
 
 def _refuse_sliding(cfg: LlamaConfig, what: str):
     """The programs that know one pool and one block table a row."""
+    if cfg.hybrid is not None:
+        raise ValueError(
+            f"{what}: the config has state-space layers (a recurrent "
+            f"state a row beside its pages); only paged_prefill_chunk and "
+            f"paged_decode_forward serve it")
     if "sliding" in cfg.period:
         raise ValueError(
             f"{what}: the config has sliding-window layers (two pools, "
@@ -523,7 +632,8 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
                         ctx_cap: int, ctx_len, chunk_len, tp_axis=None,
                         dp_axis=None, fused=None, use_kernel=None,
                         adapters=None, adapter_slot=None,
-                        window_table=None, with_stats=False):
+                        window_table=None, with_stats=False,
+                        state_slot=None):
     """Prefill ONE chunk of a request's prompt against the KV already in
     its pages — the chunked-prefill / prefix-cache continuation program
     (one compile per static ``(ctx_cap, C)`` pair; the engine buckets
@@ -593,7 +703,14 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     ``[ctx_len - wcap, ctx_len)`` gathered from the window's pages, so
     a chunk deep in a long prompt costs them what a shallow one does.
     ``with_stats``: also return the summed ``_moe_ffn`` stats of the
-    chunk's valid rows."""
+    chunk's valid rows.
+
+    ``state_slot``: the row's slot in the recurrent-state pool of a config
+    with state-space layers. The chunk starts from the slot's state and
+    convolution tail and writes both back; at ``ctx_len`` 0 it starts from
+    zeros whatever the slot holds, which is how an admission's reset of
+    its slot takes effect (``PagedKVCache``). The attention layers of such
+    a config go through the same gather and scatter as any full layer."""
     B, C = tokens.shape
     if B != 1:
         raise ValueError(
@@ -647,20 +764,32 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     kstart = pad[None]                                  # (1,)
     rpos = (ctx_len + jnp.arange(C, dtype=jnp.int32))[None, :]
     pos = jnp.arange(C, dtype=jnp.int32)
-    out = _forward_cached(params, tokens, dense, ctx_cap, cfg,
-                          W, use_kernel=use_kernel, rpos=rpos,
-                          kstart=kstart, logits_at=chunk_len - 1,
-                          tp_axis=tp_axis, dp_axis=dp_axis,
-                          fused=bool(fused), adapters=adapters,
-                          adapter_slots=adapter_slot, pos_w=wcap,
-                          kstart_w=(jnp.maximum(wcap - ctx_len, 0)[None]
-                                    if sliding else None),
-                          moe_valid=(pos < chunk_len)[None, :],
-                          with_stats=with_stats)
+    new = {}
+    if cfg.hybrid is not None:
+        from . import hybrid as _hybrid
+        _hybrid.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                       adapters=adapters)
+        logits, dense, state, stats = _hybrid.forward_chunk(
+            params, tokens, dense,
+            {n: paged[n] for n in STATE_ARRAYS}, state_slot, ctx_cap, cfg,
+            kstart=kstart, ctx_len=ctx_len, chunk_len=chunk_len,
+            use_kernel=use_kernel)
+        new.update(state)
+        out = (logits, dense) + ((stats,) if with_stats else ())
+    else:
+        out = _forward_cached(params, tokens, dense, ctx_cap, cfg,
+                              W, use_kernel=use_kernel, rpos=rpos,
+                              kstart=kstart, logits_at=chunk_len - 1,
+                              tp_axis=tp_axis, dp_axis=dp_axis,
+                              fused=bool(fused), adapters=adapters,
+                              adapter_slots=adapter_slot, pos_w=wcap,
+                              kstart_w=(jnp.maximum(wcap - ctx_len, 0)[None]
+                                        if sliding else None),
+                              moe_valid=(pos < chunk_len)[None, :],
+                              with_stats=with_stats)
     logits, dense = out[0], out[1]
     logical = jnp.clip(ctx_len + pos, 0, ext - 1)
-    new = {}
-    for kind in dict.fromkeys(cfg.period):
+    for kind in (k for k in cfg.cache_layers() if k in KIND_SUFFIX):
         table = window_table if kind == "sliding" else block_table
         at = wcap if kind == "sliding" else ctx_cap
         dst = jnp.where(pos < chunk_len,
@@ -891,6 +1020,81 @@ def make_draft_params(params, cfg: LlamaConfig, n_layers: int):
     return draft, dataclasses.replace(cfg, num_layers=n_layers)
 
 
+def _paged_kv_attend(q, k, v, held, dst, table, lengths, page, *,
+                     window=None, rope_row=None, use_kernel=None,
+                     dp_axis=None, dtype=None):
+    """The cache half of a decode layer: write the B new rows ``k`` / ``v``
+    (B, 1, nkv, hd) at flat slots ``dst`` of the pools ``held`` (``(k, v,
+    ks, vs)`` as pages ``(layers * P, page, ...)``, the scale pools None
+    off the int8 tier) and attend ``q`` (B, 1, nh, hd) through ``table``
+    over ``lengths + 1`` keys. ``rope_row``: the row's cos/sin for the
+    fused kernel, which rotates ``q`` itself; None takes the unfused call.
+    Returns ``(o (B, nh, hd), k, v, ks, vs)``."""
+    from ..ops.pallas import paged_attention as _pa
+    from ..ops.pallas import serving_fused as _sf
+    kp, vp, ksp, vsp = held
+    B, _, nh, hd = q.shape
+    quant = ksp is not None
+
+    def _pool_write(pool, rows):
+        # dp shards scatter the FULL batch's rows (gathered in
+        # shard order to match the full dst) into their pool
+        # replica — identical writes on every replica, which is
+        # what keeps the dp-replicated pools bit-identical
+        if dp_axis is not None:
+            rows = _tp_allgather(rows, dp_axis, 0)
+        if pool.ndim == 3:
+            # a scale pool as the kernel reads it (below): a scatter
+            # would re-lay it whole, so write row after row in place
+            return lax.fori_loop(
+                0, rows.shape[0], lambda r, p: lax.dynamic_update_slice(
+                    p, lax.dynamic_slice_in_dim(rows, r, 1)[None],
+                    (dst[r] // page, 0, dst[r] % page * rows.shape[1])),
+                pool)
+        return pool.reshape((-1,) + pool.shape[2:]).at[dst].set(
+            rows).reshape(pool.shape)
+
+    if quant:
+        sc = jnp.maximum(
+            jnp.max(jnp.abs(k.astype(jnp.float32)), axis=-1) / 127.0,
+            1e-8)
+        kq = jnp.clip(jnp.round(k.astype(jnp.float32)
+                                / sc[..., None]), -127, 127)
+        vc = jnp.maximum(
+            jnp.max(jnp.abs(v.astype(jnp.float32)), axis=-1) / 127.0,
+            1e-8)
+        vq = jnp.clip(jnp.round(v.astype(jnp.float32)
+                                / vc[..., None]), -127, 127)
+        kp = _pool_write(kp, kq[:, 0].astype(jnp.int8))
+        vp = _pool_write(vp, vq[:, 0].astype(jnp.int8))
+        ksp = _pool_write(ksp, sc[:, 0].astype(jnp.float32))
+        vsp = _pool_write(vsp, vc[:, 0].astype(jnp.float32))
+        ksa, vsa = (a.reshape(kp.shape[:3]) for a in (ksp, vsp))
+    else:
+        ksa = vsa = None
+        kp = _pool_write(kp, k[:, 0].astype(kp.dtype))
+        vp = _pool_write(vp, v[:, 0].astype(vp.dtype))
+    if rope_row is not None:
+        # trace-time dispatch counter + bytes-saved estimate: the
+        # rotated q's HBM write+read per layer (plus, on int8
+        # tiers, the in-VMEM dequant the unfused reference pays as
+        # an fp copy) — fires once per compile per layer, like
+        # serving_tp_allgather
+        _obs.serving_fused_dispatch(
+            "decode_rope_attn",
+            2 * B * nh * hd * jnp.dtype(dtype).itemsize)
+        o = _sf.fused_paged_decode_attention(
+            q[:, 0], *rope_row, kp, vp, table,
+            lengths + 1, ks_pages=ksa, vs_pages=vsa,
+            use_kernel=use_kernel)
+    else:
+        o = _pa.paged_attention(
+            q[:, 0], kp, vp, table, lengths + 1,
+            ks_pages=ksa, vs_pages=vsa, use_kernel=use_kernel,
+            window=window)
+    return o, kp, vp, ksp, vsp
+
+
 def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                          block_tables: jax.Array, lengths: jax.Array,
                          cfg: LlamaConfig, *, active=None,
@@ -972,8 +1176,14 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     under ``fused`` those layers take the unfused call.
     ``with_stats``: also return the ``_moe_ffn`` stats summed over
     layers, counted over the ``active`` rows."""
-    from ..ops.pallas import paged_attention as _pa
-    from ..ops.pallas import serving_fused as _sf
+    if cfg.hybrid is not None:
+        from . import hybrid as _hybrid
+        _hybrid.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                       adapters=adapters)
+        out = _hybrid.decode_forward(
+            params, tokens, paged, block_tables, lengths, cfg,
+            active=active, use_kernel=use_kernel)
+        return out if with_stats else out[:2]
     fused = bool(fused)
     B = tokens.shape[0]
     page = paged["k"].shape[2]
@@ -1050,62 +1260,10 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
             q = _rope_rows(q, cos, sin, rpos)
         k = _rope_rows(k, cos, sin, rpos)
 
-        def _pool_write(pool, rows):
-            # dp shards scatter the FULL batch's rows (gathered in
-            # shard order to match the full dst) into their pool
-            # replica — identical writes on every replica, which is
-            # what keeps the dp-replicated pools bit-identical
-            if dp_axis is not None:
-                rows = _tp_allgather(rows, dp_axis, 0)
-            if pool.ndim == 3:
-                # a scale pool as the kernel reads it (below): a scatter
-                # would re-lay it whole, so write row after row in place
-                return lax.fori_loop(
-                    0, rows.shape[0], lambda r, p: lax.dynamic_update_slice(
-                        p, lax.dynamic_slice_in_dim(rows, r, 1)[None],
-                        (dst[r] // page, 0, dst[r] % page * rows.shape[1])),
-                    pool)
-            return pool.reshape((-1,) + pool.shape[2:]).at[dst].set(
-                rows).reshape(pool.shape)
-
-        if quant:
-            sc = jnp.maximum(
-                jnp.max(jnp.abs(k.astype(jnp.float32)), axis=-1) / 127.0,
-                1e-8)
-            kq = jnp.clip(jnp.round(k.astype(jnp.float32)
-                                    / sc[..., None]), -127, 127)
-            vc = jnp.maximum(
-                jnp.max(jnp.abs(v.astype(jnp.float32)), axis=-1) / 127.0,
-                1e-8)
-            vq = jnp.clip(jnp.round(v.astype(jnp.float32)
-                                    / vc[..., None]), -127, 127)
-            kp = _pool_write(kp, kq[:, 0].astype(jnp.int8))
-            vp = _pool_write(vp, vq[:, 0].astype(jnp.int8))
-            ksp = _pool_write(ksp, sc[:, 0].astype(jnp.float32))
-            vsp = _pool_write(vsp, vc[:, 0].astype(jnp.float32))
-            ksa, vsa = (a.reshape(kp.shape[:3]) for a in (ksp, vsp))
-        else:
-            ksa = vsa = None
-            kp = _pool_write(kp, k[:, 0].astype(kp.dtype))
-            vp = _pool_write(vp, v[:, 0].astype(vp.dtype))
-        if fuse:
-            # trace-time dispatch counter + bytes-saved estimate: the
-            # rotated q's HBM write+read per layer (plus, on int8
-            # tiers, the in-VMEM dequant the unfused reference pays as
-            # an fp copy) — fires once per compile per layer, like
-            # serving_tp_allgather
-            _obs.serving_fused_dispatch(
-                "decode_rope_attn",
-                2 * B * nh * hd * jnp.dtype(cfg.dtype).itemsize)
-            o = _sf.fused_paged_decode_attention(
-                q[:, 0], *rope_row[kind], kp, vp, table,
-                lengths + 1, ks_pages=ksa, vs_pages=vsa,
-                use_kernel=use_kernel)
-        else:
-            o = _pa.paged_attention(
-                q[:, 0], kp, vp, table, lengths + 1,
-                ks_pages=ksa, vs_pages=vsa, use_kernel=use_kernel,
-                window=window)
+        o, kp, vp, ksp, vsp = _paged_kv_attend(
+            q, k, v, (kp, vp, ksp, vsp), dst, table, lengths, page,
+            window=window, rope_row=rope_row[kind] if fuse else None,
+            use_kernel=use_kernel, dp_axis=dp_axis, dtype=cfg.dtype)
         o = o.reshape(B, 1, nh * hd)
         if tp_axis is not None:
             o = _tp_allgather(o, tp_axis, 2)
@@ -1245,14 +1403,28 @@ def quantize_weights(params, cfg: LlamaConfig, bits: int = 8,
 
     q = q4 if bits == 4 else q8
     out = {k: v for k, v in params.items()}
-    layers = dict(params["layers"])
-    for name in ("wq", "wk", "wv", "wo", "wg", "wu", "wd"):
-        if name not in layers:
-            continue        # MoE trees: moe_* expert stacks stay fp
-        qw, sc = jax.vmap(q)(layers[name])
-        layers[name] = qw
-        layers[name + "_scale"] = sc
-    out["layers"] = layers
+
+    def stacks(layers, names):
+        layers = dict(layers)
+        for name in names:
+            if name not in layers:
+                continue        # MoE trees: moe_* expert stacks stay fp
+            qw, sc = jax.vmap(q)(layers[name])
+            layers[name] = qw
+            layers[name + "_scale"] = sc
+        return layers
+    if cfg.hybrid is not None:
+        # a stack per kind: the attention and the Mamba-2 projections;
+        # the expert layers stay as they are
+        out["layers"] = {
+            **params["layers"],
+            "attention": stacks(params["layers"]["attention"],
+                                ("wq", "wk", "wv", "wo")),
+            "mamba2": stacks(params["layers"]["mamba2"],
+                             ("w_in", "w_out"))}
+    else:
+        out["layers"] = stacks(params["layers"],
+                               ("wq", "wk", "wv", "wo", "wg", "wu", "wd"))
     if not cfg.tie_embeddings and "lm_head" in params:
         qw, sc = q(params["lm_head"])
         out["lm_head"] = qw
@@ -1383,6 +1555,39 @@ def _rope_rows(x, cos, sin, rpos):
                            axis=-1).astype(x.dtype)
 
 
+def _rowq(t):
+    """Per-row symmetric int8: (B,T,nkv,hd) -> (int8 rows, (B,T,nkv)
+    scales)."""
+    sc = jnp.maximum(jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1)
+                     / 127.0, 1e-8)
+    ti = jnp.clip(jnp.round(t.astype(jnp.float32) / sc[..., None]),
+                  -127, 127).astype(jnp.int8)
+    return ti, sc.astype(jnp.float32)
+
+
+def _cache_write(cache_k, cache_v, cache_ks, cache_vs, k, v, pos):
+    """Write the rows ``k`` / ``v`` (B, T, nkv, hd) of one layer into its
+    dense cache at ``pos``; with scale arrays, as int8 rows and their
+    scales. Returns the four arrays (scales None without them)."""
+    if cache_ks is not None:
+        kqr, ksc = _rowq(k)
+        vqr, vsc = _rowq(v)
+        cache_k = lax.dynamic_update_slice_in_dim(cache_k, kqr, pos,
+                                                  axis=1)
+        cache_v = lax.dynamic_update_slice_in_dim(cache_v, vqr, pos,
+                                                  axis=1)
+        cache_ks = lax.dynamic_update_slice_in_dim(cache_ks, ksc, pos,
+                                                   axis=1)
+        cache_vs = lax.dynamic_update_slice_in_dim(cache_vs, vsc, pos,
+                                                   axis=1)
+    else:
+        cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(
+            cache_k.dtype), pos, axis=1)
+        cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(
+            cache_v.dtype), pos, axis=1)
+    return cache_k, cache_v, cache_ks, cache_vs
+
+
 def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
                  use_kernel=None, rpos=None, kstart=None,
                  cache_ks=None, cache_vs=None, tp_axis=None,
@@ -1429,32 +1634,8 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
         q = _rope_rows(q, cos, sin, rpos)
         k = _rope_rows(k, cos, sin, rpos)
     quant = cache_ks is not None
-
-    def _rowq(t):
-        """Per-row symmetric int8: (B,T,nkv,hd) -> (int8 rows,
-        (B,T,nkv) scales)."""
-        sc = jnp.maximum(jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1)
-                         / 127.0, 1e-8)
-        ti = jnp.clip(jnp.round(t.astype(jnp.float32) / sc[..., None]),
-                      -127, 127).astype(jnp.int8)
-        return ti, sc.astype(jnp.float32)
-
-    if quant:
-        kqr, ksc = _rowq(k)
-        vqr, vsc = _rowq(v)
-        cache_k = lax.dynamic_update_slice_in_dim(cache_k, kqr, pos,
-                                                  axis=1)
-        cache_v = lax.dynamic_update_slice_in_dim(cache_v, vqr, pos,
-                                                  axis=1)
-        cache_ks = lax.dynamic_update_slice_in_dim(cache_ks, ksc, pos,
-                                                   axis=1)
-        cache_vs = lax.dynamic_update_slice_in_dim(cache_vs, vsc, pos,
-                                                   axis=1)
-    else:
-        cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(
-            cache_k.dtype), pos, axis=1)
-        cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(
-            cache_v.dtype), pos, axis=1)
+    cache_k, cache_v, cache_ks, cache_vs = _cache_write(
+        cache_k, cache_v, cache_ks, cache_vs, k, v, pos)
     o = _attn_with_cache(q, cache_k, cache_v, pos + T, nh,
                          use_kernel=use_kernel, kstart=kstart,
                          k_rows=cache_ks if quant else None,

@@ -53,8 +53,47 @@ class YarnRope:
     attention_factor: Optional[float] = None
 
 
-#: the two kinds of attention layer a ``layer_pattern`` may name
-LAYER_KINDS = ("sliding", "full")
+#: the kinds of layer a ``layer_pattern`` may name. ``sliding`` and ``full``
+#: are attention + MLP pairs (the decoder ``_block``); the other three are
+#: the single-part layers of a hybrid model (``models/hybrid.py``): ONE
+#: mixer or ONE feed-forward part under its own pre-norm and residual.
+LAYER_KINDS = ("sliding", "full", "mamba2", "attention", "experts")
+HYBRID_KINDS = LAYER_KINDS[2:]
+
+#: what a layer of each kind keeps between calls of a serving program: a
+#: paged KV pool (``full``: block tables and admission follow it;
+#: ``sliding``: the window's ring), a slot of recurrent ``state``, or
+#: nothing. The cache manager and the engine build pools and programs from
+#: :meth:`LlamaConfig.cache_layers`, not from the kinds' names.
+CACHE_OF_KIND = {"sliding": "sliding", "full": "full", "attention": "full",
+                 "mamba2": "state", "experts": None}
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """The widths of a hybrid model's single-part layers (the published
+    ``nemotron_h`` keys in brackets). ``LlamaConfig.moe`` carries the
+    router's width and ``top_k``; how many of the routed experts THIS
+    process holds is a property of the parameters (the expert stacks'
+    expert axis and ``first_expert``), not of the config."""
+    ssm_heads: int = 8            # mamba_num_heads
+    ssm_head_dim: int = 8         # mamba_head_dim
+    ssm_groups: int = 2           # n_groups
+    ssm_state: int = 16           # ssm_state_size
+    conv_kernel: int = 4          # conv_kernel
+    chunk_size: int = 128         # chunk_size: the scan's sub-chunk
+    latent_size: int = 32         # moe_latent_size
+    expert_size: int = 48         # moe_intermediate_size
+    shared_size: int = 64         # moe_shared_expert_intermediate_size
+    routed_scale: float = 1.0     # routed_scaling_factor
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,15 +136,33 @@ class LlamaConfig:
     # layers by ``rope_theta`` under ``yarn`` when it is given
     rope_theta_sliding: Optional[float] = None
     yarn: Optional[YarnRope] = None
+    # a hybrid model: the period names "mamba2" / "attention" / "experts"
+    # layers, each ONE part (models/hybrid.py); weights are a stack per
+    # kind, ``params["layers"][kind]``. Its attention applies no rotary
+    # embedding, and ``moe`` carries the router's width and top_k.
+    hybrid: Optional[HybridConfig] = None
 
     def __post_init__(self):
         pat = self.layer_pattern
         if pat is None:
+            if self.hybrid is not None:
+                raise ValueError("hybrid: layer_pattern must name the "
+                                 "period's layer kinds")
             return
         if not pat or any(k not in LAYER_KINDS for k in pat):
             raise ValueError(
-                f"layer_pattern={pat!r}: a period names each layer "
-                f"'sliding' or 'full'")
+                f"layer_pattern={pat!r}: a period names each layer one of "
+                f"{LAYER_KINDS}")
+        single = sum(k in HYBRID_KINDS for k in pat)
+        if single not in (0, len(pat)) or bool(single) != (
+                self.hybrid is not None):
+            raise ValueError(
+                f"layer_pattern={pat!r}: the kinds {HYBRID_KINDS} are a "
+                f"hybrid model's (LlamaConfig.hybrid) and do not mix with "
+                f"'sliding' / 'full'")
+        if "experts" in pat and self.moe is None:
+            raise ValueError("layer_pattern names expert layers: moe must "
+                             "give the router's width and top_k")
         if self.num_layers % len(pat):
             raise ValueError(
                 f"num_layers={self.num_layers} is not a whole number of "
@@ -131,6 +188,16 @@ class LlamaConfig:
         """Layers of this kind in the whole model."""
         return (self.num_layers // len(self.period)) * self.period.count(kind)
 
+    def cache_layers(self) -> Dict[str, int]:
+        """What the model asks of a cache manager: kind of cache
+        (:data:`CACHE_OF_KIND`) -> how many of its layers need one."""
+        out: Dict[str, int] = {}
+        for kind in dict.fromkeys(self.period):
+            c = CACHE_OF_KIND[kind]
+            if c is not None:
+                out[c] = out.get(c, 0) + self.kind_layers(kind)
+        return out
+
     # ---- presets (sizes follow the public Llama-2 family) ----
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -151,6 +218,9 @@ class LlamaConfig:
         return LlamaConfig(**d)
 
     def num_params(self) -> int:
+        if self.hybrid is not None:
+            raise ValueError("num_params: count a hybrid model's leaves "
+                             "(models/hybrid.py:param_shapes)")
         h, i, v, L = (self.hidden_size, self.intermediate_size,
                       self.vocab_size, self.num_layers)
         hd, nh, nkv = self.hd, self.num_heads, self.num_kv_heads
@@ -166,6 +236,9 @@ class LlamaConfig:
 
 # ---------------- init ----------------
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
+    if cfg.hybrid is not None:
+        from . import hybrid as _hybrid
+        return _hybrid.init_params(key, cfg)
     h, i, v, L = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
                   cfg.num_layers)
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
@@ -743,7 +816,14 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     "cp": axis, "ep": axis} to enable activation sharding constraints;
     None for single-device.
     """
-    x, _ = _trunk(params, tokens, cfg, mesh_axes)
+    if cfg.hybrid is not None:
+        if mesh_axes is not None:
+            raise ValueError("forward: a hybrid model has no sharded "
+                             "no-cache forward")
+        from . import hybrid as _hybrid
+        x = _hybrid.trunk(params, tokens, cfg)
+    else:
+        x, _ = _trunk(params, tokens, cfg, mesh_axes)
     if return_hidden:
         return x
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

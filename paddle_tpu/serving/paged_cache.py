@@ -577,7 +577,23 @@ class PagedKVCache:
     ever; the ring needs no reservation, since no row can hold more
     than ``window_ring`` and only trie-held twins, which an allocation
     evicts under pressure, take the rest. ``prefill_chunk`` (tokens;
-    None: whole prompts) only sizes that ring."""
+    None: whole prompts) only sizes that ring.
+
+    A config with state-space layers (``self.state_layers`` of them) adds
+    the third kind of cache, a pool of recurrent state addressed by SLOT:
+    ``self.pool`` holds it as ``ssm`` / ``conv`` (``models/generate.
+    init_paged_cache``), one slot a decode row, owned by whoever owns the
+    row's block table. Admission (:meth:`admit`, :meth:`admit_prompt`)
+    resets the slot: the row's first chunk, at context 0, starts from
+    zeros whatever the slot's last tenant left (``models/hybrid.py:
+    forward_chunk``), so a reset costs no program of its own.
+    :meth:`release` and :meth:`evict_for_preempt` drop the slot with the
+    row's pages; a preempted row's state is not kept, and its resume
+    rebuilds it by prefilling prompt and answer again, as it rebuilds the
+    pages. What walks PAGES to copy a row (prefix hits, drain/restore, the
+    fabric's handoff, defrag) would leave its state behind and is refused
+    by name: a prefix hit would need a snapshot of the state at the page
+    boundary."""
 
     def __init__(self, cfg, max_batch: int, max_len: int,
                  page_size: int = 16, num_pages: Optional[int] = None,
@@ -616,6 +632,20 @@ class PagedKVCache:
             ax, tp = None, None
         self.window = (cfg.sliding_window
                        if "sliding" in cfg.period else None)
+        self.state_layers = cfg.cache_layers().get("state", 0)
+        if self.state_layers:
+            for on, what in ((enable_prefix_cache, "enable_prefix_cache "
+                              "(the prefix cache)"),
+                             (mesh is not None, "mesh (a sharded pool)")):
+                if on:
+                    raise ValueError(
+                        f"PagedKVCache: {what} is not supported on a "
+                        f"config with state-space layers: a row's "
+                        f"recurrent state lives beside its pages and is "
+                        f"not copied, shared or sharded with them")
+        # slots reset by an admission, and the most in use at once
+        self.state_resets_total = 0
+        self.state_slots_used_peak = 0
         self.window_ring = self.window_pages = None
         if self.window:
             self.window_ring = min(
@@ -627,7 +657,8 @@ class PagedKVCache:
         # (and expands the head extent on the GQA replication path)
         self.pool = _gen.init_paged_cache(cfg, num_pages, page_size,
                                           kv_dtype=kv_dtype, tp=tp,
-                                          window_pages=self.window_pages)
+                                          window_pages=self.window_pages,
+                                          state_slots=max_batch)
         if mesh is not None:
             import jax
             from jax.sharding import NamedSharding
@@ -721,6 +752,11 @@ class PagedKVCache:
     # ---- the sliding layers' pool ----
     def _refuse_window(self, what: str):
         """The features that copy a row's pages as one list of ids."""
+        if self.state_layers:
+            raise ValueError(
+                f"{what} is not supported on a config with state-space "
+                f"layers: it walks pages and would leave the row's "
+                f"recurrent state behind")
         if self.window:
             raise ValueError(
                 f"{what} is not supported on a config with "
@@ -804,6 +840,11 @@ class PagedKVCache:
         self.block_tables[slot] = TRASH_PAGE
         self.block_tables[slot, :len(pages)] = pages
         self.active[slot] = True
+        if self.state_layers:
+            # the slot's recurrent state is the new row's, from zero
+            self.state_resets_total += 1
+            self.state_slots_used_peak = max(self.state_slots_used_peak,
+                                             int(self.active.sum()))
         return self.block_tables[slot]
 
     def admit(self, slot: int, total_tokens: int) -> np.ndarray:
@@ -964,6 +1005,21 @@ class PagedKVCache:
 
     def free_slots(self) -> List[int]:
         return [i for i in range(self.max_batch) if not self.active[i]]
+
+    def state_stats(self) -> Dict[str, int]:
+        """The recurrent-state pool's gauges and counters (empty for a
+        config without state-space layers)."""
+        if not self.state_layers:
+            return {}
+        from ..models.generate import STATE_ARRAYS
+        return {"state_slots": self.max_batch,
+                "state_slots_used": int(self.active.sum()),
+                "state_slots_used_peak": self.state_slots_used_peak,
+                "state_pool_bytes": sum(
+                    int(np.prod(self.pool[n].shape))
+                    * np.dtype(self.pool[n].dtype).itemsize
+                    for n in STATE_ARRAYS),
+                "ssm_state_resets_total": self.state_resets_total}
 
     def pages_held(self, slot: int) -> List[int]:
         """The page ids ``slot``'s block table currently references

@@ -43,9 +43,17 @@ def _plant_sync_in_dispatch(tree):
     return "decode_dispatch"
 
 
+def _plant_unfed_counter(tree):
+    (tree / "paddle_tpu/inference/predictor.py").write_text(
+        'count("ssm_state_rows_total", 1)\ncount("ssm_chunk_tokens_total")\n'
+        'count("moe_items_elsewhere_total")\n')
+    return "ssm_state_rebuilds_total"
+
+
 @pytest.mark.parametrize("rule, plant", [
     ("check_fault_sites", _plant_unthreaded_site),
-    ("check_sync_points", _plant_sync_in_dispatch)])
+    ("check_sync_points", _plant_sync_in_dispatch),
+    ("check_hybrid_names", _plant_unfed_counter)])
 def test_checker_flags_a_planted_violation(tmp_path, rule, plant):
     """The lint itself must fail, and name the culprit, when a declared
     site loses its fault_point or a dispatch function reads the device."""

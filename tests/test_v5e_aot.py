@@ -207,6 +207,69 @@ def test_grouped_expert_matmul_mellum_widths(v5e, items):
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 4
 
 
+# NVIDIA-Nemotron-3-Super-120B-A12B's published widths at the cut of
+# chipbench/configs/nemotron3-super-120b-a12b.json: one period of 11 layers
+# (5 Mamba-2, 5 expert, 1 attention), 128 of the router's 512 experts held,
+# a quarter of the vocabulary; batch 128, 24,577 pages of 64, 192 pages a
+# row. Weights 9.32 GB, the recurrent-state pool 2.72 GB, the KV pool
+# 1.61 GB. As compiled here the decode program's temp is 0.02 GB (the pools
+# are written where they lie) and the largest chunk program's (context
+# 12,288, width 256) 0.56 GB: 14.21 GB of the chip's 15.75.
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_hybrid_serving_programs_fit_one_chip(v5e, program):
+    from paddle_tpu.models import generate as gen, hybrid
+    from paddle_tpu.models.moe import MoEConfig
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=2688,
+        num_layers=11, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=12288, rms_eps=1e-5, dtype=jnp.bfloat16,
+        tie_embeddings=False, moe=MoEConfig(num_experts=512, top_k=22),
+        layer_pattern=("mamba2", "experts") * 3 + (
+            "mamba2", "attention", "experts", "mamba2", "experts"),
+        hybrid=llama.HybridConfig(
+            ssm_heads=128, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
+            conv_kernel=4, chunk_size=128, latent_size=1024,
+            expert_size=2688, shared_size=5376, routed_scale=5.0))
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(
+        lambda k: hybrid.init_params(k, cfg, experts_held=128),
+        jax.random.key(0)))
+    B, page, pps = 128, 64, 192
+    pools = on(jax.eval_shape(lambda: gen.init_paged_cache(
+        cfg, 24577, page, state_slots=B)))
+    i32 = jnp.int32
+    if program == "decode":
+        def step(params, last, paged, tables, lengths, active):
+            logits, paged, stats = gen.paged_decode_forward(
+                params, last, paged, tables, lengths, cfg, active=active,
+                use_kernel=True, with_stats=True)
+            return jnp.argmax(logits, -1), paged, stats
+        avals = (_on(d, (B,), i32), pools, _on(d, (B, pps), i32),
+                 _on(d, (B,), i32), _on(d, (B,), jnp.bool_))
+    else:
+        def step(params, toks, paged, table, ctx_len, chunk_len, slot):
+            return gen.paged_prefill_chunk(
+                params, toks, paged, table, cfg, ctx_cap=pps * page,
+                ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=True,
+                with_stats=True, state_slot=slot)
+        avals = (_on(d, (1, 256), i32), pools, _on(d, (pps,), i32),
+                 _on(d, (), i32), _on(d, (), i32), _on(d, (), i32))
+    compiled = _compile(jax.jit(step, donate_argnums=(2,)), params, *avals)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held < 15.75e9
+    text = compiled.as_text()
+    assert text.count("grouped_expert_matmul") >= 10
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pools))
+    if program == "decode":
+        # the state and KV pools ride through the layers whole, in place
+        assert text.count("ssm_state_update") >= 5
+        assert m.temp_size_in_bytes < pool_bytes // 8
+
+
 def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
     """block_rows=256 x width 4096 needed 19.93 MiB of the 16 MiB scoped
     VMEM, forward and backward; the row block now follows the width."""

@@ -1,12 +1,14 @@
 #!/usr/bin/env python
-"""Two conditions of the serving design, checked on the source text.
+"""Three conditions of the serving design, checked on the source text.
 
 ``check_fault_sites``: every site in ``serving/resilience.py``'s
 ``ENGINE_SITES`` / ``CLUSTER_SITES`` has a ``fault_point("<site>")``
 call in a hot-path module, or the chaos coverage claims sites it never
 exercised. ``check_sync_points``: the scheduler and the engine's
 dispatch-path functions hold no device-to-host read, or the overlapped
-step falls back to a synchronous chain. Runs in tier-1
+step falls back to a synchronous chain. ``check_hybrid_names``: the
+counters, gauges and named scopes of the recurrent-state pool and the
+expert share are fed where the tracing says. Runs in tier-1
 (tests/test_instrumentation_lint.py); standalone:
 
     python tools/check_instrumentation.py
@@ -104,6 +106,9 @@ _SYNC_FREE = {
     # exported as host numpy views, and keeping it device-blind is
     # what lets the fabric server run as a jax-free process
     "paddle_tpu/serving/rpc.py": None,
+    # a hybrid model's forwards run inside the dispatched programs: no
+    # host read of a device value anywhere in them
+    "paddle_tpu/models/hybrid.py": None,
 }
 
 #: device-sync idioms: a bare one-argument np.asarray (dtype-annotated
@@ -166,9 +171,41 @@ def check_sync_points(root: str) -> list:
     return problems
 
 
+#: the names the recurrent-state pool and the expert share are read by
+#: (``engine.stats()``, the device trace): each has to be fed somewhere in
+#: the modules listed, or a per-layer metric reads a total that never moves
+_HYBRID_NAMES = {
+    "ssm_state_rows_total": "paddle_tpu/inference/predictor.py",
+    "ssm_chunk_tokens_total": "paddle_tpu/inference/predictor.py",
+    "ssm_state_rebuilds_total": "paddle_tpu/inference/predictor.py",
+    "moe_items_elsewhere_total": "paddle_tpu/inference/predictor.py",
+    "ssm_state_resets_total": "paddle_tpu/serving/paged_cache.py",
+    "state_slots_used_peak": "paddle_tpu/serving/paged_cache.py",
+    "state_pool_bytes": "paddle_tpu/serving/paged_cache.py",
+    'named_scope("ssm_state_update")': "paddle_tpu/ops/pallas/ssm.py",
+    'named_scope("ssm_chunk_scan")': "paddle_tpu/models/hybrid.py",
+}
+
+
+def check_hybrid_names(root: str) -> list:
+    """Counters, gauges and named scopes of the state pool and the expert
+    share exist where the tracing says they are fed."""
+    problems = []
+    for name, rel in _HYBRID_NAMES.items():
+        path = os.path.join(root, rel)
+        if not os.path.exists(path):
+            problems.append(f"{rel}: file missing")
+            continue
+        with open(path, encoding="utf-8") as f:
+            if name not in f.read():
+                problems.append(f"{rel}: nothing feeds {name!r}")
+    return problems
+
+
 def check(root: str) -> list:
     """Returns a list of human-readable violation strings (empty = ok)."""
-    return check_fault_sites(root) + check_sync_points(root)
+    return (check_fault_sites(root) + check_sync_points(root)
+            + check_hybrid_names(root))
 
 
 def main() -> int:
@@ -178,7 +215,8 @@ def main() -> int:
         for p in problems:
             print(f"check_instrumentation: {p}", file=sys.stderr)
         return 1
-    print("check_instrumentation: fault sites and sync points ok")
+    print("check_instrumentation: fault sites, sync points and the state "
+          "pool's names ok")
     return 0
 
 
