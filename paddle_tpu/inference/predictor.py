@@ -625,6 +625,26 @@ class ContinuousBatchingEngine:
                         f"ContinuousBatchingEngine: {what} is not "
                         f"supported on a config with state-space layers "
                         f"(a recurrent state a row beside its pages)")
+        # --- latent attention (LlamaConfig.latent): the model asks for a
+        # pool of latents with no head axis; the decode and chunk programs
+        # take it as they take any pool, donated and whole in the layer
+        # scan's carry. What would shard it, or verify or draft through
+        # the programs that keep keys and values by head, refuses such a
+        # config here, by name.
+        self._latent_layers = cfg.cache_layers().get("latent", 0)
+        if self._latent_layers:
+            for on, what in ((host_tier, "host_tier"),
+                             (draft_layers, "draft_layers"),
+                             (spec_k or spec_tree, "speculative decoding"
+                              " (spec_k / spec_tree)"),
+                             (mesh is not None, "mesh"),
+                             (adapters is not None, "adapters"),
+                             (fused, "fused")):
+                if on:
+                    raise ValueError(
+                        f"ContinuousBatchingEngine: {what} is not "
+                        f"supported on a config with latent attention (a "
+                        f"pool of latents with no head axis)")
         self.mesh = mesh
         self._tp = None
         self._tp_axis = None
@@ -792,19 +812,31 @@ class ContinuousBatchingEngine:
         # (a hybrid config's expert layers hold a share of the experts:
         # the first three count what is held and computed here, and a
         # fourth the items routed to experts held elsewhere)
+        # (a share of the experts held, a property of the parameters: a
+        # hybrid config's expert layers, or a layer tree with
+        # ``first_expert``; the first three then count what is held and
+        # computed here, and a fourth the items routed to experts held
+        # elsewhere)
         self._moe_names = (
             ("moe_routed_items_total", "moe_experts_hit_total",
              "moe_max_expert_load_total")
             + (("moe_items_elsewhere_total",) if cfg.hybrid is not None
-               else ()) + ("moe_layer_steps_total",))
+               or "first_expert" in params["layers"] else ())
+            + ("moe_layer_steps_total",))
         self._moe_layers = (cfg.kind_layers("experts")
-                            if cfg.hybrid is not None else cfg.num_layers)
+                            if cfg.hybrid is not None
+                            else cfg.num_layers - cfg.dense_layers)
         self._moe_acc = (jnp.zeros((len(self._moe_names),), jnp.int32)
                          if cfg.moe is not None else None)
         self._moe_zero = self._moe_acc
         if self._state_layers:
             for name in ("ssm_state_rows_total", "ssm_chunk_tokens_total",
                          "ssm_state_rebuilds_total"):
+                self.spans.count(name, 0)
+        if self._latent_layers:
+            for name in ("latent_tokens_attended_total",
+                         "latent_decode_rows_total",
+                         "latent_chunk_tokens_total"):
                 self.spans.count(name, 0)
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
         # supervisor; -1 renders as the "router" lane in exports
@@ -1752,6 +1784,9 @@ class ContinuousBatchingEngine:
                 # the pools; tokens through the chunked scan, a layer
                 args += [jnp.int32(slot)]
                 self.spans.count("ssm_chunk_tokens_total", take)
+            if self._latent_layers:
+                # tokens whose latents this chunk writes, a layer
+                self.spans.count("latent_chunk_tokens_total", take)
             if self.adapters is not None:
                 args += [self.adapters.arrays,
                          jnp.asarray(self._aslot[slot:slot + 1].copy())]
@@ -2250,6 +2285,13 @@ class ContinuousBatchingEngine:
                 # row-layers of recurrent state this program advances
                 self.spans.count("ssm_state_rows_total",
                                  slots.size * self._state_layers)
+            if self._latent_layers:
+                # tokens this program's rows attend over, the new one
+                # among them, known from the lengths; times layers
+                self.spans.count(
+                    "latent_tokens_attended_total",
+                    int(cache.lengths[slots].sum()) * self._latent_layers)
+                self.spans.count("latent_decode_rows_total", slots.size)
             self._ntok[slots] += 1
         return h
 
@@ -3058,6 +3100,7 @@ class ContinuousBatchingEngine:
             s["window_pool_used_peak"] = wa.peak_in_use
             s["window_pool_usable"] = wa.num_usable
         s.update(self.cache.state_stats())
+        s.update(self.cache.latent_stats())
         if self.adapters is not None:
             s.update(self.adapters.stats())
         if getattr(self.cache, "host", None) is not None:

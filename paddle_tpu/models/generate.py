@@ -94,6 +94,10 @@ def _tp_heads(layers: Dict, cfg: LlamaConfig) -> Tuple[int, int]:
 
 #: suffix of a layer kind's cache arrays (see the module docstring)
 KIND_SUFFIX = {"full": "", "sliding": "_w"}
+#: a pool's arrays by their plain names: keys and values, or a config with
+#: latent attention's ONE pool of latents (``c``, and ``cs`` on the int8
+#: tier: :func:`init_paged_cache`), which stands where a ``full`` pool does
+POOL_NAMES = ("k", "v", "ks", "vs", "c", "cs")
 
 
 def _kind_arrays(cfg: LlamaConfig, make) -> Dict:
@@ -111,8 +115,7 @@ def _kind_arrays(cfg: LlamaConfig, make) -> Dict:
 def _of_kind(arrays: Dict, kind: str) -> Dict:
     """The arrays of one layer kind under their plain names."""
     sfx = KIND_SUFFIX[kind]
-    return {n: arrays[n + sfx] for n in ("k", "v", "ks", "vs")
-            if n + sfx in arrays}
+    return {n: arrays[n + sfx] for n in POOL_NAMES if n + sfx in arrays}
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
@@ -128,6 +131,8 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
     layers' arrays where it is not ``max_len`` (the chunk program's temp
     cache holds one window of context for them)."""
     nkv, hd = cfg.num_kv_heads, cfg.hd
+    _refuse_latent(cfg, "init_cache (the dense cache of generate, "
+                   "beam_search and the verify programs)")
     if num_kv_heads is not None:
         nkv = num_kv_heads
     if kv_dtype is not None and jnp.dtype(kv_dtype) != jnp.int8:
@@ -196,9 +201,34 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
     ``state_dtype`` (heads last: ``ops/pallas/ssm.py`` has the reason) and
     ``conv`` ``(state layers, state_slots, conv_kernel - 1, conv_dim)``
     the causal convolution's last columns in the model's dtype. The KV
-    pools hold layers only for the kinds that attend."""
+    pools hold layers only for the kinds that attend.
+
+    A config with latent attention gets ONE pool, of latents: ``c``
+    ``(layers, num_pages, page_size, LatentConfig.row_lanes)``, a token's
+    normed latent and the one rotated key its heads share (``kv_rank +
+    rope_dim`` numbers, then zeros to a whole 128-lane tile), with no head
+    axis (``tp`` is refused by name: there is nothing to shard); on the
+    int8 tier ``c`` is int8 and ``cs`` ``(..., page_size, 2)`` float32
+    carries a token's two dequant scales, the latent's and the key's.
+    Block tables, admission and the prefix trie deal in page ids and run
+    it as they run a ``full`` pool."""
     nkv, hd = cfg.num_kv_heads, cfg.hd
     needs = cfg.cache_layers()
+    if kv_dtype is not None and jnp.dtype(kv_dtype) != jnp.int8:
+        raise ValueError(
+            f"init_paged_cache: kv_dtype={kv_dtype!r} is not supported — "
+            f"pass None (model dtype) or 'int8'")
+    if "latent" in needs:
+        if tp is not None:
+            raise ValueError(
+                "init_paged_cache: tp is not supported on a pool of "
+                "latents: a latent has no head axis to shard")
+        shape = (needs["latent"], num_pages, page_size,
+                 cfg.latent.row_lanes)
+        if kv_dtype is None:
+            return {"c": jnp.zeros(shape, cfg.dtype)}
+        return {"c": jnp.zeros(shape, jnp.int8),
+                "cs": jnp.zeros(shape[:3] + (2,), jnp.float32)}
     if "sliding" in needs and window_pages is None:
         raise ValueError(
             "init_paged_cache: the config has sliding layers; "
@@ -216,10 +246,6 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
         # head contract is identical and MoE configs are legal on the
         # serving mesh (ISSUE 17 expert-parallel decode)
         nkv = llama.validate_serving_mesh(cfg, tp) * tp
-    if kv_dtype is not None and jnp.dtype(kv_dtype) != jnp.int8:
-        raise ValueError(
-            f"init_paged_cache: kv_dtype={kv_dtype!r} is not supported — "
-            f"pass None (model dtype) or 'int8'")
 
     def make(kind, L):
         P = window_pages if kind == "sliding" else num_pages
@@ -343,11 +369,73 @@ def _expert_apply(x_rows, item_row, le, stacks, layer, tp_axis=None,
     return jnp.take(o, inv, axis=0)
 
 
+def _swiglu(x, lp, gate: str, up: str, down: str):
+    """``(silu(x W_gate) * (x W_up)) W_down`` with the leaves of ``lp`` so
+    named: a dense FFN or a shared expert."""
+    dt = x.dtype
+    g = jax.nn.silu((x @ _w(lp, gate, dt)).astype(jnp.float32)).astype(dt)
+    return (g * (x @ _w(lp, up, dt))) @ _w(lp, down, dt)
+
+
+def _route(xf, router, bias, k: int, score: str, scale: float):
+    """The routing rule of an expert layer, in float32: xf (N, H) rows,
+    ``router`` (H, E) -> ``(idx (N, k) chosen experts, w (N, k) their
+    weights)``; ``lax.top_k`` breaks ties to the lower index.
+
+    ``"softmax"``: the ``k`` largest router logits and a softmax over
+    them, which IS the softmax over all experts, its k largest,
+    renormalised (``norm_topk_prob``).
+
+    ``"sigmoid"``: ``s = sigmoid(x W_r)``; the ``k`` largest of ``s +
+    bias`` are chosen (the bias selects, it does not weigh); the weights
+    are ``scale * s_chosen / sum(s_chosen)`` (``topk_method: noaux_tc``,
+    ``routed_scaling_factor``). The product is float32 in fact: a TPU
+    multiplies float32 operands as bfloat16 unless told otherwise, and a
+    chosen expert's weight here does not fall off towards the k-th
+    (sigmoid scores saturate), so an expert that flips in or out at the
+    boundary moves the layer's output like any other."""
+    if score == "softmax":
+        logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
+        vals, idx = lax.top_k(logits, k)                    # (N, k)
+        return idx, jax.nn.softmax(vals, axis=-1)
+    if score != "sigmoid":
+        raise ValueError(f"routing rule {score!r}: 'softmax' or 'sigmoid'")
+    s = jax.nn.sigmoid(jnp.matmul(
+        xf.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))                       # (N, E)
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)               # (N, k)
+    return idx, scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+def _held_items(idx, first_expert, El: int, valid):
+    """The routed items of a layer that holds the ``El`` experts from
+    ``first_expert`` on: idx (N, k) global expert ids -> ``(le (N*k,) local
+    ids, ``El`` where the expert is held elsewhere, so that
+    :func:`_expert_apply` sorts the item behind every group; item_row
+    (N*k,) the row each item copies; stats)``, ``stats`` int32 ``[items
+    computed here, held experts hit, largest load of a held expert, items
+    routed to experts held elsewhere]`` over the rows ``valid`` (N,) marks
+    (all when None)."""
+    N, k = idx.shape
+    le = idx.reshape(-1).astype(jnp.int32) - first_expert
+    held = (le >= 0) & (le < El)
+    le = jnp.where(held, le, El)
+    item_row = jnp.arange(N * k, dtype=jnp.int32) // k
+    live = (jnp.ones((N * k,), jnp.int32) if valid is None else
+            jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
+    load = jnp.zeros((El,), jnp.int32).at[le].add(live)
+    here = jnp.sum(live * held)
+    return le, item_row, jnp.stack([here, jnp.sum(load > 0), jnp.max(load),
+                                    jnp.sum(live) - here])
+
+
 def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
              valid=None, experts=None, layer=0, use_kernel=None):
     """Serving MoE FFN: capacity-DROPLESS top-k routing and one grouped
     expert layer (:func:`_expert_apply`) for decode and chunk alike,
-    expert-parallel over the dp axis (ISSUE 17).
+    expert-parallel over the dp axis (ISSUE 17), or with a SHARE of the
+    experts held and no exchange.
 
     x: (B, T, H); lp carries this layer's ``moe_gate`` (H, E) fp32
     router (replicated — every shard routes identically, the
@@ -362,13 +450,23 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
     (B, T) marks (all of them when None): what the engine's ``moe_*``
     counters sum.
 
-    Routing: ``top_k`` over the fp32 router logits (lax.top_k —
-    deterministic lowest-index tie-break) and a softmax over the k
-    selected logits, which IS the softmax over all experts, its k
-    largest, renormalised (``norm_topk_prob``). The combine
-    ``y = sum_j w_j * out_j`` runs over the top-k slots IN SLOT ORDER in
-    fp32 — the same fixed-order sum on every path. No capacity, no
-    dropped token: the groups are as long as the routing makes them.
+    Routing (:func:`_route`) by ``cfg.moe.score``: a softmax over the
+    chosen logits, or sigmoid scores with the selection bias ``moe_bias``
+    (E,) and ``cfg.moe.routed_scale``. The combine ``y = sum_j w_j *
+    out_j`` runs over the top-k slots IN SLOT ORDER in fp32 — the same
+    fixed-order sum on every path. No capacity, no dropped token: the
+    groups are as long as the routing makes them.
+
+    A share of the experts: ``lp`` with ``first_expert`` says that the
+    stacks hold the ``E_l`` experts from that one on (a property of the
+    parameters, not of the config). Every token is still routed over all
+    E experts, as on every chip of the deployment; the items whose expert
+    is not held here are dropped before the sort and contribute zero (the
+    other chips' shares would add theirs), with no ``dp`` exchange and
+    nothing in its place, and ``stats`` is :func:`_held_items`'s four.
+
+    ``cfg.moe.shared_size``: a shared three-matrix expert ``ws_g`` /
+    ``ws_u`` / ``ws_d`` on every token, every chip's own, added once.
 
     Dispatch (dp > 1): the N*k routed items scatter into per-owner send
     buffers of capacity N*k each — dropless BY CONSTRUCTION (a worst
@@ -382,23 +480,29 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
     B, T, H = x.shape
     moe = cfg.moe
     k = moe.top_k
-    gate = lp["moe_gate"].astype(jnp.float32)           # (H, E)
     if experts is None:
         experts = tuple(lp[n][None] for n in EXPERT_STACKS)
-    E = gate.shape[-1]
+    E = lp["moe_gate"].shape[-1]
     El = experts[0].shape[1]                            # local experts
     N = B * T
     xf = x.reshape(N, H)
-    logits = xf.astype(jnp.float32) @ gate              # (N, E)
-    vals, idx = lax.top_k(logits, k)                    # (N, k)
-    w = jax.nn.softmax(vals, axis=-1)                   # fp32
-    items_e = idx.reshape(-1).astype(jnp.int32)         # global ids
+    idx, w = _route(xf, lp["moe_gate"], lp.get("moe_bias"), k, moe.score,
+                    moe.routed_scale)
     n = N * k
-    item_row = jnp.arange(n, dtype=jnp.int32) // k
-    live = (jnp.ones((n,), jnp.int32) if valid is None else
-            jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
-    load = jnp.zeros((E,), jnp.int32).at[items_e].add(live)
-    stats = jnp.stack([jnp.sum(live), jnp.sum(load > 0), jnp.max(load)])
+    share = "first_expert" in lp
+    if share:
+        if dp_axis is not None:
+            raise ValueError("_moe_ffn: a share of the experts (lp has "
+                             "first_expert) takes no dp exchange")
+        items_e, item_row, stats = _held_items(idx, lp["first_expert"], El,
+                                               valid)
+    else:
+        items_e = idx.reshape(-1).astype(jnp.int32)     # global ids
+        item_row = jnp.arange(n, dtype=jnp.int32) // k
+        live = (jnp.ones((n,), jnp.int32) if valid is None else
+                jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
+        load = jnp.zeros((E,), jnp.int32).at[items_e].add(live)
+        stats = jnp.stack([jnp.sum(live), jnp.sum(load > 0), jnp.max(load)])
     if dp_axis is not None and El != E:
         # expert-parallel dispatch: owner shard + local id from the
         # LOCAL stack shape (dp = E/El — no collective needed), rank
@@ -430,10 +534,13 @@ def _moe_ffn(x, lp, cfg: LlamaConfig, tp_axis=None, dp_axis=None,
         items_out = back[owner, pos]                    # (N*k, H)
     else:
         items_out = _expert_apply(xf, item_row, items_e, experts, layer,
-                                  tp_axis=tp_axis, use_kernel=use_kernel)
+                                  tp_axis=tp_axis, use_kernel=use_kernel,
+                                  absent=share)
     y = jnp.sum(items_out.reshape(N, k, H).astype(jnp.float32)
-                * w[:, :, None], axis=1)
-    return y.astype(x.dtype).reshape(B, T, H), stats
+                * w[:, :, None], axis=1).astype(x.dtype)
+    if moe.shared_size:
+        y = y + _swiglu(xf, lp, "ws_g", "ws_u", "ws_d")
+    return y.reshape(B, T, H), stats
 
 
 def _latent_moe_ffn(x, lp, cfg: LlamaConfig, experts, layer, valid=None,
@@ -449,15 +556,14 @@ def _latent_moe_ffn(x, lp, cfg: LlamaConfig, experts, layer, valid=None,
     experts ``first_expert .. first_expert + E_l`` of every layer, read in
     place by :func:`_expert_apply`.
 
-    Routing, in float32: ``s = sigmoid(x W_r)``; the ``top_k`` largest of
-    ``s + bias`` are chosen (the bias selects, it does not weigh); the
-    weights are ``routed_scale * s_chosen / sum(s_chosen)``. Every token
-    is routed over all E experts, as on every chip of the deployment; the
-    items whose expert is not held here are dropped before the sort and
-    contribute zero: the other chips' shares would add theirs through the
-    same ``w_up``, which is linear and has no bias. No ``dp`` exchange,
-    and nothing stands in for the absent chips. The shared expert is
-    every chip's own and is added once.
+    Routing is :func:`_route`'s sigmoid rule and the share
+    :func:`_held_items`'s, as in :func:`_moe_ffn`: every token is routed
+    over all E experts, as on every chip of the deployment; the items whose
+    expert is not held here are dropped before the sort and contribute
+    zero: the other chips' shares would add theirs through the same
+    ``w_up``, which is linear and has no bias. No ``dp`` exchange, and
+    nothing stands in for the absent chips. The shared expert is every
+    chip's own and is added once.
 
     Returns ``(y, stats)``; ``stats`` is int32 ``[items computed here,
     held experts hit, largest load of a held expert, items routed to
@@ -467,27 +573,9 @@ def _latent_moe_ffn(x, lp, cfg: LlamaConfig, experts, layer, valid=None,
     El = experts[0].shape[1]
     N = B * T
     xf = x.reshape(N, H)
-    # float32 in fact: a TPU multiplies float32 operands as bfloat16 unless
-    # told otherwise, and a chosen expert's weight here does not fall off
-    # towards the k-th (sigmoid scores saturate), so an expert that flips
-    # in or out at the boundary moves the layer's output like any other
-    s = jax.nn.sigmoid(jnp.matmul(
-        xf.astype(jnp.float32), lp["router"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))                       # (N, E)
-    _, idx = lax.top_k(s + lp["router_bias"].astype(jnp.float32), k)
-    chosen = jnp.take_along_axis(s, idx, axis=-1)               # (N, k)
-    w = cfg.hybrid.routed_scale * chosen / jnp.sum(chosen, -1, keepdims=True)
-    n = N * k
-    le = idx.reshape(-1).astype(jnp.int32) - lp["first_expert"]
-    held = (le >= 0) & (le < El)
-    le = jnp.where(held, le, El)
-    item_row = jnp.arange(n, dtype=jnp.int32) // k
-    live = (jnp.ones((n,), jnp.int32) if valid is None else
-            jnp.repeat(valid.reshape(N).astype(jnp.int32), k))
-    load = jnp.zeros((El,), jnp.int32).at[le].add(live)
-    here = jnp.sum(live * held)
-    stats = jnp.stack([here, jnp.sum(load > 0), jnp.max(load),
-                       jnp.sum(live) - here])
+    idx, w = _route(xf, lp["router"], lp["router_bias"], k, "sigmoid",
+                    cfg.hybrid.routed_scale)
+    le, item_row, stats = _held_items(idx, lp["first_expert"], El, valid)
     lat = xf @ _w(lp, "w_down", dt)                             # (N, l)
     items = _expert_apply(lat, item_row, le, experts, layer,
                           use_kernel=use_kernel, absent=True)
@@ -543,8 +631,19 @@ def _split_experts(layers: Dict):
             tuple(layers[n] for n in EXPERT_STACKS))
 
 
+def _refuse_latent(cfg: LlamaConfig, what: str):
+    """The programs that keep keys and values by head."""
+    if cfg.latent is not None:
+        raise ValueError(
+            f"{what}: the config has latent attention (a pool of latents "
+            f"with no head axis); only paged_prefill_chunk and "
+            f"paged_decode_forward serve it, and llama.forward runs it "
+            f"without a cache")
+
+
 def _refuse_sliding(cfg: LlamaConfig, what: str):
     """The programs that know one pool and one block table a row."""
+    _refuse_latent(cfg, what)
     if cfg.hybrid is not None:
         raise ValueError(
             f"{what}: the config has state-space layers (a recurrent "
@@ -715,6 +814,16 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     if B != 1:
         raise ValueError(
             f"paged_prefill_chunk: one request at a time (got batch {B})")
+    if cfg.latent is not None:
+        # the chunk's latents go straight into the pool and its queries
+        # attend over the row's pages: no temp cache is gathered
+        from . import latent as _latent
+        _latent.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                       adapters=adapters)
+        out = _latent.forward_chunk(
+            params, tokens, paged, block_table, cfg, ctx_cap=ctx_cap,
+            ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=use_kernel)
+        return out if with_stats else out[:2]
     page = paged["k"].shape[2]
     if ctx_cap % page:
         raise ValueError(
@@ -1009,6 +1118,7 @@ def make_draft_params(params, cfg: LlamaConfig, n_layers: int):
     targets stay sharded: slicing the stacked (L, ...) layer arrays on
     axis 0 preserves each leaf's head/vocab partitioning, so the draft
     runs under the same tp mesh with the same param specs."""
+    _refuse_latent(cfg, "make_draft_params (a truncated-layer draft model)")
     L = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
     if not (1 <= n_layers < L):
         raise ValueError(
@@ -1181,6 +1291,14 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         _hybrid.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
                        adapters=adapters)
         out = _hybrid.decode_forward(
+            params, tokens, paged, block_tables, lengths, cfg,
+            active=active, use_kernel=use_kernel)
+        return out if with_stats else out[:2]
+    if cfg.latent is not None:
+        from . import latent as _latent
+        _latent.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                       adapters=adapters)
+        out = _latent.decode_forward(
             params, tokens, paged, block_tables, lengths, cfg,
             active=active, use_kernel=use_kernel)
         return out if with_stats else out[:2]
@@ -1413,7 +1531,14 @@ def quantize_weights(params, cfg: LlamaConfig, bits: int = 8,
             layers[name] = qw
             layers[name + "_scale"] = sc
         return layers
-    if cfg.hybrid is not None:
+    if cfg.latent is not None:
+        # the attention projections, the dense and the shared FFN; the
+        # expert stacks stay as they are
+        from .latent import QUANT_LEAVES
+        for group in ("layers", "dense_layers"):
+            if group in params:
+                out[group] = stacks(params[group], QUANT_LEAVES)
+    elif cfg.hybrid is not None:
         # a stack per kind: the attention and the Mamba-2 projections;
         # the expert layers stay as they are
         out["layers"] = {

@@ -54,19 +54,61 @@ class YarnRope:
 
 
 #: the kinds of layer a ``layer_pattern`` may name. ``sliding`` and ``full``
-#: are attention + MLP pairs (the decoder ``_block``); the other three are
+#: are attention + MLP pairs (the decoder ``_block``); the next three are
 #: the single-part layers of a hybrid model (``models/hybrid.py``): ONE
 #: mixer or ONE feed-forward part under its own pre-norm and residual.
-LAYER_KINDS = ("sliding", "full", "mamba2", "attention", "experts")
-HYBRID_KINDS = LAYER_KINDS[2:]
+#: ``latent`` is an attention + MLP pair whose attention is latent
+#: attention (``models/latent.py``, :class:`LatentConfig`).
+LAYER_KINDS = ("sliding", "full", "mamba2", "attention", "experts", "latent")
+HYBRID_KINDS = LAYER_KINDS[2:5]
 
 #: what a layer of each kind keeps between calls of a serving program: a
 #: paged KV pool (``full``: block tables and admission follow it;
 #: ``sliding``: the window's ring), a slot of recurrent ``state``, or
-#: nothing. The cache manager and the engine build pools and programs from
-#: :meth:`LlamaConfig.cache_layers`, not from the kinds' names.
+#: nothing; a ``latent`` layer a paged pool of LATENTS, one row of
+#: :attr:`LatentConfig.row` numbers a token with no head axis, under the
+#: block tables a ``full`` pool would have. The cache manager and the
+#: engine build pools and programs from :meth:`LlamaConfig.cache_layers`,
+#: not from the kinds' names.
 CACHE_OF_KIND = {"sliding": "sliding", "full": "full", "attention": "full",
-                 "mamba2": "state", "experts": None}
+                 "mamba2": "state", "experts": None, "latent": "latent"}
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention's widths (the published ``deepseek_v3`` / ``kimi_k2``
+    keys in brackets). A head's query and key are ``nope_dim + rope_dim``
+    wide and its value ``v_dim``, so ``LlamaConfig.hd`` names no head size
+    of such a config; what a token keeps a layer is :attr:`row` numbers.
+    ``mscale``: YaRN's ``0.1 mscale_all_dim ln(factor) + 1``, whose square
+    multiplies the softmax scale (1.0: none)."""
+    q_rank: int = 48              # q_lora_rank
+    kv_rank: int = 32             # kv_lora_rank
+    nope_dim: int = 16            # qk_nope_head_dim
+    rope_dim: int = 8             # qk_rope_head_dim
+    v_dim: int = 16               # v_head_dim
+    mscale: float = 1.0
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row(self) -> int:
+        """The normed latent and the one rotated key all heads share."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def row_lanes(self) -> int:
+        """:attr:`row` in whole 128-lane tiles: how wide the pool lays a
+        row out (the chip's layout pads the last axis to tiles whatever the
+        shape says, and a page is copied out of HBM in whole tiles), zeros
+        in the lanes past ``row``."""
+        return -(-self.row // 128) * 128
+
+    @property
+    def scale(self) -> float:
+        return self.qk_dim ** -0.5 * self.mscale * self.mscale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,9 +183,34 @@ class LlamaConfig:
     # kind, ``params["layers"][kind]``. Its attention applies no rotary
     # embedding, and ``moe`` carries the router's width and top_k.
     hybrid: Optional[HybridConfig] = None
+    # latent attention: the period is ("latent",), ``rope_theta`` / ``yarn``
+    # rotate ``latent.rope_dim`` lanes, and the parameters, the no-cache
+    # forward and the serving layer bodies are models/latent.py's
+    latent: Optional[LatentConfig] = None
+    # leading layers whose MLP is dense at ``intermediate_size`` although
+    # ``moe`` is set (``first_k_dense_replace``): their weights are a stack
+    # of their own, ``params["dense_layers"]``, run as a prologue before the
+    # scan over the expert layers ``params["layers"]``
+    dense_layers: int = 0
 
     def __post_init__(self):
         pat = self.layer_pattern
+        if (self.latent is not None) != (pat == ("latent",)):
+            raise ValueError(
+                f"layer_pattern={pat!r}: a config with latent attention "
+                f"(LlamaConfig.latent) has the period ('latent',), and no "
+                f"other config names that kind")
+        if self.latent is not None and self.moe is None:
+            raise ValueError("latent attention: moe must describe the "
+                             "expert layers (models/latent.py)")
+        if self.dense_layers and (
+                self.latent is None or self.moe is None
+                or not 0 < self.dense_layers < self.num_layers):
+            raise ValueError(
+                f"dense_layers={self.dense_layers}: leading dense layers "
+                f"are served before the expert layers of a latent-attention "
+                f"config (0 < dense_layers < num_layers, moe set); for "
+                f"other layer kinds cfg.moe switches every MLP at once")
         if pat is None:
             if self.hybrid is not None:
                 raise ValueError("hybrid: layer_pattern must name the "
@@ -218,9 +285,10 @@ class LlamaConfig:
         return LlamaConfig(**d)
 
     def num_params(self) -> int:
-        if self.hybrid is not None:
-            raise ValueError("num_params: count a hybrid model's leaves "
-                             "(models/hybrid.py:param_shapes)")
+        if self.hybrid is not None or self.latent is not None:
+            raise ValueError("num_params: count the leaves of a hybrid or "
+                             "latent-attention model (param_shapes of "
+                             "models/hybrid.py, models/latent.py)")
         h, i, v, L = (self.hidden_size, self.intermediate_size,
                       self.vocab_size, self.num_layers)
         hd, nh, nkv = self.hd, self.num_heads, self.num_kv_heads
@@ -239,6 +307,9 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.hybrid is not None:
         from . import hybrid as _hybrid
         return _hybrid.init_params(key, cfg)
+    if cfg.latent is not None:
+        from . import latent as _latent
+        return _latent.init_params(key, cfg)
     h, i, v, L = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
                   cfg.num_layers)
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
@@ -419,6 +490,11 @@ def _validate_serving_heads(cfg: LlamaConfig, tp: int) -> int:
     returns per-shard kv heads."""
     if tp < 1:
         raise ValueError(f"serving tp must be >= 1, got {tp}")
+    if cfg.latent is not None:
+        raise ValueError(
+            "serving tp/mesh is not supported on a latent-attention config: "
+            "the pool of latents has no head axis to shard (heads sharded "
+            "over a replicated latent is not built)")
     if cfg.num_heads % tp:
         raise ValueError(
             f"num_heads={cfg.num_heads} is not divisible by tp={tp}: "
@@ -595,6 +671,10 @@ def rope_tables_by_kind(cfg: LlamaConfig, seq_len: int
         if kind == "sliding":
             out[kind] = rope_tables(seq_len, cfg.hd,
                                     cfg.rope_theta_sliding or cfg.rope_theta)
+        elif kind == "latent":
+            # the rotary width is the shared key's, not a head's
+            out[kind] = rope_tables(seq_len, cfg.latent.rope_dim,
+                                    cfg.rope_theta, yarn=cfg.yarn)
         else:
             out[kind] = rope_tables(seq_len, cfg.hd, cfg.rope_theta,
                                     yarn=cfg.yarn)
@@ -753,6 +833,12 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None,
 
 def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
     """-> (final-norm hidden (B,S,H), summed MoE aux loss scalar)."""
+    if cfg.latent is not None:
+        raise ValueError(
+            "training a latent-attention config is not supported: its "
+            "expert layer has no grouped backward and its attention no "
+            "flash kernel (models/latent.py serves it; llama.forward is "
+            "its no-cache forward)")
     B, S = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     if mesh_axes is not None:
@@ -822,6 +908,12 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
                              "no-cache forward")
         from . import hybrid as _hybrid
         x = _hybrid.trunk(params, tokens, cfg)
+    elif cfg.latent is not None:
+        if mesh_axes is not None:
+            raise ValueError("forward: a latent-attention config has no "
+                             "sharded no-cache forward")
+        from . import latent as _latent
+        x = _latent.trunk(params, tokens, cfg)
     else:
         x, _ = _trunk(params, tokens, cfg, mesh_axes)
     if return_hidden:

@@ -34,9 +34,23 @@ from jax.sharding import PartitionSpec as P
 class MoEConfig:
     """``num_experts`` and ``top_k`` are the model's and both paths read
     them; capacity and the loss weights are the trainer's router's alone
-    (serving never drops a token)."""
+    (serving never drops a token). The routing rule, the expert's width
+    and the shared expert are read by the serving expert layer
+    (``models/generate.py:_moe_ffn``) alone."""
     num_experts: int = 8
     top_k: int = 2
+    # how the chosen experts' weights are made: "softmax" over the chosen
+    # logits, or "sigmoid" scores with a selection bias, the chosen scores
+    # normalised and multiplied by ``routed_scale`` (``scoring_func``,
+    # ``topk_method: noaux_tc``, ``routed_scaling_factor``)
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    # an expert's width where it is not the config's ``intermediate_size``
+    # (``moe_intermediate_size`` beside a dense layer's width)
+    expert_size: Optional[int] = None
+    # width of the shared expert every token passes through (0: none;
+    # ``moe_intermediate_size * n_shared_experts``)
+    shared_size: int = 0
     capacity_factor: float = 1.25
     min_capacity: int = 4
     aux_loss_weight: float = 0.01

@@ -593,7 +593,17 @@ class PagedKVCache:
     pages. What walks PAGES to copy a row (prefix hits, drain/restore, the
     fabric's handoff, defrag) would leave its state behind and is refused
     by name: a prefix hit would need a snapshot of the state at the page
-    boundary."""
+    boundary.
+
+    A config with latent attention (``self.latent_layers`` of them) has ONE
+    pool, of latents: ``self.pool`` holds ``c`` ``(layers, pages, page,
+    kv_rank + rope_dim)`` (and ``cs``, a token's two dequant scales, on the
+    int8 tier; ``models/generate.init_paged_cache``) in place of keys and
+    values by head. Everything here deals in page ids and in a pool's
+    arrays whole, so admission, block tables, release, preemption, the
+    prefix trie and its copy-on-write run it as they run a K/V pool; a
+    ``mesh`` is refused by name, since a latent has no head axis to
+    shard."""
 
     def __init__(self, cfg, max_batch: int, max_len: int,
                  page_size: int = 16, num_pages: Optional[int] = None,
@@ -632,6 +642,13 @@ class PagedKVCache:
             ax, tp = None, None
         self.window = (cfg.sliding_window
                        if "sliding" in cfg.period else None)
+        self.latent_layers = cfg.cache_layers().get("latent", 0)
+        if self.latent_layers and mesh is not None:
+            raise ValueError(
+                "PagedKVCache: mesh (a sharded pool) is not supported on a "
+                "config with latent attention: a latent has no head axis "
+                "to shard (heads sharded over a replicated latent is not "
+                "built)")
         self.state_layers = cfg.cache_layers().get("state", 0)
         if self.state_layers:
             for on, what in ((enable_prefix_cache, "enable_prefix_cache "
@@ -1020,6 +1037,17 @@ class PagedKVCache:
                     * np.dtype(self.pool[n].dtype).itemsize
                     for n in STATE_ARRAYS),
                 "ssm_state_resets_total": self.state_resets_total}
+
+    def latent_stats(self) -> Dict[str, int]:
+        """The latent pool's gauges (empty for a config without latent
+        attention): its bytes as the arrays' shapes give them, and the
+        most pages in use at once."""
+        if not self.latent_layers:
+            return {}
+        return {"latent_pool_bytes": sum(
+                    int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                    for a in self.pool.values()),
+                "latent_pool_used_peak": self.allocator.peak_in_use}
 
     def pages_held(self, slot: int) -> List[int]:
         """The page ids ``slot``'s block table currently references

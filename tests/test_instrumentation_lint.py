@@ -50,10 +50,26 @@ def _plant_unfed_counter(tree):
     return "ssm_state_rebuilds_total"
 
 
+def _plant_unfed_latent_counter(tree):
+    (tree / "paddle_tpu/inference/predictor.py").write_text(
+        'count("latent_tokens_attended_total", 1)\n'
+        'count("latent_decode_rows_total")\n')
+    return "latent_chunk_tokens_total"
+
+
+def _plant_lost_latent_scope(tree):
+    (tree / "paddle_tpu/ops/pallas").mkdir(parents=True)
+    (tree / "paddle_tpu/ops/pallas/paged_latent_attention.py").write_text(
+        'name="paged_latent_attention"\n')
+    return 'named_scope("paged_latent_attention")'
+
+
 @pytest.mark.parametrize("rule, plant", [
     ("check_fault_sites", _plant_unthreaded_site),
     ("check_sync_points", _plant_sync_in_dispatch),
-    ("check_hybrid_names", _plant_unfed_counter)])
+    ("check_hybrid_names", _plant_unfed_counter),
+    ("check_hybrid_names", _plant_unfed_latent_counter),
+    ("check_hybrid_names", _plant_lost_latent_scope)])
 def test_checker_flags_a_planted_violation(tmp_path, rule, plant):
     """The lint itself must fail, and name the culprit, when a declared
     site loses its fault_point or a dispatch function reads the device."""

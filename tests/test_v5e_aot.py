@@ -277,6 +277,72 @@ def test_hybrid_serving_programs_fit_one_chip(v5e, program):
         assert m.temp_size_in_bytes < pool_bytes // 8
 
 
+# Kimi-K2-Instruct's published widths at the cut of chipbench/configs/
+# kimi-k2-instruct.json: the leading dense layer and six expert layers, 12 of
+# the router's 384 experts held, an eighth of the vocabulary; batch 32, 7,169
+# pages of 64, 224 pages a row. Weights 9.73 GB, the pool of latents 4.11 GB
+# (576 numbers a token a layer laid out in 640 lanes). As compiled here the
+# decode program's temp is under 0.01 GB (the pool is written where it lies)
+# and the largest chunk program's (context 14,336, width 256) 0.10 GB: 13.94
+# GB of the chip's 15.75.
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_latent_serving_programs_fit_one_chip(v5e, program):
+    from paddle_tpu.models import generate as gen, latent
+    from paddle_tpu.models.moe import MoEConfig
+    cfg = llama.LlamaConfig(
+        vocab_size=20480, hidden_size=7168, intermediate_size=18432,
+        num_layers=7, num_heads=64, num_kv_heads=64, max_seq_len=14336,
+        rope_theta=50000.0, rms_eps=1e-6, dtype=jnp.bfloat16,
+        tie_embeddings=False,
+        yarn=llama.YarnRope(32, 4096, 1, 1, attention_factor=1.0),
+        moe=MoEConfig(num_experts=384, top_k=8, score="sigmoid",
+                      routed_scale=2.827, expert_size=2048, shared_size=2048),
+        layer_pattern=("latent",), dense_layers=1,
+        latent=llama.LatentConfig(q_rank=1536, kv_rank=512, nope_dim=128,
+                                  rope_dim=64, v_dim=128, mscale=1.34657))
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(
+        lambda k: latent.init_params(k, cfg, experts_held=12),
+        jax.random.key(0)))
+    B, page, pps = 32, 64, 224
+    pool = on(jax.eval_shape(lambda: gen.init_paged_cache(cfg, 7169, page)))
+    assert pool["c"].shape == (7, 7169, 64, 640)
+    i32 = jnp.int32
+    if program == "decode":
+        def step(params, last, paged, tables, lengths, active):
+            logits, paged, stats = gen.paged_decode_forward(
+                params, last, paged, tables, lengths, cfg, active=active,
+                use_kernel=True, with_stats=True)
+            return jnp.argmax(logits, -1), paged, stats
+        avals = (_on(d, (B,), i32), pool, _on(d, (B, pps), i32),
+                 _on(d, (B,), i32), _on(d, (B,), jnp.bool_))
+    else:
+        def step(params, toks, paged, table, ctx_len, chunk_len):
+            return gen.paged_prefill_chunk(
+                params, toks, paged, table, cfg, ctx_cap=pps * page,
+                ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=True,
+                with_stats=True)
+        avals = (_on(d, (1, 256), i32), pool, _on(d, (pps,), i32),
+                 _on(d, (), i32), _on(d, (), i32))
+    compiled = _compile(jax.jit(step, donate_argnums=(2,)), params, *avals)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held < 15.75e9
+    text = compiled.as_text()
+    assert text.count("grouped_expert_matmul") >= 3
+    pool_bytes = pool["c"].size * 2
+    if program == "decode":
+        # the prologue's layer and the scanned one: the pool rides through
+        # both whole, written in place
+        assert text.count("paged_latent_attention") >= 2
+        assert m.temp_size_in_bytes < pool_bytes // 8
+    else:
+        # float32 scores of a block of heads, never of all 64
+        assert m.temp_size_in_bytes < 1.5e9
+
+
 def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
     """block_rows=256 x width 4096 needed 19.93 MiB of the 16 MiB scoped
     VMEM, forward and backward; the row block now follows the width."""

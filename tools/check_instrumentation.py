@@ -7,8 +7,8 @@ call in a hot-path module, or the chaos coverage claims sites it never
 exercised. ``check_sync_points``: the scheduler and the engine's
 dispatch-path functions hold no device-to-host read, or the overlapped
 step falls back to a synchronous chain. ``check_hybrid_names``: the
-counters, gauges and named scopes of the recurrent-state pool and the
-expert share are fed where the tracing says. Runs in tier-1
+counters, gauges and named scopes of the recurrent-state pool, the latent
+pool and the expert share are fed where the tracing says. Runs in tier-1
 (tests/test_instrumentation_lint.py); standalone:
 
     python tools/check_instrumentation.py
@@ -171,9 +171,10 @@ def check_sync_points(root: str) -> list:
     return problems
 
 
-#: the names the recurrent-state pool and the expert share are read by
-#: (``engine.stats()``, the device trace): each has to be fed somewhere in
-#: the modules listed, or a per-layer metric reads a total that never moves
+#: the names the recurrent-state pool, the latent pool and the expert share
+#: are read by (``engine.stats()``, the device trace): each has to be fed
+#: somewhere in the modules listed, or a per-layer metric reads a total that
+#: never moves
 _HYBRID_NAMES = {
     "ssm_state_rows_total": "paddle_tpu/inference/predictor.py",
     "ssm_chunk_tokens_total": "paddle_tpu/inference/predictor.py",
@@ -184,12 +185,20 @@ _HYBRID_NAMES = {
     "state_pool_bytes": "paddle_tpu/serving/paged_cache.py",
     'named_scope("ssm_state_update")': "paddle_tpu/ops/pallas/ssm.py",
     'named_scope("ssm_chunk_scan")': "paddle_tpu/models/hybrid.py",
+    "latent_tokens_attended_total": "paddle_tpu/inference/predictor.py",
+    "latent_decode_rows_total": "paddle_tpu/inference/predictor.py",
+    "latent_chunk_tokens_total": "paddle_tpu/inference/predictor.py",
+    "latent_pool_bytes": "paddle_tpu/serving/paged_cache.py",
+    "latent_pool_used_peak": "paddle_tpu/serving/paged_cache.py",
+    'named_scope("paged_latent_attention")':
+        "paddle_tpu/ops/pallas/paged_latent_attention.py",
+    'named_scope("latent_chunk_attention")': "paddle_tpu/models/latent.py",
 }
 
 
 def check_hybrid_names(root: str) -> list:
-    """Counters, gauges and named scopes of the state pool and the expert
-    share exist where the tracing says they are fed."""
+    """Counters, gauges and named scopes of the state pool, the latent pool
+    and the expert share exist where the tracing says they are fed."""
     problems = []
     for name, rel in _HYBRID_NAMES.items():
         path = os.path.join(root, rel)
@@ -216,7 +225,7 @@ def main() -> int:
             print(f"check_instrumentation: {p}", file=sys.stderr)
         return 1
     print("check_instrumentation: fault sites, sync points and the state "
-          "pool's names ok")
+          "and latent pools' names ok")
     return 0
 
 
