@@ -1366,12 +1366,12 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         # unfused one
         fuse = fused and window is None
         h1 = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
-        q = h1 @ _w(lp, "wq", xc.dtype)
-        if ad_l is not None:
-            q = q + _lora_delta(h1, ad_l[0], ad_l[1], aslot, asc)
-        q = q.reshape(B, 1, nh, hd)
-        k = (h1 @ _w(lp, "wk", xc.dtype)).reshape(B, 1, nkv, hd)
-        v = (h1 @ _w(lp, "wv", xc.dtype)).reshape(B, 1, nkv, hd)
+        q = _project_heads(
+            h1, _w(lp, "wq", xc.dtype), nh,
+            None if ad_l is None
+            else _lora_delta(h1, ad_l[0], ad_l[1], aslot, asc))
+        k = _project_heads(h1, _w(lp, "wk", xc.dtype), nkv)
+        v = _project_heads(h1, _w(lp, "wv", xc.dtype), nkv)
         if not fuse:
             # unfused: q rotates here in XLA and round-trips HBM into
             # the attention op; fused moves this rotation into VMEM
@@ -1573,6 +1573,26 @@ def _w(lp, name, dtype):
     return w
 
 
+def _project_heads(x, w, heads: int, delta=None):
+    """Project ``x`` (..., in) through ``w`` (in, heads * d) and split the
+    result into heads: (..., heads, d). ``delta`` (an adapter's term) is
+    added before the split; ``w`` is ``_w``'s result.
+
+    The barrier keeps the split out of the dot, and changes no value.
+    Without it XLA:TPU folds the reshape into the dot, takes the head axis
+    for a convolution's spatial dimension and wants the weight with its
+    contraction dimension minor: the program then cuts the matrix out of
+    the scanned stack, copies it transposed and feeds the dot from the
+    copy in every layer call, or re-lays the whole stack ahead of the
+    loop. Behind the barrier the dot is the plain (rows, in) x (in, out)
+    one that reads its slice of the stack as stored, as ``wo``'s and the
+    MLP's do (``tests/test_v5e_aot.py`` holds the compiled text)."""
+    y = x @ w
+    if delta is not None:
+        y = y + delta
+    return lax.optimization_barrier(y).reshape(x.shape[:-1] + (heads, -1))
+
+
 def _use_decode_kernel(override=None):
     """Pallas decode attention on real TPU; jnp composition elsewhere
     (interpret-mode pallas inside a scan is pointlessly slow on CPU)."""
@@ -1744,12 +1764,12 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
     if tp_axis is not None:
         nh, nkv = _tp_heads(lp, cfg)
     h1 = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = h1 @ _w(lp, "wq", x.dtype)
-    if ad_l is not None:
-        q = q + _lora_delta(h1, ad_l[0], ad_l[1], aslot, ascale)
-    q = q.reshape(B, T, nh, hd)
-    k = (h1 @ _w(lp, "wk", x.dtype)).reshape(B, T, nkv, hd)
-    v = (h1 @ _w(lp, "wv", x.dtype)).reshape(B, T, nkv, hd)
+    q = _project_heads(
+        h1, _w(lp, "wq", x.dtype), nh,
+        None if ad_l is None
+        else _lora_delta(h1, ad_l[0], ad_l[1], aslot, ascale))
+    k = _project_heads(h1, _w(lp, "wk", x.dtype), nkv)
+    v = _project_heads(h1, _w(lp, "wv", x.dtype), nkv)
     if rpos is None:
         q = apply_rope(q, lax.dynamic_slice_in_dim(cos, pos, T),
                        lax.dynamic_slice_in_dim(sin, pos, T))

@@ -314,11 +314,10 @@ def _head(params, x, cfg):
 def _qkv(u, lp, cfg: LlamaConfig, nkv: int):
     """The attention layer's three projections of ``u`` (B, T, h), by
     heads; no rotary embedding is applied to them."""
-    from .generate import _w
-    B, T = u.shape[:2]
-    return ((u @ _w(lp, "wq", u.dtype)).reshape(B, T, cfg.num_heads, cfg.hd),
-            (u @ _w(lp, "wk", u.dtype)).reshape(B, T, nkv, cfg.hd),
-            (u @ _w(lp, "wv", u.dtype)).reshape(B, T, nkv, cfg.hd))
+    from .generate import _project_heads, _w
+    return (_project_heads(u, _w(lp, "wq", u.dtype), cfg.num_heads),
+            _project_heads(u, _w(lp, "wk", u.dtype), nkv),
+            _project_heads(u, _w(lp, "wv", u.dtype), nkv))
 
 
 #: a KV cache's arrays; the scales only on the int8 tier

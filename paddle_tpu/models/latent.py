@@ -153,11 +153,10 @@ def _project(u, lp, cfg: LlamaConfig, cos, sin, rpos):
     """The layer's projections of ``u`` (B, T, h) at rope positions ``rpos``
     (B, T): ``(q_nope (B, T, nh, nope), q_rope (B, T, nh, rope) rotated,
     rows (B, T, kv_rank + rope): the normed latent and the rotated key)``."""
-    from .generate import _rope_rows, _w
+    from .generate import _project_heads, _rope_rows, _w
     la, dt = cfg.latent, u.dtype
-    B, T = u.shape[:2]
     cq = rms_norm(u @ _w(lp, "wq_a", dt), lp["q_norm"], cfg.rms_eps)
-    q = (cq @ _w(lp, "wq_b", dt)).reshape(B, T, cfg.num_heads, la.qk_dim)
+    q = _project_heads(cq, _w(lp, "wq_b", dt), cfg.num_heads)
     kv = u @ _w(lp, "wkv_a", dt)
     c = rms_norm(kv[..., :la.kv_rank], lp["kv_norm"], cfg.rms_eps)
     k_r = _rope_rows(kv[..., None, la.kv_rank:], cos, sin, rpos)[:, :, 0]

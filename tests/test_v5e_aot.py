@@ -9,7 +9,10 @@ there the flash kernel is not eligible and every test takes the jnp path.
 It proves compilation only; a cell of ``python3 -m chipbench.run`` on the
 chip proves the run.
 """
+import json
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -159,14 +162,18 @@ def test_paged_decode_kernel_mellum_widths(v5e, kv, window, layers, pages):
 # before); int8, which no benchmark cell times, 999,936 B / 2.60 GB (2.82 GB
 # before; 0.79 GB with the scale pools carried as (pages, page, heads),
 # which the kernel's wrapper lays out anew in every layer call).
+def _internlm2(num_layers=24):
+    return llama.LlamaConfig(
+        vocab_size=92544, hidden_size=2048, intermediate_size=8192,
+        num_layers=num_layers, num_heads=16, num_kv_heads=8, head_dim=128,
+        max_seq_len=4096, rope_theta=1e6, rms_eps=1e-5, dtype=jnp.bfloat16,
+        tie_embeddings=False)
+
+
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_dense_decode_step_writes_pools_in_place(v5e, kv):
     from paddle_tpu.models import generate as gen
-    cfg = llama.LlamaConfig(
-        vocab_size=92544, hidden_size=2048, intermediate_size=8192,
-        num_layers=24, num_heads=16, num_kv_heads=8, head_dim=128,
-        max_seq_len=4096, rope_theta=1e6, rms_eps=1e-5, dtype=jnp.bfloat16,
-        tie_embeddings=False)
+    cfg = _internlm2()
     d = v5e[0]
     on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
     params = on(jax.eval_shape(lambda k: llama.init_params(k, cfg),
@@ -187,6 +194,87 @@ def test_dense_decode_step_writes_pools_in_place(v5e, kv):
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pools))
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+# The serving programs read the attention projections as the parameter
+# tree stores them (PR 35). A projection whose result is split into heads
+# goes through ``generate._project_heads``; spelled ``(x @ w).reshape(...,
+# heads, d)`` XLA:TPU folds the split into the dot, takes the head axis for
+# a convolution's spatial dimension and wants the weight with its
+# contraction dimension minor: the dense programs then cut ``wq`` / ``wk`` /
+# ``wv`` of the layer out of the stack (three root-level ``dynamic-slice``
+# fusions) and copied each transposed, once a layer a program call, and at
+# Mellum's widths the transposes were hoisted out of the loop and the WHOLE
+# stacks re-laid every call (228 MB of temp at 12 layers). Four layers (one
+# of Mellum's periods) show either; the widths are the cells'. The chunk
+# program's context is 256 tokens, so that the keys and values it gathers
+# for its four layers stay smaller than a layer's ``wk``.
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("widths", ["internlm2", "mellum"])
+def test_serving_programs_read_attention_weights_as_stored(v5e, widths,
+                                                           program):
+    from paddle_tpu.models import generate as gen
+    B, page, pps, i32 = 32, 64, 64, jnp.int32
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    window = widths == "mellum"
+    if window:
+        from chipbench.archs import mellum as arch
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "chipbench", "configs",
+                               "mellum2-12b-a2.5b.json")) as f:
+            c = dict(json.load(f), num_hidden_layers=4)
+        cfg = arch.program_config(c, pps * page)
+        params = on(jax.eval_shape(lambda k: arch.weights(k, c),
+                                   jax.random.key(0)))
+        pools = on(jax.eval_shape(lambda: gen.init_paged_cache(
+            cfg, 801, page, window_pages=1 + B * 21)))
+    else:
+        cfg = _internlm2(4)
+        params = on(jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                                   jax.random.key(0)))
+        pools = on(jax.eval_shape(lambda: gen.init_paged_cache(
+            cfg, 801, page)))
+    # a windowed config also takes its rows' pages in the sliding pool
+    # and hands back the expert layer's counters
+    if program == "decode":
+        def step(params, last, paged, lengths, active, table, wt):
+            kw = {"window_tables": wt, "with_stats": True} if window else {}
+            out = gen.paged_decode_forward(
+                params, last, paged, table, lengths, cfg, active=active,
+                use_kernel=True, **kw)
+            return (jnp.argmax(out[0], -1),) + tuple(out[1:])
+        avals = (_on(d, (B,), i32), pools, _on(d, (B,), i32),
+                 _on(d, (B,), jnp.bool_))
+    else:
+        def step(params, toks, paged, ctx_len, chunk_len, table, wt):
+            kw = ({"window_table": wt[0], "with_stats": True} if window
+                  else {})
+            return gen.paged_prefill_chunk(
+                params, toks, paged, table[0], cfg, ctx_cap=256,
+                ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=True, **kw)
+        avals = (_on(d, (1, 256), i32), pools, _on(d, (), i32),
+                 _on(d, (), i32))
+    avals += (_on(d, (B, pps), i32),) * 2
+    with fa.force_compiled_lowering():
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, *avals).compile()
+    text = compiled.as_text()
+    layers = params["layers"]
+    least = cfg.hidden_size * cfg.num_kv_heads * cfg.hd
+    assert least == math.prod(layers["wk"].shape[1:])
+    moved = [
+        f"%{name} = {dt}[{dims}]" for name, dt, dims in re.findall(
+            r"%((?:copy|\w*dynamic-slice_fusion)[\w.\-]*) = (\w+)"
+            r"\[([\d,]+)\]\S* (?:copy|fusion)\(", text)
+        if dt == "bf16" and math.prod(map(int, dims.split(","))) >= least]
+    assert not moved, moved
+    if program == "decode":
+        assert "paged_attention" in text
+        if widths == "mellum":
+            wq_stack = math.prod(layers["wq"].shape) * 2
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < wq_stack // 10
 
 
 @pytest.mark.parametrize("items", [32 * 8, 256 * 8])
