@@ -61,6 +61,9 @@ from .resilience import DEGRADED_MODES, fault_point
 #: step is pipelined (host_overhead_fraction leaves them out then)
 _HIDDEN_SPANS = ("sched.admit", "sched.plan", "engine.dispatch",
                  "engine.commit")
+#: the span a prefill chunk's commit opens (engine._commit_chunk): a step
+#: in which its count grew committed a chunk's program
+_CHUNK_COMMIT = "engine.wait/chunk"
 
 
 class ServingScheduler:
@@ -143,6 +146,9 @@ class ServingScheduler:
         #: the engine's span totals: the step's phases land beside the
         #: engine's own (dispatch, wait, commit) and come out in stats()
         self.spans = engine.spans
+        for name in ("steps_committing_chunk_total",
+                     "steps_committing_chunk_ns_total"):
+            self.spans.count(name, 0)
         #: host-overhead telemetry mirrors (readable without the
         #: metrics registry): fraction of
         #: the last step's wall time spent on EXPOSED host work (host
@@ -521,6 +527,8 @@ class ServingScheduler:
         sp = self.spans
         step0, wait0 = sp.ns("sched.step"), sp.ns("engine.wait")
         busy0 = sum(sp.ns(n) for n in _HIDDEN_SPANS)
+        chunks0 = sp.calls(_CHUNK_COMMIT)
+        sp.step_begins(self._steps)
         with sp.span("sched.step", step=self._steps):
             committed = 0
             if self.overlap:
@@ -571,6 +579,11 @@ class ServingScheduler:
         # the step less device-wait less what ran under a program in
         # flight, from the span totals: no second set of stamps
         wall = max(1, sp.ns("sched.step") - step0)
+        if sp.calls(_CHUNK_COMMIT) > chunks0:
+            # a pipelined iteration waits for what it commits, so its
+            # wall is the device's time for a step that carried a chunk
+            sp.count("steps_committing_chunk_total", 1)
+            sp.count("steps_committing_chunk_ns_total", wall)
         exposed = max(0, wall - (sp.ns("engine.wait") - wait0)
                       - (sum(sp.ns(n) for n in _HIDDEN_SPANS) - busy0
                          if hidden else 0))
@@ -584,8 +597,10 @@ class ServingScheduler:
             # reports what the step actually consumed, plan + reserve
             plan.scheduled_tokens + plan.reserved_tokens, plan.budget)
         _obs.serving_overlap_step(exposed, wall, committed, self.overlap)
-        return (any(self._queues.values()) or not eng.idle
+        more = (any(self._queues.values()) or not eng.idle
                 or eng.has_inflight())
+        sp.step_ends(more)
+        return more
 
     def _idle_fence(self) -> None:
         """The busy-spin fix (ISSUE 12 satellite): a step that planned

@@ -64,12 +64,27 @@ def _plant_lost_latent_scope(tree):
     return 'named_scope("paged_latent_attention")'
 
 
+def _plant_lost_chunk_step_counter(tree):
+    (tree / "paddle_tpu/serving/scheduler.py").write_text(
+        'sp.count("steps_committing_chunk_total", 1)\n')
+    return "steps_committing_chunk_ns_total"
+
+
+def _plant_lost_stall_total(tree):
+    (tree / "paddle_tpu/observability").mkdir(parents=True)
+    (tree / "paddle_tpu/observability/spans.py").write_text(
+        'self._counters["stalls_total"] += 1\n')
+    return "stall_ns_total"
+
+
 @pytest.mark.parametrize("rule, plant", [
     ("check_fault_sites", _plant_unthreaded_site),
     ("check_sync_points", _plant_sync_in_dispatch),
     ("check_hybrid_names", _plant_unfed_counter),
     ("check_hybrid_names", _plant_unfed_latent_counter),
-    ("check_hybrid_names", _plant_lost_latent_scope)])
+    ("check_hybrid_names", _plant_lost_latent_scope),
+    ("check_hybrid_names", _plant_lost_chunk_step_counter),
+    ("check_hybrid_names", _plant_lost_stall_total)])
 def test_checker_flags_a_planted_violation(tmp_path, rule, plant):
     """The lint itself must fail, and name the culprit, when a declared
     site loses its fault_point or a dispatch function reads the device."""

@@ -4,7 +4,9 @@ profiler's clock read back from a CPU trace, counters worked out by hand,
 and the names as they lower for the TPU."""
 import glob
 import inspect
+import logging
 import re
+import time
 
 import numpy as np
 import pytest
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference.predictor import ContinuousBatchingEngine
 from paddle_tpu.models import llama, train
+from paddle_tpu.observability import spans as spans_mod
 from paddle_tpu.observability.spans import SpanTotals
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import paged_attention as pa
@@ -85,6 +88,218 @@ def test_a_span_that_raises_still_counts():
         with t.span("s"):
             raise KeyError("x")
     assert t.snapshot()["spans"]["s"]["count"] == 1
+
+
+def test_a_names_kinds_sum_to_the_name_exactly_and_keep_their_longest():
+    t = SpanTotals()
+    for kind, n in (("chunk", 3), ("decode", 5), ("swap", 1)):
+        for i in range(n):
+            with t.span("engine.wait", kind=kind):
+                sum(range(100 * (i + 1)))
+    with t.span("engine.commit", rows=2):
+        pass
+    spans = t.snapshot()["spans"]
+    kinds = {k: v for k, v in spans.items() if k.startswith("engine.wait/")}
+    assert sorted(kinds) == ["engine.wait/chunk", "engine.wait/decode",
+                             "engine.wait/swap"]
+    assert kinds["engine.wait/decode"]["count"] == 5
+    for field in ("count", "ns"):
+        assert sum(v[field] for v in kinds.values()) \
+            == spans["engine.wait"][field]
+    assert spans["engine.wait"]["max_ns"] \
+        == max(v["max_ns"] for v in kinds.values())
+    assert [k for k in spans if k.startswith("engine.commit")] \
+        == ["engine.commit"]                    # no kind, no second cell
+    for v in spans.values():
+        assert v["max_ns"] >= v["ns"] / v["count"] > 0
+    assert t.calls("engine.wait/chunk") == 3 and t.calls("never") == 0
+
+
+def _spin_until_cpu(ns):
+    t0 = time.thread_time_ns()
+    while time.thread_time_ns() - t0 < ns:
+        sum(range(1000))
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins"])
+def test_a_stall_record_tells_a_thread_off_its_core_from_one_computing(
+        how, caplog):
+    t = SpanTotals()
+    t.step_begins(7)
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu.serving"):
+        with t.span("sched.step", step=7):
+            with t.span("engine.wait", kind="decode"):
+                if how == "sleeps":
+                    time.sleep(0.15)
+                else:
+                    _spin_until_cpu(150_000_000)
+            with t.span("engine.commit", rows=1):
+                pass
+    st = t.snapshot()
+    rec, = st["stalls"]             # the inner span's, and only that one
+    assert (rec["span"], rec["kind"], rec["step"]) == ("engine.wait",
+                                                       "decode", 7)
+    assert rec["wall_ns"] >= 150_000_000
+    assert st["stalls_total"] == 1 and st["stall_ns_total"] == rec["wall_ns"]
+    assert rec["wall_ns"] == st["spans"]["engine.wait"]["max_ns"]
+    if how == "sleeps":
+        assert rec["cpu_ns"] < rec["wall_ns"] / 10
+        assert rec["voluntary_switches"] >= 1
+    else:       # an absolute reading: other processes share the machine
+        assert rec["cpu_ns"] >= 150_000_000
+    assert rec["involuntary_switches"] >= 0
+    line, = [r.getMessage() for r in caplog.records]
+    assert "engine.wait (decode)" in line and "step 7" in line
+
+
+def test_nothing_is_logged_or_recorded_where_nothing_stalls(caplog):
+    t = SpanTotals()
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu.serving"):
+        for step in range(3):
+            t.step_begins(step)
+            with t.span("sched.step", step=step):
+                with t.span("engine.wait", kind="decode"):
+                    pass
+            t.step_ends(True)
+    st = t.snapshot()
+    assert st["stalls"] == [] and st["stalls_total"] == 0
+    assert st["stall_ns_total"] == 0 and not caplog.records
+
+
+def test_a_long_span_around_a_stall_adds_only_its_own_long_time():
+    t = SpanTotals()
+    with t.span("engine.wait", kind="chunk"):
+        time.sleep(0.12)        # outside a scheduler's step: the engine's
+    assert t.snapshot()["stalls"] == []     # own calls queue and wait
+    t.step_begins(0)
+    with t.span("outer"):
+        with t.span("inner"):
+            time.sleep(0.12)
+    assert [r["span"] for r in t.snapshot()["stalls"]] == ["inner"]
+    with t.span("outer"):
+        with t.span("inner"):
+            time.sleep(0.12)
+        time.sleep(0.12)
+    recs = t.snapshot()["stalls"]
+    assert [r["span"] for r in recs] == ["inner", "inner", "outer"]
+    # the outer record holds its own time, not the inner stall's again
+    assert 120_000_000 <= recs[2]["wall_ns"] < 200_000_000
+    assert t.snapshot()["stall_ns_total"] == sum(r["wall_ns"] for r in recs)
+
+
+def test_a_build_is_no_stall_and_is_taken_out_of_the_spans_around_it():
+    t = SpanTotals()
+    t.step_begins(0)
+    with t.span("sched.step", step=0):
+        with t.span("engine.dispatch", kind="chunk"):
+            with t.span("engine.build_program", kind="chunk", ctx_cap=8,
+                        width=16):
+                time.sleep(0.12)
+            with t.span("engine.build_program", kind="decode"):
+                pass                # a short one still counts for nothing
+    st = t.snapshot()
+    assert st["stalls"] == [] and st["stalls_total"] == 0
+    assert st["spans"]["engine.build_program/chunk"]["count"] == 1
+    assert st["spans"]["sched.step"]["max_ns"] >= 120_000_000
+
+
+def test_the_stall_list_is_bounded_and_the_totals_keep_counting(monkeypatch):
+    monkeypatch.setattr(spans_mod, "STALL_NS", 200_000)
+    t = SpanTotals()
+    for step in range(40):
+        t.step_begins(step)
+        with t.span("engine.wait", kind="decode"):
+            time.sleep(0.0005)
+    st = t.snapshot()
+    assert st["stalls_total"] == 40
+    assert len(st["stalls"]) == spans_mod.STALLS_KEPT == 32
+    assert [r["step"] for r in st["stalls"]] == list(range(8, 40))
+    assert st["stall_ns_total"] >= sum(r["wall_ns"] for r in st["stalls"])
+
+
+def test_the_threads_clock_is_sampled_once_in_a_while_not_every_step(
+        monkeypatch):
+    reads = []
+    sample = spans_mod._thread_sample
+    monkeypatch.setattr(spans_mod, "_thread_sample",
+                        lambda: reads.append(1) or sample())
+    t = SpanTotals()
+    assert len(reads) == 1                      # at construction
+    for step in range(50):
+        t.step_begins(step)
+        with t.span("sched.step", step=step):
+            pass
+        t.step_ends(True)
+    assert len(reads) == 1                      # 50 steps in under 50 ms
+    monkeypatch.setattr(spans_mod, "SAMPLE_NS", 0)
+    t.step_begins(50)
+    t.step_begins(51)
+    assert len(reads) == 3
+    monkeypatch.setattr(spans_mod, "STALL_NS", 100_000)
+    with t.span("engine.wait", kind="decode"):  # inside step 51
+        time.sleep(0.001)
+    rec, = t.snapshot()["stalls"]               # a stall's exit samples too
+    assert len(reads) == 4
+    # what the CPU reading covers: from step 51's sample to the stall's exit
+    assert rec["wall_ns"] <= rec["sampled_ns"] < rec["wall_ns"] + 50_000_000
+
+
+@pytest.mark.parametrize("more", [True, False])
+def test_a_pause_between_two_steps_is_a_record_while_work_remained(more):
+    t = SpanTotals()
+    t.step_begins(4)
+    with t.span("sched.step", step=4):
+        pass
+    t.step_ends(more)
+    time.sleep(0.12)
+    t.step_begins(5)
+    recs = t.snapshot()["stalls"]
+    if not more:
+        assert recs == []
+        return
+    rec, = recs
+    assert (rec["span"], rec["kind"], rec["step"]) == ("between_steps",
+                                                       None, 4)
+    assert rec["wall_ns"] >= 120_000_000 > 10 * rec["cpu_ns"]
+    # the record lies between the two steps on the spans' clock
+    assert rec["start_ns"] + rec["wall_ns"] <= time.perf_counter_ns()
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_step_that_commits_a_chunk_is_counted_with_its_wall(tiny, overlap):
+    """A prompt of 37 tokens is three chunks of 16; each is committed by
+    one step, the decode steps after them by none."""
+    s = scheduler(tiny, overlap=overlap)
+    s.submit(prompt(tiny[0], 37, 8), max_new_tokens=6)
+    seen = []
+    more = True
+    while more:
+        a = s.stats()
+        more = s.step()
+        b = s.stats()
+        grew = {k: b[k] - a[k] for k in ("steps_committing_chunk_total",
+                                         "steps_committing_chunk_ns_total")}
+        chunk = (b["spans"].get("engine.wait/chunk", {"count": 0})["count"]
+                 - a["spans"].get("engine.wait/chunk", {"count": 0})["count"])
+        wall = span_ns(b, "sched.step") - a["spans"].get(
+            "sched.step", {"ns": 0})["ns"]
+        seen.append(bool(chunk))
+        if chunk:
+            assert grew == {"steps_committing_chunk_total": 1,
+                            "steps_committing_chunk_ns_total": wall}
+        else:
+            assert not any(grew.values())
+    st = s.stats()
+    assert st["steps_committing_chunk_total"] == sum(seen) == 3
+    assert len(seen) - sum(seen) >= 5           # the decode-only steps
+    assert st["steps_committing_chunk_ns_total"] < span_ns(st, "sched.step")
+    for name in ("engine.dispatch", "engine.wait", "engine.build_program"):
+        kinds = [v for k, v in st["spans"].items()
+                 if k.startswith(name + "/")]
+        assert len(kinds) == 2                  # chunk and decode
+        for field in ("count", "ns"):
+            assert sum(v[field] for v in kinds) == st["spans"][name][field]
+    assert st["stalls_total"] == len(st["stalls"])
 
 
 def test_the_steps_spans_cover_the_step(tiny):

@@ -8,7 +8,8 @@ exercised. ``check_sync_points``: the scheduler and the engine's
 dispatch-path functions hold no device-to-host read, or the overlapped
 step falls back to a synchronous chain. ``check_hybrid_names``: the
 counters, gauges and named scopes of the recurrent-state pool, the latent
-pool and the expert share are fed where the tracing says. Runs in tier-1
+pool and the expert share, the chunk-step counters and the stall totals
+are fed where the tracing says. Runs in tier-1
 (tests/test_instrumentation_lint.py); standalone:
 
     python tools/check_instrumentation.py
@@ -193,6 +194,11 @@ _HYBRID_NAMES = {
     'named_scope("paged_latent_attention")':
         "paddle_tpu/ops/pallas/paged_latent_attention.py",
     'named_scope("latent_chunk_attention")': "paddle_tpu/models/latent.py",
+    # the steps that committed a chunk and the stall totals (PR 36)
+    "steps_committing_chunk_total": "paddle_tpu/serving/scheduler.py",
+    "steps_committing_chunk_ns_total": "paddle_tpu/serving/scheduler.py",
+    "stalls_total": "paddle_tpu/observability/spans.py",
+    "stall_ns_total": "paddle_tpu/observability/spans.py",
 }
 
 
