@@ -645,6 +645,21 @@ class ContinuousBatchingEngine:
                         f"ContinuousBatchingEngine: {what} is not "
                         f"supported on a config with latent attention (a "
                         f"pool of latents with no head axis)")
+        # --- a K/V decoder with more than the plain layer's parts (a dense
+        # prologue, an output gate, QK-norm, NoPE layers, sandwich norms,
+        # an input scale: cfg.kv_parts()) is served by one chip's unfused
+        # programs; what would shard them, fuse them, add adapter terms
+        # or draft from a truncation of its layer stack refuses here
+        parts = cfg.kv_parts()
+        if parts:
+            for on, what in ((mesh is not None, "mesh"),
+                             (adapters is not None, "adapters"),
+                             (fused, "fused"),
+                             (draft_layers, "draft_layers")):
+                if on:
+                    raise ValueError(
+                        f"ContinuousBatchingEngine: {what} is not "
+                        f"supported on a config with {', '.join(parts)}")
         self.mesh = mesh
         self._tp = None
         self._tp_axis = None
@@ -838,6 +853,18 @@ class ContinuousBatchingEngine:
                          "latent_decode_rows_total",
                          "latent_chunk_tokens_total",
                          "latent_chunk_keys_attended_total"):
+                self.spans.count(name, 0)
+        # --- keys attended by kind (a config with sliding layers): the
+        # (query, key) pairs of a program's live rows, a window at most
+        # in a sliding layer, times the layers of the kind; known at
+        # dispatch from the lengths
+        self._kind_layers = {k: cfg.cache_layers().get(k, 0)
+                             for k in ("sliding", "full")}
+        if self.cache.window:
+            for name in ("window_keys_attended_total",
+                         "full_keys_attended_total",
+                         "chunk_window_keys_attended_total",
+                         "chunk_full_keys_attended_total"):
                 self.spans.count(name, 0)
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
         # supervisor; -1 renders as the "router" lane in exports
@@ -1785,6 +1812,10 @@ class ContinuousBatchingEngine:
                 # the pools; tokens through the chunked scan, a layer
                 args += [jnp.int32(slot)]
                 self.spans.count("ssm_chunk_tokens_total", take)
+            if cache.window:
+                # token i of the chunk sees done + i + 1 positions
+                self._count_keys("chunk_", np.arange(done + 1,
+                                                     done + take + 1))
             if self._latent_layers:
                 # tokens whose latents this chunk writes, a layer, and the
                 # (query, key) pairs its valid tokens attend: token i sees
@@ -1860,6 +1891,18 @@ class ContinuousBatchingEngine:
                  "logits": logits, "samp": samp, "rawmax": rawmax,
                  "ttr": _obs.serving_trace_now()})
         return width
+
+    def _count_keys(self, prefix: str, seen):
+        """Book the keys a program's queries read, ``seen`` (n,) the
+        positions each query sees: ``min(seen, window)`` a sliding layer,
+        ``seen`` a full one, times the layers of the kind."""
+        seen = np.asarray(seen, np.int64)
+        self.spans.count(
+            prefix + "window_keys_attended_total",
+            int(np.minimum(seen, self.cache.window).sum())
+            * self._kind_layers["sliding"])
+        self.spans.count(prefix + "full_keys_attended_total",
+                         int(seen.sum()) * self._kind_layers["full"])
 
     def _launched_handle(self, h):
         """Queue the handle of a program just launched, in launch
@@ -2288,6 +2331,9 @@ class ContinuousBatchingEngine:
             if cache.window:
                 self.spans.count("window_pages_released_total",
                                  cache.window_step(slots))
+                # a row's query sees its length's positions, the new one
+                # among them
+                self._count_keys("", cache.lengths[slots])
             if self._state_layers:
                 # row-layers of recurrent state this program advances
                 self.spans.count("ssm_state_rows_total",

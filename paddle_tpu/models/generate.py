@@ -621,6 +621,12 @@ def _layer_feed(tree, plen: int):
                                            keepdims=False), tree)
 
 
+def _no_stats(layers: Dict):
+    """What a layer tree's ``_moe_ffn`` stats start from: four counts where
+    it holds a share of the experts (:func:`_held_items`), else three."""
+    return jnp.zeros((4 if "first_expert" in layers else 3,), jnp.int32)
+
+
 def _split_experts(layers: Dict):
     """A layer tree as (what the layer scan slices, the three expert
     stacks whole or None): :func:`_expert_apply` reads a layer's experts
@@ -639,6 +645,19 @@ def _refuse_latent(cfg: LlamaConfig, what: str):
             f"with no head axis); only paged_prefill_chunk and "
             f"paged_decode_forward serve it, and llama.forward runs it "
             f"without a cache")
+
+
+def _refuse_parts(cfg: LlamaConfig, **on):
+    """What a K/V decoder with more than the plain layer's parts
+    (:meth:`LlamaConfig.kv_parts`) is not served with: one chip's programs,
+    unfused, with no adapters."""
+    parts = cfg.kv_parts()
+    for name, value in on.items():
+        if value and parts:
+            raise ValueError(
+                f"{name} is not supported on a config with "
+                f"{', '.join(parts)} (one chip's unfused programs, no "
+                f"adapters)")
 
 
 def _refuse_sliding(cfg: LlamaConfig, what: str):
@@ -814,6 +833,8 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     if B != 1:
         raise ValueError(
             f"paged_prefill_chunk: one request at a time (got batch {B})")
+    _refuse_parts(cfg, tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                  adapters=adapters)
     if cfg.latent is not None:
         # the chunk's latents go straight into the pool and its queries
         # attend over the row's pages: no temp cache is gathered
@@ -835,7 +856,7 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     ctx_len = jnp.asarray(ctx_len, jnp.int32).reshape(())
     chunk_len = jnp.asarray(chunk_len, jnp.int32).reshape(())
     pad = ctx_cap - ctx_len                       # garbage rows below
-    sliding = "sliding" in cfg.period
+    sliding = "sliding" in cfg.cache_layers()
     # context rows a sliding layer's first chunk query can see: one
     # window less itself, in whole pages, never more than the context
     wcap = (min(ctx_cap, -(-(cfg.sliding_window - 1) // page) * page)
@@ -979,6 +1000,8 @@ def paged_verify_forward(params, tokens: jax.Array, paged: Dict,
     reference), so rejection needs no rollback at all."""
     B, T = tokens.shape
     _refuse_sliding(cfg, "paged_verify_forward")
+    _refuse_parts(cfg, tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                  adapters=adapters)
     tree = tree_depth is not None
     if tree and tree_mask is None:
         raise ValueError("paged_verify_forward: tree_depth requires "
@@ -1285,7 +1308,13 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     reference's ``window``). The fused decode kernel has no window, so
     under ``fused`` those layers take the unfused call.
     ``with_stats``: also return the ``_moe_ffn`` stats summed over
-    layers, counted over the ``active`` rows."""
+    layers, counted over the ``active`` rows.
+
+    Leading dense layers (``cfg.prologue``) run one by one before the scan
+    over periods, each on its kind's pool from the first layer on; the
+    scanned layers' pools follow theirs."""
+    _refuse_parts(cfg, tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
+                  adapters=adapters)
     if cfg.hybrid is not None:
         from . import hybrid as _hybrid
         _hybrid.refuse(tp_axis=tp_axis, dp_axis=dp_axis, fused=fused,
@@ -1318,7 +1347,8 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         aslot, asc = _adapter_prep(adapters, adapter_slots, cfg)
     period = cfg.period
     plen = len(period)
-    kinds = tuple(dict.fromkeys(period))
+    pro = cfg.prologue
+    kinds = tuple(dict.fromkeys(pro + period))
     if "sliding" in kinds and window_tables is None:
         raise ValueError(
             "paged_decode_forward: the config has sliding layers; "
@@ -1349,8 +1379,7 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
             # layer's scatter
             dst = _tp_allgather(dst, dp_axis, 0)
         dsts[kind] = dst
-    x = jnp.take(params["embed"], tokens[:, None], axis=0).astype(
-        cfg.dtype)                                   # (B, 1, H)
+    x = llama.embed_rows(params, tokens[:, None], cfg)   # (B, 1, H)
     names = ("k", "v", "ks", "vs") if quant else ("k", "v")
     # where in its kind's slice of a period a layer's pool sits
     slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
@@ -1372,16 +1401,21 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
             else _lora_delta(h1, ad_l[0], ad_l[1], aslot, asc))
         k = _project_heads(h1, _w(lp, "wk", xc.dtype), nkv)
         v = _project_heads(h1, _w(lp, "wv", xc.dtype), nkv)
-        if not fuse:
-            # unfused: q rotates here in XLA and round-trips HBM into
-            # the attention op; fused moves this rotation into VMEM
-            q = _rope_rows(q, cos, sin, rpos)
-        k = _rope_rows(k, cos, sin, rpos)
+        if cfg.qk_norm:
+            q, k = _qk_norm(q, k, lp, cfg)
+        if cos is not None:
+            if not fuse:
+                # unfused: q rotates here in XLA and round-trips HBM into
+                # the attention op; fused moves this rotation into VMEM
+                q = _rope_rows(q, cos, sin, rpos)
+            k = _rope_rows(k, cos, sin, rpos)
 
         o, kp, vp, ksp, vsp = _paged_kv_attend(
             q, k, v, (kp, vp, ksp, vsp), dst, table, lengths, page,
             window=window, rope_row=rope_row[kind] if fuse else None,
             use_kernel=use_kernel, dp_axis=dp_axis, dtype=cfg.dtype)
+        if cfg.attn_gate:
+            o = _gate(o[:, None], h1, lp, nh)
         o = o.reshape(B, 1, nh * hd)
         if tp_axis is not None:
             o = _tp_allgather(o, tp_axis, 2)
@@ -1393,15 +1427,16 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         if tp_axis is not None:
             xo = xc + _tp_allgather(ow, tp_axis, 2)
         else:
-            xo = xc + ow
+            xo = xc + _post_norm(ow, lp, "post_attn_norm", cfg)
         h2 = rms_norm(xo, lp["mlp_norm"], cfg.rms_eps)
         stats = None
-        if cfg.moe is not None:
+        if cfg.moe is not None and "wg" not in lp:
+            # (a leading dense layer of an expert model has "wg")
             ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
                                  dp_axis=dp_axis, valid=active[:, None],
                                  experts=experts, layer=at_layer,
                                  use_kernel=use_kernel)
-            y = xo + ff
+            y = xo + _post_norm(ff, lp, "post_mlp_norm", cfg)
         else:
             g = jax.nn.silu((h2 @ _w(lp, "wg", xc.dtype)).astype(
                 jnp.float32)).astype(xc.dtype)
@@ -1411,7 +1446,8 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                 y = xo + _tp_allgather(gu @ _w(lp, "wd", xc.dtype),
                                        tp_axis, 2)
             else:
-                y = xo + (g * u) @ _w(lp, "wd", xc.dtype)
+                y = xo + _post_norm((g * u) @ _w(lp, "wd", xc.dtype), lp,
+                                    "post_mlp_norm", cfg)
         return y, dict(zip(names, (kp, vp, ksp, vsp))), stats
 
     scanned, experts = _split_experts(params["layers"])
@@ -1420,7 +1456,7 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     ads, take_ad = _layer_feed(
         None if adapters is None else tuple(
             adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
-    index = jnp.arange(cfg.num_layers // plen, dtype=jnp.int32)
+    index = jnp.arange(cfg.periods, dtype=jnp.int32)
     # The pools ride in the scan's carry WHOLE: a layer writes its rows and
     # the kernel reads its pages where they lie, by block tables moved up
     # to the layer's first page, and no slice of a pool is copied out or
@@ -1428,17 +1464,20 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     # of its own over one layer body; a plain decoder's period is one
     # layer, and the scan over periods is its layer loop.
     pool_pages = {kind: d["k"].shape[1] for kind, d in pools.items()}
-    zero = jnp.zeros((3,), jnp.int32) if cfg.moe is not None else None
+    zero = _no_stats(scanned) if cfg.moe is not None else None
 
     def body(carry, xs):
         lps_i, ads_i, i = xs
 
         def one(carry, j, kind, first):
             # layer j of the period; ``first``: the period's first layer
-            # of this run, at slot ``slot_of[first]`` of its kind
+            # of this run, at slot ``slot_of[first]`` of its kind, after
+            # the prologue's layers of that kind
             xc, held, stats = carry
             base = ((i * period.count(kind) + slot_of[first]
                      + (j - first)) * pool_pages[kind])
+            if pro.count(kind):
+                base = base + pro.count(kind) * pool_pages[kind]
             xc, out, st = layer(
                 xc, take_lp(lps_i, i, j), kind, held[kind],
                 take_ad(ads_i, i, j), i * plen + j, base)
@@ -1460,6 +1499,11 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     flat = {kind: {n: a.reshape((-1, 1, a.shape[2] * a.shape[3])
                                 if a.ndim == 4 else (-1,) + a.shape[2:])
                    for n, a in d.items()} for kind, d in pools.items()}
+    for j, kind in enumerate(pro):
+        x, out, _ = layer(
+            x, jax.tree.map(lambda a: a[j], params["dense_layers"]), kind,
+            flat[kind], None, None, pro[:j].count(kind) * pool_pages[kind])
+        flat = {**flat, kind: out}
     (x, new, stats), _ = lax.scan(body, (x, flat, zero), (lps, ads, index))
     new_paged = {n + KIND_SUFFIX[kind]: a.reshape(
         paged[n + KIND_SUFFIX[kind]].shape)
@@ -1548,8 +1592,13 @@ def quantize_weights(params, cfg: LlamaConfig, bits: int = 8,
             "mamba2": stacks(params["layers"]["mamba2"],
                              ("w_in", "w_out"))}
     else:
-        out["layers"] = stacks(params["layers"],
-                               ("wq", "wk", "wv", "wo", "wg", "wu", "wd"))
+        # the attention projections (and its output gate), the dense and
+        # the shared FFN; the expert stacks stay as they are
+        names = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "wgate",
+                 "ws_g", "ws_u", "ws_d")
+        for group in ("layers", "dense_layers"):
+            if group in params:
+                out[group] = stacks(params[group], names)
     if not cfg.tie_embeddings and "lm_head" in params:
         qw, sc = q(params["lm_head"])
         out["lm_head"] = qw
@@ -1591,6 +1640,39 @@ def _project_heads(x, w, heads: int, delta=None):
     if delta is not None:
         y = y + delta
     return lax.optimization_barrier(y).reshape(x.shape[:-1] + (heads, -1))
+
+
+def _qk_norm(q, k, lp, cfg: LlamaConfig):
+    """QK-norm: each query and key head RMS-normed over its lanes, one gain
+    a layer for all heads (``q_norm`` / ``k_norm``), before rotary and
+    before the keys go into the cache."""
+    return (rms_norm(q, lp["q_norm"], cfg.rms_eps),
+            rms_norm(k, lp["k_norm"], cfg.rms_eps))
+
+
+def _gate(o, u, lp, heads: int):
+    """The attention's output gate on the heads' results ``o`` (..., heads,
+    d): times ``sigmoid(u W_gate)`` elementwise, in float32, ``u`` the
+    layer's normed input; ``W_gate`` projects like ``W_q``
+    (:func:`_project_heads`)."""
+    with jax.named_scope("attention_output_gate"):
+        g = _project_heads(u, _w(lp, "wgate", u.dtype), heads)
+        return (o.astype(jnp.float32)
+                * jax.nn.sigmoid(g.astype(jnp.float32))).astype(o.dtype)
+
+
+def _post_norm(y, lp, name: str, cfg: LlamaConfig):
+    """A sandwich norm on a part's output before its residual add, where
+    the config has them; ``y`` itself where not."""
+    if not cfg.sandwich_norms:
+        return y
+    return rms_norm(y, lp[name], cfg.rms_eps)
+
+
+#: the float32 score matrix of a multi-token attention above which
+#: :func:`_attn_with_cache` takes one key head's group at a time (a chunk
+#: of 256 over a 16k context at 48 heads would hold 0.8 GB)
+SCORES_WHOLE_BYTES = 512 << 20
 
 
 def _use_decode_kernel(override=None):
@@ -1662,6 +1744,21 @@ def _attn_with_cache(q, ck, cv, length, nh, use_kernel=None,
         ck = (ck.astype(jnp.float32) * k_rows[..., None]).astype(q.dtype)
         cv = (cv.astype(jnp.float32) * v_rows[..., None]).astype(q.dtype)
     nkv = ck.shape[2]
+    if (T > 1 and nkv > 1 and tree_mask is None
+            and B * nh * T * ck.shape[1] * 4 > SCORES_WHOLE_BYTES):
+        # one key head's group of query heads at a time: the float32
+        # scores of all heads of a long chunk would not fit beside a
+        # chip's weights and pools
+        rep = nh // nkv
+        qg = q.reshape(B, T, nkv, rep, hd)
+
+        def group(g):
+            cut = lambda a: lax.dynamic_slice_in_dim(a, g, 1, axis=2)
+            return _attn_with_cache(
+                lax.dynamic_index_in_dim(qg, g, 2, keepdims=False),
+                cut(ck), cut(cv), length, rep, kstart=kstart, window=window)
+        o = lax.map(group, jnp.arange(nkv))          # (nkv, B, T, rep, hd)
+        return jnp.moveaxis(o, 0, 2).reshape(B, T, nh, hd)
     if nkv != nh:
         ck = jnp.repeat(ck, nh // nkv, axis=2)
         cv = jnp.repeat(cv, nh // nkv, axis=2)
@@ -1770,7 +1867,11 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
         else _lora_delta(h1, ad_l[0], ad_l[1], aslot, ascale))
     k = _project_heads(h1, _w(lp, "wk", x.dtype), nkv)
     v = _project_heads(h1, _w(lp, "wv", x.dtype), nkv)
-    if rpos is None:
+    if cfg.qk_norm:
+        q, k = _qk_norm(q, k, lp, cfg)
+    if cos is None:
+        pass                                     # no rotary embedding
+    elif rpos is None:
         q = apply_rope(q, lax.dynamic_slice_in_dim(cos, pos, T),
                        lax.dynamic_slice_in_dim(sin, pos, T))
         k = apply_rope(k, lax.dynamic_slice_in_dim(cos, pos, T),
@@ -1786,6 +1887,8 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
                          k_rows=cache_ks if quant else None,
                          v_rows=cache_vs if quant else None,
                          fused=fused, tree_mask=tree_mask, window=window)
+    if cfg.attn_gate:
+        o = _gate(o, h1, lp, nh)
     o = o.reshape(B, T, nh * hd)
     if tp_axis is not None:
         # full heads before the (column-sharded) wo contraction, then
@@ -1797,15 +1900,16 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
     if tp_axis is not None:
         x = x + _tp_allgather(ow, tp_axis, 2)
     else:
-        x = x + ow
+        x = x + _post_norm(ow, lp, "post_attn_norm", cfg)
     h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.moe is not None:
+    if cfg.moe is not None and "wg" not in lp:
         # serving MoE FFN (ISSUE 17): dense-dispatch on a single chip,
         # expert-parallel over dp when the stacks arrive E-sharded
         ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
                              dp_axis=dp_axis, valid=moe_valid,
                              experts=experts, layer=layer,
                              use_kernel=use_kernel)
+        ff = _post_norm(ff, lp, "post_mlp_norm", cfg)
         return x + ff, cache_k, cache_v, cache_ks, cache_vs, stats
     g = jax.nn.silu((h2 @ _w(lp, "wg", x.dtype)).astype(
         jnp.float32)).astype(x.dtype)
@@ -1814,8 +1918,8 @@ def _block_infer(x, lp, cache_k, cache_v, pos, cos, sin, cfg: LlamaConfig,
         gu = _tp_allgather(g * u, tp_axis, 2)
         ff = _tp_allgather(gu @ _w(lp, "wd", x.dtype), tp_axis, 2)
         return x + ff, cache_k, cache_v, cache_ks, cache_vs, None
-    return (x + (g * u) @ _w(lp, "wd", x.dtype), cache_k, cache_v,
-            cache_ks, cache_vs, None)
+    ff = _post_norm((g * u) @ _w(lp, "wd", x.dtype), lp, "post_mlp_norm", cfg)
+    return (x + ff, cache_k, cache_v, cache_ks, cache_vs, None)
 
 
 def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
@@ -1842,11 +1946,14 @@ def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
     from ``pos`` / ``kstart`` (the chunk program's narrower window
     cache); rope positions are the same for both. ``with_stats``: a
     third result, the ``_moe_ffn`` stats summed over layers, counted
-    over the rows ``moe_valid`` marks."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    over the rows ``moe_valid`` marks.
+
+    Leading dense layers (``cfg.prologue``) run one by one before the scan,
+    each against its kind's arrays from the first on."""
+    x = llama.embed_rows(params, tokens, cfg)
     period = cfg.period
     plen = len(period)
-    layers_n = cfg.num_layers
+    pro = cfg.prologue
     kinds = tuple(dict.fromkeys(period))
     rope = llama.rope_tables_by_kind(cfg, max_len)
     at = {"full": (pos, kstart),
@@ -1898,7 +2005,7 @@ def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
                     (xc, st), out = lax.scan(
                         step,
                         (xc, None if cfg.moe is None
-                         else jnp.zeros((3,), jnp.int32)),
+                         else _no_stats(scanned)),
                         (first + jnp.arange(count, dtype=jnp.int32), mine))
                 for n in names:
                     new[kind][n].append(out[n])
@@ -1916,18 +2023,37 @@ def _forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
     ads, take_ad = _layer_feed(
         None if adapters is None else tuple(
             adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
+    arrays = {kind: _of_kind(cache, kind)
+              for kind in dict.fromkeys(pro + period)}
+    lead = {}                     # kind -> the prologue's rows, in order
+    for j, kind in enumerate(pro):
+        c = {n: a[pro[:j].count(kind)] for n, a in arrays[kind].items()}
+        x, *out, _ = _block_infer(
+            x, jax.tree.map(lambda a: a[j], params["dense_layers"]),
+            c["k"], c["v"], at[kind][0], *rope[kind], cfg,
+            use_kernel=use_kernel, rpos=rpos, kstart=at[kind][1],
+            cache_ks=c.get("ks"), cache_vs=c.get("vs"),
+            window=cfg.window_of(kind))
+        lead.setdefault(kind, []).append(dict(zip(names, out)))
     xs = (lps,
-          {kind: {n: stacked(kind, a)
-                  for n, a in _of_kind(cache, kind).items()}
+          {kind: {n: stacked(kind, a[pro.count(kind):] if pro else a)
+                  for n, a in arrays[kind].items()}
            for kind in kinds},
-          ads, jnp.arange(layers_n // plen, dtype=jnp.int32))
+          ads, jnp.arange(cfg.periods, dtype=jnp.int32))
     x, new = lax.scan(body, x, xs)
     stats = None
     if cfg.moe is not None:
         new, stats = new[0], jnp.sum(new[1], axis=0)
-    new_cache = {n + KIND_SUFFIX[kind]: a.reshape(
-        cache[n + KIND_SUFFIX[kind]].shape)
-        for kind in kinds for n, a in new[kind].items()}
+    new_cache = {}
+    for kind in arrays:
+        for n in arrays[kind]:
+            rows = [r[n][None] for r in lead.get(kind, [])]
+            if kind in new:
+                rows.append(new[kind][n].reshape(
+                    (-1,) + arrays[kind][n].shape[1:]))
+            new_cache[n + KIND_SUFFIX[kind]] = (
+                rows[0] if len(rows) == 1 else jnp.concatenate(rows)
+            ).reshape(cache[n + KIND_SUFFIX[kind]].shape)
     extra = (stats,) if with_stats else ()
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if logits_at is not None:

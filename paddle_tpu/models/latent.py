@@ -58,8 +58,6 @@ from ..ops.pallas.paged_latent_attention import (latent_chunk_attention,
 #: the matrices ``generate.quantize_weights`` takes (expert stacks stay)
 QUANT_LEAVES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "wg", "wu", "wd",
                 "ws_g", "ws_u", "ws_d")
-#: leaves kept in float32 whatever the model's dtype
-F32_LEAVES = ("moe_gate", "moe_bias")
 
 
 def refuse(**on):
@@ -125,23 +123,8 @@ def param_shapes(cfg: LlamaConfig, experts_held=None) -> Dict:
 
 def init_params(key: jax.Array, cfg: LlamaConfig, experts_held=None,
                 first_expert: int = 0) -> Dict:
-    is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
-    with_paths, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg, experts_held), is_leaf=is_leaf)
-    out = []
-    for k, (path, (shape, kind)) in zip(
-            jax.random.split(key, len(with_paths)), with_paths):
-        dtype = jnp.float32 if path[-1].key in F32_LEAVES else cfg.dtype
-        if kind == "norm":
-            out.append(jnp.ones(shape, dtype))
-        elif kind == "first_expert":
-            out.append(jnp.full(shape, first_expert, jnp.int32))
-        else:
-            n = jax.random.normal(k, shape, jnp.float32)
-            scale = {"embed": 0.02, "router_bias": 0.01}.get(kind) \
-                or kind ** -0.5
-            out.append((n * scale).astype(dtype))
-    return jax.tree.unflatten(treedef, out)
+    return llama._init_from_shapes(key, cfg, param_shapes(cfg, experts_held),
+                                   first_expert)
 
 
 # ---------------- the attention layer's parts ----------------

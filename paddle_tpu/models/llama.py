@@ -188,10 +188,28 @@ class LlamaConfig:
     # forward and the serving layer bodies are models/latent.py's
     latent: Optional[LatentConfig] = None
     # leading layers whose MLP is dense at ``intermediate_size`` although
-    # ``moe`` is set (``first_k_dense_replace``): their weights are a stack
-    # of their own, ``params["dense_layers"]``, run as a prologue before the
-    # scan over the expert layers ``params["layers"]``
+    # ``moe`` is set (``first_k_dense_replace``, ``num_dense_layers``):
+    # their weights are a stack of their own, ``params["dense_layers"]``,
+    # run as a prologue before the scan over the expert layers
+    # ``params["layers"]``, whose periods follow ``layer_pattern``
     dense_layers: int = 0
+    # the attention kinds of a K/V decoder's leading dense layers, one a
+    # layer, in order (a latent-attention config's are all "latent")
+    dense_kinds: Tuple[str, ...] = ()
+    # the attention kinds that apply no rotary embedding (NoPE)
+    nope_kinds: Tuple[str, ...] = ()
+    # RMSNorm of each query and key head over its ``hd`` lanes before
+    # rotary, one gain a layer shared by the heads: ``q_norm`` / ``k_norm``
+    qk_norm: bool = False
+    # the attention's output gate: the heads' results times
+    # ``sigmoid(u W_gate)`` elementwise before ``W_o``, ``u`` the layer's
+    # normed input and ``W_gate`` (hidden, heads * hd) the leaf ``wgate``
+    attn_gate: bool = False
+    # sandwich norms: RMSNorm of the attention's and of the MLP's output
+    # before each residual add (``post_attn_norm`` / ``post_mlp_norm``)
+    sandwich_norms: bool = False
+    # what the embedding rows are multiplied by (muP's input scale)
+    embed_scale: Optional[float] = None
 
     def __post_init__(self):
         pat = self.layer_pattern
@@ -204,13 +222,32 @@ class LlamaConfig:
             raise ValueError("latent attention: moe must describe the "
                              "expert layers (models/latent.py)")
         if self.dense_layers and (
-                self.latent is None or self.moe is None
+                self.moe is None or self.hybrid is not None
                 or not 0 < self.dense_layers < self.num_layers):
             raise ValueError(
                 f"dense_layers={self.dense_layers}: leading dense layers "
                 f"are served before the expert layers of a latent-attention "
-                f"config (0 < dense_layers < num_layers, moe set); for "
-                f"other layer kinds cfg.moe switches every MLP at once")
+                f"or K/V decoder (0 < dense_layers < num_layers, moe set); "
+                f"a hybrid model's layers are each one part")
+        kv = self.latent is None and self.hybrid is None
+        if (len(self.dense_kinds) != (self.dense_layers if kv else 0)
+                or any(k not in ("sliding", "full")
+                       for k in self.dense_kinds)):
+            raise ValueError(
+                f"dense_kinds={self.dense_kinds!r}: a K/V decoder names the "
+                f"attention kind ('sliding' / 'full') of each of its "
+                f"{self.dense_layers} leading dense layers; other configs "
+                f"name none")
+        if "sliding" in self.dense_kinds and not self.sliding_window:
+            raise ValueError("dense_kinds names a sliding layer: "
+                             "sliding_window must say how many keys it sees")
+        if not kv and self.kv_parts():
+            raise ValueError(
+                f"{', '.join(self.kv_parts())}: parts of a K/V decoder's "
+                f"layer, not of a latent-attention or hybrid one")
+        if any(k not in ("sliding", "full") for k in self.nope_kinds):
+            raise ValueError(f"nope_kinds={self.nope_kinds!r}: attention "
+                             f"kinds 'sliding' / 'full'")
         if pat is None:
             if self.hybrid is not None:
                 raise ValueError("hybrid: layer_pattern must name the "
@@ -230,10 +267,11 @@ class LlamaConfig:
         if "experts" in pat and self.moe is None:
             raise ValueError("layer_pattern names expert layers: moe must "
                              "give the router's width and top_k")
-        if self.num_layers % len(pat):
+        if (self.num_layers - self.dense_layers) % len(pat):
             raise ValueError(
-                f"num_layers={self.num_layers} is not a whole number of "
-                f"periods of {len(pat)} layers")
+                f"num_layers={self.num_layers} less dense_layers="
+                f"{self.dense_layers} is not a whole number of periods of "
+                f"{len(pat)} layers")
         if "sliding" in pat and not self.sliding_window:
             raise ValueError(
                 "layer_pattern names sliding layers: sliding_window "
@@ -248,18 +286,43 @@ class LlamaConfig:
         """The kinds of one period's layers, in order."""
         return tuple(self.layer_pattern) if self.layer_pattern else ("full",)
 
+    @property
+    def prologue(self) -> Tuple[str, ...]:
+        """The kinds of the leading dense layers, in order."""
+        if self.latent is not None:
+            return ("latent",) * self.dense_layers
+        return tuple(self.dense_kinds)
+
+    @property
+    def periods(self) -> int:
+        """Periods of ``layer_pattern`` after the prologue."""
+        return (self.num_layers - self.dense_layers) // len(self.period)
+
+    def kv_parts(self) -> Tuple[str, ...]:
+        """The parts of a K/V decoder's layer this config switches on
+        beyond the plain one, by name (what a program that lacks them
+        refuses)."""
+        on = ((self.dense_layers and self.latent is None, "a dense prologue"),
+              (self.attn_gate, "an attention output gate"),
+              (self.qk_norm, "QK-norm"),
+              (self.nope_kinds, "attention with no rotary embedding"),
+              (self.sandwich_norms, "sandwich norms"),
+              (self.embed_scale is not None, "an input scale"))
+        return tuple(name for flag, name in on if flag)
+
     def window_of(self, kind: str) -> Optional[int]:
         return self.sliding_window if kind == "sliding" else None
 
     def kind_layers(self, kind: str) -> int:
         """Layers of this kind in the whole model."""
-        return (self.num_layers // len(self.period)) * self.period.count(kind)
+        return (self.periods * self.period.count(kind)
+                + self.prologue.count(kind))
 
     def cache_layers(self) -> Dict[str, int]:
         """What the model asks of a cache manager: kind of cache
         (:data:`CACHE_OF_KIND`) -> how many of its layers need one."""
         out: Dict[str, int] = {}
-        for kind in dict.fromkeys(self.period):
+        for kind in dict.fromkeys(self.prologue + self.period):
             c = CACHE_OF_KIND[kind]
             if c is not None:
                 out[c] = out.get(c, 0) + self.kind_layers(kind)
@@ -285,10 +348,13 @@ class LlamaConfig:
         return LlamaConfig(**d)
 
     def num_params(self) -> int:
-        if self.hybrid is not None or self.latent is not None:
+        if (self.hybrid is not None or self.latent is not None
+                or self.kv_parts()):
             raise ValueError("num_params: count the leaves of a hybrid or "
-                             "latent-attention model (param_shapes of "
-                             "models/hybrid.py, models/latent.py)")
+                             "latent-attention model, or of a K/V decoder "
+                             "with more than the plain layer's parts "
+                             "(param_shapes of models/hybrid.py, "
+                             "models/latent.py, llama.kv_param_shapes)")
         h, i, v, L = (self.hidden_size, self.intermediate_size,
                       self.vocab_size, self.num_layers)
         hd, nh, nkv = self.hd, self.num_heads, self.num_kv_heads
@@ -310,6 +376,9 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.latent is not None:
         from . import latent as _latent
         return _latent.init_params(key, cfg)
+    if cfg.kv_parts() or (cfg.moe is not None and (
+            cfg.moe.score != "softmax" or cfg.moe.shared_size)):
+        return _init_from_shapes(key, cfg, kv_param_shapes(cfg))
     h, i, v, L = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
                   cfg.num_layers)
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
@@ -352,6 +421,85 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(jax.random.fold_in(key, 99), (h, v), fan_in=h)
     return params
+
+
+def kv_param_shapes(cfg: LlamaConfig, experts_held=None) -> Dict[str, Any]:
+    """A K/V decoder's leaves as ``leaf -> (shape, how it is drawn)``: a
+    fan-in (N(0, 1/fan_in)), "norm" (ones), "embed" (N(0, 0.02^2)),
+    "router_bias" (N(0, 0.01^2)) or "first_expert". The leading dense
+    layers (``dense_layers``) are a stack of their own; ``experts_held``:
+    the expert axis of the stacks (None holds all the router's experts and
+    leaves ``first_expert`` out)."""
+    h, i, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    Ld = cfg.dense_layers
+    L = cfg.num_layers - Ld
+
+    def attn(n):
+        out = {"wq": ((n, h, nh * hd), h), "wk": ((n, h, nkv * hd), h),
+               "wv": ((n, h, nkv * hd), h), "wo": ((n, nh * hd, h), nh * hd),
+               "attn_norm": ((n, h), "norm"), "mlp_norm": ((n, h), "norm")}
+        if cfg.attn_gate:
+            out["wgate"] = ((n, h, nh * hd), h)
+        if cfg.qk_norm:
+            out.update(q_norm=((n, hd), "norm"), k_norm=((n, hd), "norm"))
+        if cfg.sandwich_norms:
+            out.update(post_attn_norm=((n, h), "norm"),
+                       post_mlp_norm=((n, h), "norm"))
+        return out
+
+    def dense(n):
+        return {"wg": ((n, h, i), h), "wu": ((n, h, i), h),
+                "wd": ((n, i, h), i)}
+
+    out = {"embed": ((v, h), "embed"), "final_norm": ((h,), "norm")}
+    if not cfg.tie_embeddings:
+        out["lm_head"] = ((h, v), h)
+    if Ld:
+        out["dense_layers"] = {**attn(Ld), **dense(Ld)}
+    moe = cfg.moe
+    if moe is None:
+        out["layers"] = {**attn(L), **dense(L)}
+        return out
+    E = moe.num_experts
+    El = E if experts_held is None else experts_held
+    ie, sh = moe.expert_size or i, moe.shared_size
+    layers = {**attn(L), "moe_gate": ((L, h, E), h),
+              "moe_wg": ((L, El, h, ie), h), "moe_wu": ((L, El, h, ie), h),
+              "moe_wd": ((L, El, ie, h), ie)}
+    if moe.score == "sigmoid":
+        layers["moe_bias"] = ((L, E), "router_bias")
+    if sh:
+        layers.update(ws_g=((L, h, sh), h), ws_u=((L, h, sh), h),
+                      ws_d=((L, sh, h), sh))
+    if experts_held is not None:
+        layers["first_expert"] = ((L,), "first_expert")
+    out["layers"] = layers
+    return out
+
+
+def _init_from_shapes(key: jax.Array, cfg: LlamaConfig, shapes: Dict,
+                      first_expert: int = 0) -> Dict[str, Any]:
+    """Draw the leaves ``shapes`` describes (:func:`kv_param_shapes`,
+    ``latent.param_shapes``); the router and its bias stay float32."""
+    is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
+    with_paths, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=is_leaf)
+    out = []
+    for k, (path, (shape, kind)) in zip(
+            jax.random.split(key, len(with_paths)), with_paths):
+        dtype = (jnp.float32 if path[-1].key in ("moe_gate", "moe_bias")
+                 else cfg.dtype)
+        if kind == "norm":
+            out.append(jnp.ones(shape, dtype))
+        elif kind == "first_expert":
+            out.append(jnp.full(shape, first_expert, jnp.int32))
+        else:
+            n = jax.random.normal(k, shape, jnp.float32)
+            scale = {"embed": 0.02, "router_bias": 0.01}.get(kind) \
+                or kind ** -0.5
+            out.append((n * scale).astype(dtype))
+    return jax.tree.unflatten(treedef, out)
 
 
 def param_specs(cfg: LlamaConfig) -> Dict[str, Any]:
@@ -665,10 +813,13 @@ def rope_tables(seq_len: int, hd: int, theta: float,
 
 def rope_tables_by_kind(cfg: LlamaConfig, seq_len: int
                         ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
-    """cos and sin for each kind of layer in the config's period."""
+    """cos and sin for each kind of layer in the config, ``(None, None)``
+    for a kind with no rotary embedding (``nope_kinds``)."""
     out = {}
-    for kind in dict.fromkeys(cfg.period):
-        if kind == "sliding":
+    for kind in dict.fromkeys(cfg.prologue + cfg.period):
+        if kind in cfg.nope_kinds:
+            out[kind] = (None, None)
+        elif kind == "sliding":
             out[kind] = rope_tables(seq_len, cfg.hd,
                                     cfg.rope_theta_sliding or cfg.rope_theta)
         elif kind == "latent":
@@ -789,11 +940,15 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None,
     q = tpact(h1 @ lp["wq"]).reshape(B, S, nh, hd)
     k = tpact(h1 @ lp["wk"]).reshape(B, S, nkv, hd)
     v = tpact(h1 @ lp["wv"]).reshape(B, S, nkv, hd)
+    if cfg.qk_norm:
+        from .generate import _qk_norm
+        q, k = _qk_norm(q, k, lp, cfg)
     # rope stays XLA even when fused=True: it folds into the qkv matmul
     # epilogue for free, while the pallas rope kernel needs its halves
     # split/concatenated outside the kernel (extra HBM passes)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cos is not None:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     if cp:
         from ..distributed.fleet.meta_parallel.context_parallel import (
             ring_attention)
@@ -807,12 +962,26 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None,
         o = _attention(q, k, v, causal=True,
                        mesh_axes=attn_axes or mesh_axes,
                        window=window).reshape(B, S, nh * hd)
+    if cfg.attn_gate:
+        from .generate import _gate
+        o = _gate(o.reshape(B, S, nh, hd), h1, lp, nh).reshape(B, S, nh * hd)
     from jax.ad_checkpoint import checkpoint_name
+    from .generate import _post_norm, _swiglu
     o = checkpoint_name(o, "attn_out")
-    x = sp(x + o @ lp["wo"])
+    x = sp(x + _post_norm(o @ lp["wo"], lp, "post_attn_norm", cfg))
 
     h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps, pallas=fused)
-    if cfg.moe is not None:
+    if cfg.moe is not None and "wg" in lp:
+        # a leading dense layer of an expert model
+        ff, aux = _swiglu(h2, lp, "wg", "wu", "wd"), jnp.float32(0.0)
+    elif cfg.moe is not None and (cfg.moe.score != "softmax"
+                                  or cfg.moe.shared_size
+                                  or "first_expert" in lp):
+        # what the training router (moe.moe_ffn) does not compute: the
+        # serving expert layer, dropless (a rule, a shared expert, a share)
+        from .generate import _moe_ffn
+        ff, aux = _moe_ffn(h2, lp, cfg)[0], jnp.float32(0.0)
+    elif cfg.moe is not None:
         ff, losses = _moe.moe_ffn(
             h2, {"w_gate": lp["moe_gate"], "wg": lp["moe_wg"],
                  "wu": lp["moe_wu"], "wd": lp["moe_wd"]},
@@ -828,7 +997,7 @@ def _block(x, lp, cos, sin, cfg: LlamaConfig, mesh_axes, attn_axes=None,
             ff = (jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
                   * u) @ lp["wd"]
         aux = jnp.float32(0.0)
-    return sp(x + ff), aux
+    return sp(x + _post_norm(ff, lp, "post_mlp_norm", cfg)), aux
 
 
 def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
@@ -840,7 +1009,7 @@ def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
             "flash kernel (models/latent.py serves it; llama.forward is "
             "its no-cache forward)")
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    x = embed_rows(params, tokens, cfg)
     if mesh_axes is not None:
         # Pin the gather output to the one layout the partitioner can
         # produce without moving the table: batch over the data axes (the
@@ -876,6 +1045,10 @@ def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
         return f
 
     blocks = {kind: block(kind) for kind in tables}
+    # the leading dense layers, one by one
+    for j, kind in enumerate(cfg.prologue):
+        x, _ = blocks[kind](x, jax.tree.map(lambda a: a[j],
+                                            params["dense_layers"]))
 
     def body(x, lps):
         # one period: its layers in order, each of its own kind
@@ -890,6 +1063,15 @@ def _trunk(params, tokens, cfg: LlamaConfig, mesh_axes=None):
     x = rms_norm(x, params["final_norm"], cfg.rms_eps,
                  pallas=_pallas_fused(cfg))
     return x, jnp.sum(auxs)
+
+
+def embed_rows(params, tokens, cfg: LlamaConfig):
+    """The embedding's rows of ``tokens`` in the model's dtype, times
+    ``embed_scale`` where the config has one."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    if cfg.embed_scale is not None:
+        x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+    return x
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
@@ -940,6 +1122,13 @@ def loss_fn(params, tokens, cfg: LlamaConfig, mesh_axes=None,
     materialized — the HBM win that lets batch size scale (the reference
     pays the full fp32 logits; this is a TPU-first deviation).
     """
+    if cfg.latent is None and (cfg.dense_layers or (cfg.moe is not None and (
+            cfg.moe.score != "softmax" or cfg.moe.shared_size))):
+        raise ValueError(
+            "training a config with leading dense layers, a sigmoid router "
+            "or a shared expert is not supported: its expert layer is the "
+            "serving one (generate._moe_ffn), with no grouped backward; "
+            "llama.forward is its no-cache forward")
     h, aux = _trunk(params, tokens, cfg, mesh_axes)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     head = head.astype(h.dtype)

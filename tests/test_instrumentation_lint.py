@@ -91,7 +91,24 @@ def _plant_lost_stall_total(tree):
     return "stall_ns_total"
 
 
+def _plant_unfed_chunk_window_keys(tree):
+    (tree / "paddle_tpu/inference/predictor.py").write_text(
+        'count("window_keys_attended_total")\n'
+        'count("full_keys_attended_total")\n'
+        'count("chunk_full_keys_attended_total")\n')
+    return "chunk_window_keys_attended_total"
+
+
+def _plant_lost_gate_scope(tree):
+    (tree / "paddle_tpu/models").mkdir(parents=True)
+    (tree / "paddle_tpu/models/generate.py").write_text(
+        'def _gate(o, u, lp, heads):\n    return o\n')
+    return 'named_scope("attention_output_gate")'
+
+
 @pytest.mark.parametrize("rule, plant", [
+    ("check_hybrid_names", _plant_unfed_chunk_window_keys),
+    ("check_hybrid_names", _plant_lost_gate_scope),
     ("check_fault_sites", _plant_unthreaded_site),
     ("check_sync_points", _plant_sync_in_dispatch),
     ("check_hybrid_names", _plant_unfed_counter),

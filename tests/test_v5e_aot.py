@@ -436,6 +436,69 @@ def test_latent_serving_programs_fit_one_chip(v5e, program):
         assert m.temp_size_in_bytes < 0.10e9
 
 
+# Trinity-Large-Preview's published widths at the cut of chipbench/configs/
+# trinity-large-preview.json: layers 5-9 (the last dense layer, sliding, then
+# sliding, full, sliding, sliding expert layers), 32 of the router's 256
+# experts held, an eighth of the vocabulary; batch 32, a full pool of 12,289
+# pages of 64 (every row can reach 24,576) and a sliding pool of 32 x 69 + 1.
+# Weights 8.65 GB + pools 5.54 GB resident. As compiled here the decode
+# program's temp is under 0.01 GB and the largest chunk program the cell
+# reaches (context 16,384, width 256) holds 0.66 GB: its full layer's scores
+# are taken a key head's group at a time (1.21 GB with all 48 heads).
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_gated_window_serving_programs_fit_one_chip(v5e, program):
+    from chipbench.archs import afmoe as arch
+    from paddle_tpu.models import generate as gen
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "trinity-large-preview.json")) as f:
+        c = json.load(f)
+    cfg = arch.program_config(c, 24576)
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(lambda k: arch.weights(k, c),
+                               jax.random.key(0)))
+    B, page, pps = 32, 64, 384
+    pool = on(jax.eval_shape(lambda: gen.init_paged_cache(
+        cfg, 12289, page, window_pages=1 + B * 69)))
+    assert pool["k"].shape == (1, 12289, 64, 8, 128)
+    assert pool["k_w"].shape == (4, 2209, 64, 8, 128)
+    i32 = jnp.int32
+    if program == "decode":
+        def step(params, last, paged, tables, lengths, active, wt):
+            logits, paged, stats = gen.paged_decode_forward(
+                params, last, paged, tables, lengths, cfg, active=active,
+                use_kernel=True, window_tables=wt, with_stats=True)
+            return jnp.argmax(logits, -1), paged, stats
+        avals = (_on(d, (B,), i32), pool, _on(d, (B, pps), i32),
+                 _on(d, (B,), i32), _on(d, (B,), jnp.bool_),
+                 _on(d, (B, pps), i32))
+    else:
+        def step(params, toks, paged, table, ctx_len, chunk_len, wt):
+            return gen.paged_prefill_chunk(
+                params, toks, paged, table, cfg, ctx_cap=256 * page,
+                ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=True,
+                window_table=wt, with_stats=True)
+        avals = (_on(d, (1, 256), i32), pool, _on(d, (pps,), i32),
+                 _on(d, (), i32), _on(d, (), i32), _on(d, (pps,), i32))
+    compiled = _compile(jax.jit(step, donate_argnums=(2,)), params, *avals)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held < 15.75e9
+    text = compiled.as_text()
+    assert text.count("grouped_expert_matmul") >= 3
+    if program == "decode":
+        # the prologue's sliding layer, the scanned sliding runs and the
+        # full layer: the pools ride through them whole, written in place
+        assert len(re.findall(r"%paged_attention[\w.\-]* = ", text)) >= 3
+        assert m.temp_size_in_bytes < 0.01e9
+    else:
+        # no float32 score of all 48 heads over 256 queries in HBM
+        assert not re.search(r"f32\[1,48,256,\d+\]", text)
+        assert m.temp_size_in_bytes < 0.8e9
+
+
 def test_swiglu_fits_scoped_vmem_at_width_4096(v5e):
     """block_rows=256 x width 4096 needed 19.93 MiB of the 16 MiB scoped
     VMEM, forward and backward; the row block now follows the width."""

@@ -198,6 +198,13 @@ _HYBRID_NAMES = {
         "paddle_tpu/ops/pallas/paged_latent_attention.py",
     'name="latent_chunk_attention"':
         "paddle_tpu/ops/pallas/paged_latent_attention.py",
+    # the keys a windowed config's programs read, by kind, and the
+    # attention's output gate in the serving programs
+    "window_keys_attended_total": "paddle_tpu/inference/predictor.py",
+    "full_keys_attended_total": "paddle_tpu/inference/predictor.py",
+    "chunk_window_keys_attended_total": "paddle_tpu/inference/predictor.py",
+    "chunk_full_keys_attended_total": "paddle_tpu/inference/predictor.py",
+    'named_scope("attention_output_gate")': "paddle_tpu/models/generate.py",
     # the steps that committed a chunk and the stall totals (PR 36)
     "steps_committing_chunk_total": "paddle_tpu/serving/scheduler.py",
     "steps_committing_chunk_ns_total": "paddle_tpu/serving/scheduler.py",
