@@ -854,17 +854,19 @@ class ContinuousBatchingEngine:
                          "latent_chunk_tokens_total",
                          "latent_chunk_keys_attended_total"):
                 self.spans.count(name, 0)
-        # --- keys attended by kind (a config with sliding layers): the
-        # (query, key) pairs of a program's live rows, a window at most
-        # in a sliding layer, times the layers of the kind; known at
-        # dispatch from the lengths
+        # --- keys attended by kind: the (query, key) pairs of a program's
+        # live rows, a window at most in a sliding layer, times the layers
+        # of the kind; known at dispatch from the lengths. A chunk's for
+        # every config that keeps keys and values, a decode step's where
+        # it has sliding layers
         self._kind_layers = {k: cfg.cache_layers().get(k, 0)
                              for k in ("sliding", "full")}
+        if self._kind_layers["full"]:
+            self.spans.count("chunk_full_keys_attended_total", 0)
         if self.cache.window:
             for name in ("window_keys_attended_total",
                          "full_keys_attended_total",
-                         "chunk_window_keys_attended_total",
-                         "chunk_full_keys_attended_total"):
+                         "chunk_window_keys_attended_total"):
                 self.spans.count(name, 0)
         # replica id spans carry (ISSUE 16) — stamped by the cluster /
         # supervisor; -1 renders as the "router" lane in exports
@@ -1812,7 +1814,7 @@ class ContinuousBatchingEngine:
                 # the pools; tokens through the chunked scan, a layer
                 args += [jnp.int32(slot)]
                 self.spans.count("ssm_chunk_tokens_total", take)
-            if cache.window:
+            if self._kind_layers["full"]:
                 # token i of the chunk sees done + i + 1 positions
                 self._count_keys("chunk_", np.arange(done + 1,
                                                      done + take + 1))
@@ -1897,10 +1899,11 @@ class ContinuousBatchingEngine:
         positions each query sees: ``min(seen, window)`` a sliding layer,
         ``seen`` a full one, times the layers of the kind."""
         seen = np.asarray(seen, np.int64)
-        self.spans.count(
-            prefix + "window_keys_attended_total",
-            int(np.minimum(seen, self.cache.window).sum())
-            * self._kind_layers["sliding"])
+        if self.cache.window:
+            self.spans.count(
+                prefix + "window_keys_attended_total",
+                int(np.minimum(seen, self.cache.window).sum())
+                * self._kind_layers["sliding"])
         self.spans.count(prefix + "full_keys_attended_total",
                          int(seen.sum()) * self._kind_layers["full"])
 

@@ -771,17 +771,25 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
     returns (logits (1, V) at the chunk's LAST VALID token, updated
     pools).
 
-    Layout: the slot's first ``ctx_len`` cached rows are gathered from
-    its pages and RIGHT-ALIGNED into a ``(1, ctx_cap + C)`` dense temp
-    cache (garbage below masked via the same ``kstart``/``rpos``
-    machinery as left-padded ragged prompts), the chunk forwards at
-    temp positions ``[ctx_cap, ctx_cap + C)`` with logical rope
-    positions ``ctx_len + i``, and the new rows scatter into the slot's
-    pages (pad rows route to the trash page). Chunk rows see exactly
-    the KV a monolithic prefill's rows ``[ctx_len, ctx_len + chunk)``
-    would see — cached rows are bit-identical and masked columns
-    contribute exact zeros — so chunked + prefix-shared prefill stays
-    TOKEN-IDENTICAL to the dense path.
+    Layout (:func:`_chunk_over_pages`): a layer writes the chunk's keys
+    and values into the slot's pages at logical positions ``ctx_len +
+    i`` (pad rows route to the trash page), and its queries attend over
+    the slot's pages through the block table
+    (:func:`~paddle_tpu.ops.pallas.paged_chunk_attention.
+    kv_chunk_attention`: on the chip a kernel that reads the pages to
+    the row's true length, elsewhere its twin over the first ``ctx_cap +
+    C`` positions); the pools ride whole in the layer scan's carry. Under
+    ``fused`` and for state-space layers the slot's first ``ctx_len``
+    cached rows are instead gathered from its pages and RIGHT-ALIGNED into
+    a ``(1, ctx_cap + C)`` dense temp cache (garbage below masked via the
+    same ``kstart``/``rpos`` machinery as left-padded ragged prompts),
+    the chunk forwards at temp positions ``[ctx_cap, ctx_cap + C)`` with
+    logical rope positions ``ctx_len + i``, and the new rows scatter into
+    the slot's pages. Either way chunk rows see exactly the KV a
+    monolithic prefill's rows ``[ctx_len, ctx_len + chunk)`` would see —
+    cached rows are bit-identical and masked columns contribute exact
+    zeros — so chunked + prefix-shared prefill stays TOKEN-IDENTICAL to
+    the dense path.
 
     This one program serves THREE consumers: chunked prefill of a fresh
     admission, the prefix-cache continuation (``ctx_len`` > 0 on the
@@ -816,10 +824,10 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
 
     ``window_table``: the slot's page ids in the SLIDING layers' pool,
     by the same logical page index (entries of pages that slid out of
-    the window hold the trash page and are never read). Those layers'
-    temp cache holds one window of context, not ``ctx_cap``: the rows
-    ``[ctx_len - wcap, ctx_len)`` gathered from the window's pages, so
-    a chunk deep in a long prompt costs them what a shallow one does.
+    the window hold the trash page and are never read). Those layers
+    read one window of context, not ``ctx_cap`` (the temp cache of the
+    gathering path holds the rows ``[ctx_len - wcap, ctx_len)``), so a
+    chunk deep in a long prompt costs them what a shallow one does.
     ``with_stats``: also return the summed ``_moe_ffn`` stats of the
     chunk's valid rows.
 
@@ -850,6 +858,13 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
         raise ValueError(
             f"paged_prefill_chunk: ctx_cap={ctx_cap} must be a multiple "
             f"of the page size {page}")
+    if cfg.hybrid is None and not fused:
+        return _chunk_over_pages(
+            params, tokens, paged, block_table, cfg, ctx_cap=ctx_cap,
+            ctx_len=ctx_len, chunk_len=chunk_len, window_table=window_table,
+            tp_axis=tp_axis, dp_axis=dp_axis, adapters=adapters,
+            adapter_slot=adapter_slot, use_kernel=use_kernel,
+            with_stats=with_stats)
     ext = block_table.shape[0] * page
     quant = "ks" in paged
     W = ctx_cap + C
@@ -930,6 +945,86 @@ def paged_prefill_chunk(params, tokens: jax.Array, paged: Dict,
             rows = dense[name][:, 0, at:]               # (L, C, ...)
             new[name] = _scatter_rows(paged[name], dst, rows)
     return (logits, new) + tuple(out[2:])
+
+
+def _chunk_over_pages(params, tokens, paged: Dict, block_table,
+                      cfg: LlamaConfig, *, ctx_cap: int, ctx_len, chunk_len,
+                      window_table, tp_axis, dp_axis, adapters, adapter_slot,
+                      use_kernel, with_stats):
+    """:func:`paged_prefill_chunk` for a K/V decoder, unfused: each layer
+    writes the chunk's keys and values into the row's pages (the int8
+    tier's rows and scales as :func:`_cache_write` makes them), then its
+    queries attend over the row's pages, their own rows among them,
+    through :func:`~paddle_tpu.ops.pallas.paged_chunk_attention.
+    kv_chunk_attention` (the twin reaches ``ctx_cap + C`` positions, the
+    kernel the row's true length). The pools ride whole in the layer
+    scan's carry (:func:`_paged_layers`), donated and written in place;
+    no temp cache is gathered."""
+    from ..ops.pallas.paged_chunk_attention import kv_chunk_attention
+    C = tokens.shape[1]
+    page = paged["k"].shape[2]
+    ppseq = block_table.shape[0]
+    ext = ppseq * page
+    ctx_len = jnp.asarray(ctx_len, jnp.int32).reshape(())
+    chunk_len = jnp.asarray(chunk_len, jnp.int32).reshape(())
+    pos = jnp.arange(C, dtype=jnp.int32)
+    rpos = (ctx_len + pos)[None, :]
+    logical = jnp.clip(ctx_len + pos, 0, ext - 1)
+    kinds = tuple(dict.fromkeys(cfg.prologue + cfg.period))
+    tables = {"full": block_table, "sliding": window_table}
+    dsts = {kind: jnp.where(pos < chunk_len, tables[kind][logical // page]
+                            * page + logical % page, 0) for kind in kinds}
+    rope = llama.rope_tables_by_kind(cfg, ext)
+    # the pages of the context's bucket and the chunk (the twin's reach)
+    seen = min(ppseq, ctx_cap // page + -(-C // page))
+
+    def write(pool, dst, rows):
+        return pool.reshape((-1,) + pool.shape[2:]).at[dst].set(
+            rows.astype(pool.dtype)).reshape(pool.shape)
+
+    def attend(q, k, v, held, kind, dst, table):
+        cos, sin = rope[kind]
+        if cos is not None:
+            q = _rope_rows(q, cos, sin, rpos)
+            k = _rope_rows(k, cos, sin, rpos)
+        if "ks" in held:
+            (k, ks), (v, vs) = _rowq(k), _rowq(v)
+            rows = {"k": k, "v": v, "ks": ks, "vs": vs}
+        else:
+            rows = {"k": k, "v": v}
+        held = {n: write(held[n], dst, r[0]) for n, r in rows.items()}
+        o = kv_chunk_attention(
+            q[0], held["k"], held["v"], table, ctx_len,
+            chunk_len, bucket_pages=seen, window=cfg.window_of(kind),
+            ks_pages=held.get("ks"), vs_pages=held.get("vs"),
+            use_kernel=use_kernel)
+        return o, held
+
+    pools = {kind: _of_kind(paged, kind) for kind in kinds}
+    x, new, stats = _paged_layers(
+        params, cfg, llama.embed_rows(params, tokens, cfg),
+        {kind: {n: a.reshape((-1,) + a.shape[2:]) for n, a in d.items()}
+         for kind, d in pools.items()},
+        {kind: d["k"].shape[1] for kind, d in pools.items()}, dsts, tables,
+        attend, valid=pos < chunk_len, use_kernel=use_kernel,
+        tp_axis=tp_axis, dp_axis=dp_axis, adapters=adapters,
+        adapter_slots=adapter_slot)
+    new_paged = {n + KIND_SUFFIX[kind]: a.reshape(
+        paged[n + KIND_SUFFIX[kind]].shape)
+        for kind in kinds for n, a in new[kind].items()}
+    x = lax.dynamic_slice_in_dim(x, jnp.clip(chunk_len - 1, 0, C - 1), 1,
+                                 axis=1)
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    if cfg.tie_embeddings:
+        head = params["embed"].T.astype(x.dtype)
+        gather = False
+    else:
+        head = _w(params, "lm_head", x.dtype)
+        gather = tp_axis is not None                # vocab-sharded
+    logits = (x[:, -1] @ head).astype(jnp.float32)
+    if gather:
+        logits = _tp_allgather(logits, tp_axis, 1)
+    return (logits, new_paged) + ((stats,) if with_stats else ())
 
 
 def paged_verify_forward(params, tokens: jax.Array, paged: Dict,
@@ -1228,6 +1323,135 @@ def _paged_kv_attend(q, k, v, held, dst, table, lengths, page, *,
     return o, kp, vp, ksp, vsp
 
 
+def _paged_layers(params, cfg: LlamaConfig, x, pools: Dict, pool_pages,
+                  dsts, tables, attend, *, valid, use_kernel=None,
+                  tp_axis=None, dp_axis=None, adapters=None,
+                  adapter_slots=None):
+    """Every layer of a K/V decoder in order over ``x`` (B, T, hidden), the
+    pools riding WHOLE in the layer scan's carry: ``pools`` {kind: {name:
+    (layers of the kind * ``pool_pages[kind]``, page, ...)}} as ``attend``
+    reads and writes them; no slice of a pool is copied out or back, and
+    donated pools are written in place. ``dsts`` / ``tables`` by kind: the
+    flat slots the new rows go to and the block tables, both counted from
+    a kind's first layer; a layer whose pages start at ``base`` gets them
+    moved up by ``base`` pages. ``attend(q, k, v, held, kind, dst, table)
+    -> (o, held)`` is the cache half of a layer: ``q``,
+    ``k``, ``v`` projected and QK-normed, not yet rotated; ``held`` the
+    kind's pools; ``o`` the heads' results of the B x T rows in their
+    order, ``(B * T, nh, hd)`` as a kernel gives them. ``valid`` (B * T,):
+    the rows the ``_moe_ffn`` stats count. Inside a period each run of one kind
+    (:func:`_runs`) is a scan of its own over one layer body; a plain
+    decoder's period is one layer, and the scan over periods is its layer
+    loop. Leading dense layers (``cfg.prologue``) run one by one before
+    it, each on its kind's pool from the first layer on; the scanned
+    layers' pools follow theirs. Returns ``(x, pools, the summed stats or
+    None)``."""
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    if tp_axis is not None:
+        nh, nkv = _tp_heads(params["layers"], cfg)
+    aslot = asc = None
+    if adapters is not None:
+        aslot, asc = _adapter_prep(adapters, adapter_slots, cfg)
+    period = cfg.period
+    plen = len(period)
+    pro = cfg.prologue
+    # where in its kind's slice of a period a layer's pool sits
+    slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
+
+    def layer(xc, lp, kind, held, ad_l, at_layer, base):
+        page = held["k"].shape[1]
+        dst, table = dsts[kind] + base * page, tables[kind] + base
+        h1 = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
+        q = _project_heads(
+            h1, _w(lp, "wq", xc.dtype), nh,
+            None if ad_l is None
+            else _lora_delta(h1, ad_l[0], ad_l[1], aslot, asc))
+        k = _project_heads(h1, _w(lp, "wk", xc.dtype), nkv)
+        v = _project_heads(h1, _w(lp, "wv", xc.dtype), nkv)
+        if cfg.qk_norm:
+            q, k = _qk_norm(q, k, lp, cfg)
+        o, held = attend(q, k, v, held, kind, dst, table)
+        if cfg.attn_gate:
+            o = _gate(o.reshape(xc.shape[:2] + (nh, hd)), h1, lp, nh)
+        o = o.reshape(xc.shape[:2] + (nh * hd,))
+        if tp_axis is not None:
+            o = _tp_allgather(o, tp_axis, 2)
+        ow = o @ _w(lp, "wo", xc.dtype)
+        if ad_l is not None:
+            # the o-projection's adapter term: input is the (full-
+            # width) attention output, B_o column-sharded with wo
+            ow = ow + _lora_delta(o, ad_l[2], ad_l[3], aslot, asc)
+        if tp_axis is not None:
+            xo = xc + _tp_allgather(ow, tp_axis, 2)
+        else:
+            xo = xc + _post_norm(ow, lp, "post_attn_norm", cfg)
+        h2 = rms_norm(xo, lp["mlp_norm"], cfg.rms_eps)
+        stats = None
+        if cfg.moe is not None and "wg" not in lp:
+            # (a leading dense layer of an expert model has "wg")
+            ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
+                                 dp_axis=dp_axis,
+                                 valid=valid.reshape(xc.shape[:2]),
+                                 experts=experts, layer=at_layer,
+                                 use_kernel=use_kernel)
+            y = xo + _post_norm(ff, lp, "post_mlp_norm", cfg)
+        else:
+            g = jax.nn.silu((h2 @ _w(lp, "wg", xc.dtype)).astype(
+                jnp.float32)).astype(xc.dtype)
+            u = h2 @ _w(lp, "wu", xc.dtype)
+            if tp_axis is not None:
+                gu = _tp_allgather(g * u, tp_axis, 2)
+                y = xo + _tp_allgather(gu @ _w(lp, "wd", xc.dtype),
+                                       tp_axis, 2)
+            else:
+                y = xo + _post_norm((g * u) @ _w(lp, "wd", xc.dtype), lp,
+                                    "post_mlp_norm", cfg)
+        return y, held, stats
+
+    scanned, experts = _split_experts(params["layers"])
+    lps, take_lp = _layer_feed(scanned, plen)
+    ads, take_ad = _layer_feed(
+        None if adapters is None else tuple(
+            adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
+    index = jnp.arange(cfg.periods, dtype=jnp.int32)
+    zero = _no_stats(scanned) if cfg.moe is not None else None
+
+    def body(carry, xs):
+        lps_i, ads_i, i = xs
+
+        def one(carry, j, kind, first):
+            # layer j of the period; ``first``: the period's first layer
+            # of this run, at slot ``slot_of[first]`` of its kind, after
+            # the prologue's layers of that kind
+            xc, held, stats = carry
+            base = ((i * period.count(kind) + slot_of[first]
+                     + (j - first)) * pool_pages[kind])
+            if pro.count(kind):
+                base = base + pro.count(kind) * pool_pages[kind]
+            xc, out, st = layer(
+                xc, take_lp(lps_i, i, j), kind, held[kind],
+                take_ad(ads_i, i, j), i * plen + j, base)
+            return (xc, {**held, kind: out},
+                    stats if st is None else stats + st)
+
+        for kind, first, n in _runs(period):
+            if n == 1:
+                carry = one(carry, first, kind, first)
+            else:
+                carry, _ = lax.scan(
+                    lambda cr, j, kind=kind, first=first: (
+                        one(cr, j, kind, first), None),
+                    carry, first + jnp.arange(n, dtype=jnp.int32))
+        return carry, None
+    for j, kind in enumerate(pro):
+        x, out, _ = layer(
+            x, jax.tree.map(lambda a: a[j], params["dense_layers"]), kind,
+            pools[kind], None, None, pro[:j].count(kind) * pool_pages[kind])
+        pools = {**pools, kind: out}
+    (x, new, stats), _ = lax.scan(body, (x, pools, zero), (lps, ads, index))
+    return x, new, stats
+
+
 def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
                          block_tables: jax.Array, lengths: jax.Array,
                          cfg: LlamaConfig, *, active=None,
@@ -1335,20 +1559,11 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
     B = tokens.shape[0]
     page = paged["k"].shape[2]
     ext = block_tables.shape[1] * page
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    if tp_axis is not None:
-        nh, nkv = _tp_heads(params["layers"], cfg)
     quant = "ks" in paged
     if active is None:
         active = jnp.ones((B,), bool)
     lengths = jnp.asarray(lengths, jnp.int32)
-    aslot = asc = None
-    if adapters is not None:
-        aslot, asc = _adapter_prep(adapters, adapter_slots, cfg)
-    period = cfg.period
-    plen = len(period)
-    pro = cfg.prologue
-    kinds = tuple(dict.fromkeys(pro + period))
+    kinds = tuple(dict.fromkeys(cfg.prologue + cfg.period))
     if "sliding" in kinds and window_tables is None:
         raise ValueError(
             "paged_decode_forward: the config has sliding layers; "
@@ -1381,130 +1596,38 @@ def paged_decode_forward(params, tokens: jax.Array, paged: Dict,
         dsts[kind] = dst
     x = llama.embed_rows(params, tokens[:, None], cfg)   # (B, 1, H)
     names = ("k", "v", "ks", "vs") if quant else ("k", "v")
-    # where in its kind's slice of a period a layer's pool sits
-    slot_of = [period[:j].count(kind) for j, kind in enumerate(period)]
 
-    def layer(xc, lp, kind, held, ad_l, at_layer, base):
-        # held: the whole pools of the layer's kind as pages (layers * P,
-        # page, ...), this layer's from page ``base`` on
-        kp, vp, ksp, vsp = (held.get(n) for n in ("k", "v", "ks", "vs"))
+    def attend(q, k, v, held, kind, dst, table):
         cos, sin = rope[kind]
         window = cfg.window_of(kind)
-        dst, table = dsts[kind] + base * page, tables[kind] + base
         # the fused kernel has no window: a sliding layer's call is the
         # unfused one
         fuse = fused and window is None
-        h1 = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
-        q = _project_heads(
-            h1, _w(lp, "wq", xc.dtype), nh,
-            None if ad_l is None
-            else _lora_delta(h1, ad_l[0], ad_l[1], aslot, asc))
-        k = _project_heads(h1, _w(lp, "wk", xc.dtype), nkv)
-        v = _project_heads(h1, _w(lp, "wv", xc.dtype), nkv)
-        if cfg.qk_norm:
-            q, k = _qk_norm(q, k, lp, cfg)
         if cos is not None:
             if not fuse:
                 # unfused: q rotates here in XLA and round-trips HBM into
                 # the attention op; fused moves this rotation into VMEM
                 q = _rope_rows(q, cos, sin, rpos)
             k = _rope_rows(k, cos, sin, rpos)
-
-        o, kp, vp, ksp, vsp = _paged_kv_attend(
-            q, k, v, (kp, vp, ksp, vsp), dst, table, lengths, page,
-            window=window, rope_row=rope_row[kind] if fuse else None,
+        o, *out = _paged_kv_attend(
+            q, k, v, tuple(held.get(n) for n in ("k", "v", "ks", "vs")),
+            dst, table, lengths, page, window=window, rope_row=rope_row[kind] if fuse else None,
             use_kernel=use_kernel, dp_axis=dp_axis, dtype=cfg.dtype)
-        if cfg.attn_gate:
-            o = _gate(o[:, None], h1, lp, nh)
-        o = o.reshape(B, 1, nh * hd)
-        if tp_axis is not None:
-            o = _tp_allgather(o, tp_axis, 2)
-        ow = o @ _w(lp, "wo", xc.dtype)
-        if ad_l is not None:
-            # the o-projection's adapter term: input is the (full-
-            # width) attention output, B_o column-sharded with wo
-            ow = ow + _lora_delta(o, ad_l[2], ad_l[3], aslot, asc)
-        if tp_axis is not None:
-            xo = xc + _tp_allgather(ow, tp_axis, 2)
-        else:
-            xo = xc + _post_norm(ow, lp, "post_attn_norm", cfg)
-        h2 = rms_norm(xo, lp["mlp_norm"], cfg.rms_eps)
-        stats = None
-        if cfg.moe is not None and "wg" not in lp:
-            # (a leading dense layer of an expert model has "wg")
-            ff, stats = _moe_ffn(h2, lp, cfg, tp_axis=tp_axis,
-                                 dp_axis=dp_axis, valid=active[:, None],
-                                 experts=experts, layer=at_layer,
-                                 use_kernel=use_kernel)
-            y = xo + _post_norm(ff, lp, "post_mlp_norm", cfg)
-        else:
-            g = jax.nn.silu((h2 @ _w(lp, "wg", xc.dtype)).astype(
-                jnp.float32)).astype(xc.dtype)
-            u = h2 @ _w(lp, "wu", xc.dtype)
-            if tp_axis is not None:
-                gu = _tp_allgather(g * u, tp_axis, 2)
-                y = xo + _tp_allgather(gu @ _w(lp, "wd", xc.dtype),
-                                       tp_axis, 2)
-            else:
-                y = xo + _post_norm((g * u) @ _w(lp, "wd", xc.dtype), lp,
-                                    "post_mlp_norm", cfg)
-        return y, dict(zip(names, (kp, vp, ksp, vsp))), stats
+        return o, dict(zip(names, out))
 
-    scanned, experts = _split_experts(params["layers"])
     pools = {kind: _of_kind(paged, kind) for kind in kinds}
-    lps, take_lp = _layer_feed(scanned, plen)
-    ads, take_ad = _layer_feed(
-        None if adapters is None else tuple(
-            adapters[n] for n in ("aq", "bq", "ao", "bo")), plen)
-    index = jnp.arange(cfg.periods, dtype=jnp.int32)
-    # The pools ride in the scan's carry WHOLE: a layer writes its rows and
-    # the kernel reads its pages where they lie, by block tables moved up
-    # to the layer's first page, and no slice of a pool is copied out or
-    # back. Inside a period each run of one kind (:func:`_runs`) is a scan
-    # of its own over one layer body; a plain decoder's period is one
-    # layer, and the scan over periods is its layer loop.
-    pool_pages = {kind: d["k"].shape[1] for kind, d in pools.items()}
-    zero = _no_stats(scanned) if cfg.moe is not None else None
-
-    def body(carry, xs):
-        lps_i, ads_i, i = xs
-
-        def one(carry, j, kind, first):
-            # layer j of the period; ``first``: the period's first layer
-            # of this run, at slot ``slot_of[first]`` of its kind, after
-            # the prologue's layers of that kind
-            xc, held, stats = carry
-            base = ((i * period.count(kind) + slot_of[first]
-                     + (j - first)) * pool_pages[kind])
-            if pro.count(kind):
-                base = base + pro.count(kind) * pool_pages[kind]
-            xc, out, st = layer(
-                xc, take_lp(lps_i, i, j), kind, held[kind],
-                take_ad(ads_i, i, j), i * plen + j, base)
-            return (xc, {**held, kind: out},
-                    stats if st is None else stats + st)
-
-        for kind, first, n in _runs(period):
-            if n == 1:
-                carry = one(carry, first, kind, first)
-            else:
-                carry, _ = lax.scan(
-                    lambda cr, j, kind=kind, first=first: (
-                        one(cr, j, kind, first), None),
-                    carry, first + jnp.arange(n, dtype=jnp.int32))
-        return carry, None
     # K/V pools as (layers * pages, page, ...); the int8 tier's scale pools
     # as (layers * pages, 1, page * heads) lane rows, the layout the kernel
     # reads: laid out once a step here, not whole in every layer call.
     flat = {kind: {n: a.reshape((-1, 1, a.shape[2] * a.shape[3])
                                 if a.ndim == 4 else (-1,) + a.shape[2:])
                    for n, a in d.items()} for kind, d in pools.items()}
-    for j, kind in enumerate(pro):
-        x, out, _ = layer(
-            x, jax.tree.map(lambda a: a[j], params["dense_layers"]), kind,
-            flat[kind], None, None, pro[:j].count(kind) * pool_pages[kind])
-        flat = {**flat, kind: out}
-    (x, new, stats), _ = lax.scan(body, (x, flat, zero), (lps, ads, index))
+    x, new, stats = _paged_layers(
+        params, cfg, x, flat,
+        {kind: d["k"].shape[1] for kind, d in pools.items()}, dsts, tables,
+        attend, valid=active, use_kernel=use_kernel,
+        tp_axis=tp_axis, dp_axis=dp_axis, adapters=adapters,
+        adapter_slots=adapter_slots)
     new_paged = {n + KIND_SUFFIX[kind]: a.reshape(
         paged[n + KIND_SUFFIX[kind]].shape)
         for kind in kinds for n, a in new[kind].items()}

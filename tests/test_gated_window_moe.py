@@ -213,12 +213,14 @@ def test_each_part_alone_serves_as_the_reference_computes(part):
 
 def test_a_chunk_too_wide_for_whole_scores_takes_a_key_head_at_a_time(
         monkeypatch):
-    """Above ``SCORES_WHOLE_BYTES`` a chunk's attention runs one key head's
-    group of query heads at a time: the same logits."""
+    """Above ``paged_chunk_attention._SCORE_BYTES`` the twin of a chunk's
+    attention over its pages runs one key head's group of query heads at a
+    time: the same logits."""
+    from paddle_tpu.ops.pallas import paged_chunk_attention as pca
     cfg, params = _model()
     seq = _tokens(34, seed=4)
     whole, _ = _served_logits(params, cfg, seq, 32, 16)
-    monkeypatch.setattr(gen, "SCORES_WHOLE_BYTES", 1024)
+    monkeypatch.setattr(pca, "_SCORE_BYTES", 1024)
     blocked, _ = _served_logits(params, cfg, seq, 32, 16)
     np.testing.assert_allclose(blocked, whole, atol=1e-5, rtol=0)
 

@@ -78,6 +78,13 @@ def _plant_lost_chunk_kernel_name(tree):
     return 'name="latent_chunk_attention"'
 
 
+def _plant_lost_kv_chunk_scope(tree):
+    (tree / "paddle_tpu/ops/pallas").mkdir(parents=True)
+    (tree / "paddle_tpu/ops/pallas/paged_chunk_attention.py").write_text(
+        'name="kv_chunk_attention"\n')
+    return 'named_scope("kv_chunk_attention")'
+
+
 def _plant_lost_chunk_step_counter(tree):
     (tree / "paddle_tpu/serving/scheduler.py").write_text(
         'sp.count("steps_committing_chunk_total", 1)\n')
@@ -116,6 +123,7 @@ def _plant_lost_gate_scope(tree):
     ("check_hybrid_names", _plant_lost_latent_scope),
     ("check_hybrid_names", _plant_unfed_chunk_keys_counter),
     ("check_hybrid_names", _plant_lost_chunk_kernel_name),
+    ("check_hybrid_names", _plant_lost_kv_chunk_scope),
     ("check_hybrid_names", _plant_lost_chunk_step_counter),
     ("check_hybrid_names", _plant_lost_stall_total)])
 def test_checker_flags_a_planted_violation(tmp_path, rule, plant):

@@ -198,6 +198,11 @@ _HYBRID_NAMES = {
         "paddle_tpu/ops/pallas/paged_latent_attention.py",
     'name="latent_chunk_attention"':
         "paddle_tpu/ops/pallas/paged_latent_attention.py",
+    # the K/V chunk's attention over the row's pages (its kernel and twin)
+    'named_scope("kv_chunk_attention")':
+        "paddle_tpu/ops/pallas/paged_chunk_attention.py",
+    'name="kv_chunk_attention"':
+        "paddle_tpu/ops/pallas/paged_chunk_attention.py",
     # the keys a windowed config's programs read, by kind, and the
     # attention's output gate in the serving programs
     "window_keys_attended_total": "paddle_tpu/inference/predictor.py",
