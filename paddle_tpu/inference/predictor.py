@@ -611,6 +611,7 @@ class ContinuousBatchingEngine:
         # fabric's handoff, drain/restore and defrag refuse in
         # PagedKVCache); so does what would shard or extend its programs.
         self._state_layers = cfg.cache_layers().get("state", 0)
+        self._state_kind = cfg.state_kind
         if self._state_layers:
             for on, what in ((host_tier, "host_tier"),
                              (draft_layers, "draft_layers"),
@@ -845,8 +846,12 @@ class ContinuousBatchingEngine:
                          if cfg.moe is not None else None)
         self._moe_zero = self._moe_acc
         if self._state_layers:
+            # the first two again by the kind of state-space layer
+            # (``mamba2_state_rows_total``, ``mamba1_chunk_tokens_total``)
             for name in ("ssm_state_rows_total", "ssm_chunk_tokens_total",
-                         "ssm_state_rebuilds_total"):
+                         "ssm_state_rebuilds_total",
+                         f"{self._state_kind}_state_rows_total",
+                         f"{self._state_kind}_chunk_tokens_total"):
                 self.spans.count(name, 0)
         if self._latent_layers:
             for name in ("latent_tokens_attended_total",
@@ -1814,6 +1819,8 @@ class ContinuousBatchingEngine:
                 # the pools; tokens through the chunked scan, a layer
                 args += [jnp.int32(slot)]
                 self.spans.count("ssm_chunk_tokens_total", take)
+                self.spans.count(f"{self._state_kind}_chunk_tokens_total",
+                                 take)
             if self._kind_layers["full"]:
                 # token i of the chunk sees done + i + 1 positions
                 self._count_keys("chunk_", np.arange(done + 1,
@@ -2340,6 +2347,8 @@ class ContinuousBatchingEngine:
             if self._state_layers:
                 # row-layers of recurrent state this program advances
                 self.spans.count("ssm_state_rows_total",
+                                 slots.size * self._state_layers)
+                self.spans.count(f"{self._state_kind}_state_rows_total",
                                  slots.size * self._state_layers)
             if self._latent_layers:
                 # tokens this program's rows attend over, the new one

@@ -197,11 +197,12 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
 
     A config with state-space layers gets a THIRD kind of cache beside
     them, addressed by slot and not by page: ``ssm`` ``(state layers,
-    state_slots, head_dim, state, heads)`` the recurrence's state in
-    ``state_dtype`` (heads last: ``ops/pallas/ssm.py`` has the reason) and
-    ``conv`` ``(state layers, state_slots, conv_kernel - 1, conv_dim)``
-    the causal convolution's last columns in the model's dtype. The KV
-    pools hold layers only for the kinds that attend.
+    state_slots, ...)`` the recurrence's state in ``state_dtype`` (Mamba-2
+    ``(head_dim, state, heads)``, Mamba-1 ``(state, channels)``:
+    ``hybrid.state_shapes``) and ``conv`` ``(state layers, state_slots,
+    ...)`` the causal convolution's last ``conv_kernel - 1`` columns in
+    the model's dtype (Mamba-1's flat). The KV pools hold layers only for
+    the kinds that attend.
 
     A config with latent attention gets ONE pool, of latents: ``c``
     ``(layers, num_pages, page_size, LatentConfig.row_lanes)``, a token's
@@ -262,11 +263,11 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
         }
     out = _kind_arrays(cfg, make)
     if "state" in needs:
-        hy, L = cfg.hybrid, needs["state"]
-        out["ssm"] = jnp.zeros((L, state_slots, hy.ssm_head_dim,
-                                hy.ssm_state, hy.ssm_heads), state_dtype)
-        out["conv"] = jnp.zeros((L, state_slots, hy.conv_kernel - 1,
-                                 hy.conv_dim), cfg.dtype)
+        from .hybrid import state_shapes
+        L = needs["state"]
+        ssm, conv = state_shapes(cfg)
+        out["ssm"] = jnp.zeros((L, state_slots) + ssm, state_dtype)
+        out["conv"] = jnp.zeros((L, state_slots) + conv, cfg.dtype)
     return out
 
 
@@ -298,10 +299,16 @@ def _take_pages(pool, table):
 
 def _scatter_rows(pool, dst, rows):
     """Write token rows into pool slots: pool (L, P, page, ...), dst
-    (N,) flat slot ids (page*page_size + offset), rows (L, N, ...)."""
+    (N,) flat slot ids (page*page_size + offset), rows (L, N, ...). One
+    layer at a time: asked to scatter into all layers at once, XLA:TPU
+    lays the slot axis outermost, and where a position's row is narrower
+    than a tile (one key/value head) that is a copy of the whole pool in
+    and out of every call (two of 1.6 GB a pool at a 49k-page pool of two
+    layers)."""
     L, P, page = pool.shape[0], pool.shape[1], pool.shape[2]
     flat = pool.reshape((L, P * page) + pool.shape[3:])
-    flat = flat.at[:, dst].set(rows.astype(pool.dtype))
+    for l in range(L):
+        flat = flat.at[l, dst].set(rows[l].astype(pool.dtype))
     return flat.reshape(pool.shape)
 
 
@@ -1706,14 +1713,16 @@ def quantize_weights(params, cfg: LlamaConfig, bits: int = 8,
             if group in params:
                 out[group] = stacks(params[group], QUANT_LEAVES)
     elif cfg.hybrid is not None:
-        # a stack per kind: the attention and the Mamba-2 projections;
-        # the expert layers stay as they are
+        # a stack per kind: the attention, Mamba and dense MLP
+        # projections; the expert layers stay as they are
+        names = {"attention": ("wq", "wk", "wv", "wo"),
+                 "mamba2": ("w_in", "w_out"),
+                 "mamba1": ("w_in", "w_x", "w_dt", "w_out"),
+                 "mlp": ("wg", "wu", "wd")}
         out["layers"] = {
             **params["layers"],
-            "attention": stacks(params["layers"]["attention"],
-                                ("wq", "wk", "wv", "wo")),
-            "mamba2": stacks(params["layers"]["mamba2"],
-                             ("w_in", "w_out"))}
+            **{kind: stacks(params["layers"][kind], names[kind])
+               for kind in names if kind in params["layers"]}}
     else:
         # the attention projections (and its output gate), the dense and
         # the shared FFN; the expert stacks stay as they are

@@ -1,19 +1,22 @@
-"""A hybrid model's layers: Mamba-2 mixers, attention without rotary
-embedding, and expert-only feed-forward layers, one part a layer.
+"""A hybrid model's layers: Mamba-2 or Mamba-1 mixers, attention without
+rotary embedding, and expert or dense MLP feed-forward layers, one part a
+layer.
 
 ``LlamaConfig.hybrid`` (``llama.HybridConfig``) says the model is of this
-family; its ``layer_pattern`` names each layer of a period ``mamba2``,
-``attention`` or ``experts``. Every layer is ``x <- x + f(RMSNorm(x))`` with
-ONE ``f``, so the three kinds have different weights and the parameters are
-a stack per kind, ``params["layers"][kind]`` with leaves ``(layers of the
-kind, ...)`` in model order; the expert stacks ``w1`` / ``w2`` hold the
-experts THIS process holds, ``first_expert`` says which.
+family; its ``layer_pattern`` names each layer of a period ``mamba2`` or
+``mamba1``, ``attention``, ``experts`` or ``mlp``. Every layer is ``x <- x
++ f(RMSNorm(x))`` with ONE ``f``, so the kinds have different weights and
+the parameters are a stack per kind, ``params["layers"][kind]`` with leaves
+``(layers of the kind, ...)`` in model order; the expert stacks ``w1`` /
+``w2`` hold the experts THIS process holds, ``first_expert`` says which. A
+decoder layer of mixer and MLP (Jamba's) is two such layers.
 
 What a layer keeps between calls (``llama.CACHE_OF_KIND``): an attention
 layer its paged keys and values, in the one KV pool the cache manager
-already runs; a Mamba-2 layer a row of recurrent state ``ssm`` (head_dim,
-state, heads) in float32 and the causal convolution's last ``conv_kernel -
-1`` columns ``conv``, addressed by the row's slot; an expert layer nothing.
+already runs; a state-space layer a row of recurrent state ``ssm`` in
+float32 and the causal convolution's last ``conv_kernel - 1`` columns
+``conv``, addressed by the row's slot (:func:`state_shapes`); an expert or
+MLP layer nothing.
 
 Three forwards share the layer bodies: :func:`trunk` (no cache, whole
 sequences; the tests' plain path), :func:`forward_chunk` (one row's prefill
@@ -30,6 +33,15 @@ gated norm, gate first, the mean square over each group's channels:
 the chunked form (sub-chunks of ``chunk_size``: quadratic inside, the state
 handed on between); decode runs ``ops/pallas/ssm.py:ssm_state_update`` over
 the pool in place.
+
+The Mamba-1 mixer (Jamba's), ``D`` channels and ``N`` states: ``[x | z] =
+u W_in``; ``x <- silu(b + sum_j w_j x_{t-K+1+j})`` depthwise and causal;
+``[d | B | C] = x W_x``, each through its own RMSNorm; in float32 ``dt =
+softplus(d W_dt + dt_bias)`` (D,), ``A = -exp(A_log)`` (D, N), ``S_t[n, d] =
+exp(dt[d] A[d, n]) S_{t-1}[n, d] + dt[d] x_t[d] B_t[n]``, ``y_t = sum_n S_t
+C_t + D x_t``; out ``(y silu(z)) W_out``. The state is ``(N, D)``, channels
+last. A prefill chunk and a decode step both run ``ops/pallas/mamba1.py``
+(a scan token by token, the one-token update over the pool in place).
 """
 from __future__ import annotations
 
@@ -42,9 +54,11 @@ from jax import lax
 
 from . import llama
 from .llama import LlamaConfig, rms_norm
+from ..ops.pallas.mamba1 import mamba1_chunk_scan, mamba1_state_update
 from ..ops.pallas.ssm import ssm_state_update
 
 _HI = lax.Precision.HIGHEST
+F32 = jnp.float32
 
 
 def refuse(**on):
@@ -60,43 +74,82 @@ def refuse(**on):
 # ---------------- parameters ----------------
 def param_shapes(cfg: LlamaConfig, experts_held=None) -> Dict:
     """leaf -> (shape, how it is drawn): a fan-in (N(0, 1/fan_in)), "norm"
-    (ones), "embed" (N(0, 0.02^2)), or the name of a Mamba-2 leaf's own
-    rule (:func:`init_params`). ``experts_held``: the expert axis of the
-    stacks (all the router's experts when None)."""
+    (ones), "embed" (N(0, 0.02^2)), or the name of a state-space leaf's
+    own rule (:func:`init_params`). ``experts_held``: the expert axis of
+    the stacks (all the router's experts when None). Only the period's
+    kinds have stacks; a tied head is the embedding."""
     hy, h, v = cfg.hybrid, cfg.hidden_size, cfg.vocab_size
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    di, cd, H = hy.d_inner, hy.conv_dim, hy.ssm_heads
-    E = cfg.moe.num_experts
-    El = E if experts_held is None else experts_held
-    lat, ie, ish = hy.latent_size, hy.expert_size, hy.shared_size
-    Lm, La, Le = (cfg.kind_layers(k) for k in
-                  ("mamba2", "attention", "experts"))
-    return {
-        "embed": ((v, h), "embed"), "final_norm": ((h,), "norm"),
-        "lm_head": ((h, v), h),
-        "layers": {
-            "mamba2": {"norm": ((Lm, h), "norm"),
-                       "w_in": ((Lm, h, di + cd + H), h),
-                       "conv_w": ((Lm, hy.conv_kernel, cd), hy.conv_kernel),
-                       "conv_b": ((Lm, cd), "zeros"),
-                       "dt_bias": ((Lm, H), "dt_bias"),
-                       "A_log": ((Lm, H), "A_log"), "D": ((Lm, H), "ones"),
-                       "gate_norm": ((Lm, di), "norm"),
-                       "w_out": ((Lm, di, h), di)},
-            "attention": {"norm": ((La, h), "norm"),
-                          "wq": ((La, h, nh * hd), h),
-                          "wk": ((La, h, nkv * hd), h),
-                          "wv": ((La, h, nkv * hd), h),
-                          "wo": ((La, nh * hd, h), nh * hd)},
-            "experts": {"norm": ((Le, h), "norm"),
-                        "router": ((Le, h, E), h),
-                        "router_bias": ((Le, E), "router_bias"),
-                        "w_down": ((Le, h, lat), h),
-                        "w_up": ((Le, lat, h), lat),
-                        "w1": ((Le, El, lat, ie), lat),
-                        "w2": ((Le, El, ie, lat), ie),
-                        "ws1": ((Le, h, ish), h), "ws2": ((Le, ish, h), ish),
-                        "first_expert": ((Le,), "first_expert")}}}
+
+    def mamba2(L):
+        di, cd, H = hy.d_inner, hy.conv_dim, hy.ssm_heads
+        return {"norm": ((L, h), "norm"),
+                "w_in": ((L, h, di + cd + H), h),
+                "conv_w": ((L, hy.conv_kernel, cd), hy.conv_kernel),
+                "conv_b": ((L, cd), "zeros"),
+                "dt_bias": ((L, H), "dt_bias"),
+                "A_log": ((L, H), "A_log"), "D": ((L, H), "ones"),
+                "gate_norm": ((L, di), "norm"),
+                "w_out": ((L, di, h), di)}
+
+    def mamba1(L):
+        D, N, R = hy.channels, hy.ssm_state, hy.dt_rank
+        return {"norm": ((L, h), "norm"),
+                "w_in": ((L, h, 2 * D), h),
+                "conv_w": ((L, hy.conv_kernel, D), hy.conv_kernel),
+                "conv_b": ((L, D), "zeros"),
+                "w_x": ((L, D, R + 2 * N), D),
+                "dt_norm": ((L, R), "norm"), "b_norm": ((L, N), "norm"),
+                "c_norm": ((L, N), "norm"),
+                "w_dt": ((L, R, D), R), "dt_bias": ((L, D), "dt_bias"),
+                "A_log": ((L, D, N), "A_log"), "D": ((L, D), "ones"),
+                "w_out": ((L, D, h), D)}
+
+    def attention(L):
+        return {"norm": ((L, h), "norm"),
+                "wq": ((L, h, nh * hd), h), "wk": ((L, h, nkv * hd), h),
+                "wv": ((L, h, nkv * hd), h), "wo": ((L, nh * hd, h), nh * hd)}
+
+    def experts(L):
+        E = cfg.moe.num_experts
+        El = E if experts_held is None else experts_held
+        lat, ie, ish = hy.latent_size, hy.expert_size, hy.shared_size
+        return {"norm": ((L, h), "norm"),
+                "router": ((L, h, E), h),
+                "router_bias": ((L, E), "router_bias"),
+                "w_down": ((L, h, lat), h), "w_up": ((L, lat, h), lat),
+                "w1": ((L, El, lat, ie), lat), "w2": ((L, El, ie, lat), ie),
+                "ws1": ((L, h, ish), h), "ws2": ((L, ish, h), ish),
+                "first_expert": ((L,), "first_expert")}
+
+    def mlp(L):
+        i = cfg.intermediate_size
+        return {"norm": ((L, h), "norm"), "wg": ((L, h, i), h),
+                "wu": ((L, h, i), h), "wd": ((L, i, h), i)}
+
+    kinds = {"mamba2": mamba2, "mamba1": mamba1, "attention": attention,
+             "experts": experts, "mlp": mlp}
+    return {"embed": ((v, h), "embed"), "final_norm": ((h,), "norm"),
+            **({} if cfg.tie_embeddings else {"lm_head": ((h, v), h)}),
+            "layers": {k: f(cfg.kind_layers(k)) for k, f in kinds.items()
+                       if k in cfg.period}}
+
+
+def state_shapes(cfg: LlamaConfig):
+    """What a row keeps a state-space layer: ``(ssm, conv)`` shapes. Mamba-2
+    ``(head_dim, state, heads)``, heads last, and the convolution's tail
+    ``(conv_kernel - 1, conv_dim)``; Mamba-1 ``(state, channels)``,
+    channels last (``ops/pallas/ssm.py`` and ``mamba1.py`` have the
+    reasons), and its tail flat."""
+    hy = cfg.hybrid
+    if cfg.state_kind == "mamba1":
+        # the tail flat, ((K - 1) * channels,): laid (K - 1, channels) in
+        # bf16, XLA:TPU re-lays the whole pool twice in every layer of a
+        # decode step to cut and write a batch of rows (PERF.md §5)
+        return ((hy.ssm_state, hy.channels),
+                ((hy.conv_kernel - 1) * hy.channels,))
+    return ((hy.ssm_head_dim, hy.ssm_state, hy.ssm_heads),
+            (hy.conv_kernel - 1, hy.conv_dim))
 
 
 #: leaves kept in float32 whatever the model's dtype
@@ -271,6 +324,99 @@ def _mamba_decode(u, lp, cfg: LlamaConfig, ssm, conv, base, active,
     return y @ _w(lp, "w_out", dt_), ssm, conv
 
 
+# ---------------- the Mamba-1 mixer ----------------
+def _mamba1_in(u, lp, cfg: LlamaConfig):
+    """``[x | z] = u W_in``, x first."""
+    from .generate import _w
+    xz = u @ _w(lp, "w_in", u.dtype)
+    D = cfg.hybrid.channels
+    return xz[..., :D], xz[..., D:]
+
+
+def _mamba1_dbc(xc, lp, cfg: LlamaConfig):
+    """The post-convolution ``x`` (..., D) -> float32 ``dt`` (..., D), B
+    and C (..., N): ``x W_x`` cut into ``[d | B | C]``, each through its
+    own RMSNorm, and ``dt = softplus(d W_dt + dt_bias)``."""
+    from .generate import _w
+    hy, eps = cfg.hybrid, cfg.rms_eps
+    R, N = hy.dt_rank, hy.ssm_state
+    dbc = xc @ _w(lp, "w_x", xc.dtype)
+    d = rms_norm(dbc[..., :R], lp["dt_norm"], eps)
+    b = rms_norm(dbc[..., R:R + N], lp["b_norm"], eps)
+    c = rms_norm(dbc[..., R + N:], lp["c_norm"], eps)
+    dt = jax.nn.softplus(jnp.matmul(d, _w(lp, "w_dt", d.dtype),
+                                    preferred_element_type=F32)
+                         + lp["dt_bias"])
+    return dt, b.astype(F32), c.astype(F32)
+
+
+def _mamba1_out(y, xc, z, lp, dtype):
+    """``((y + D x) silu(z)) W_out``: y float32 (..., D)."""
+    from .generate import _w
+    y = (y + lp["D"] * xc.astype(F32)) * jax.nn.silu(z.astype(F32))
+    return y.astype(dtype) @ _w(lp, "w_out", dtype)
+
+
+def _decay(lp):
+    """``A = -exp(A_log)`` as the state lies: (N, D)."""
+    return -jnp.exp(lp["A_log"]).T
+
+
+def _mamba1_seq(u, lp, cfg: LlamaConfig, state, tail, n_valid=None,
+                use_kernel=None):
+    """The Mamba-1 mixer over T tokens of ONE row: ``u`` (T, h) the normed
+    input, ``state`` (N, D) and ``tail`` ((K - 1) * D,) what the row
+    brought; ``n_valid`` (traced) the tokens that are not right-padding.
+    Returns ``(out (T, h), state, tail)``."""
+    dt_ = u.dtype
+    T, K = u.shape[0], cfg.hybrid.conv_kernel
+    x, z = _mamba1_in(u, lp, cfg)
+    seq = jnp.concatenate([tail.reshape(K - 1, -1).astype(dt_), x], axis=0)
+    cw = lp["conv_w"].astype(F32)
+    conv = lp["conv_b"].astype(F32) + sum(
+        cw[j] * seq[j:j + T].astype(F32) for j in range(K))
+    xc = jax.nn.silu(conv).astype(dt_)
+    n = T if n_valid is None else n_valid
+    # the last K - 1 columns that came in, padding not counted
+    tail = lax.dynamic_slice_in_dim(seq, n, K - 1, 0).astype(
+        tail.dtype).reshape(tail.shape)
+    dt, Bm, Cm = _mamba1_dbc(xc, lp, cfg)
+    dt = jnp.where(jnp.arange(T)[:, None] < n, dt, 0.0)
+    y, state = mamba1_chunk_scan(dt, xc.astype(F32), _decay(lp), Bm, Cm,
+                                 state, use_kernel=use_kernel)
+    return _mamba1_out(y, xc, z, lp, dt_), state, tail
+
+
+def _mamba1_decode(u, lp, cfg: LlamaConfig, ssm, conv, base, active,
+                   use_kernel=None):
+    """One token of every row: ``u`` (B, h); ``ssm`` / ``conv`` the pools
+    of ALL state layers as ``(layers * slots, ...)``, this layer's rows
+    from ``base`` on, row b in slot b. Rows not ``active`` keep their
+    state and tail. Returns ``(out (B, h), ssm, conv)``."""
+    dt_ = u.dtype
+    B = u.shape[0]
+    x, z = _mamba1_in(u, lp, cfg)
+    tail = lax.dynamic_slice_in_dim(conv, base, B, 0)     # (B, (K - 1) D)
+    win = jnp.concatenate([tail.reshape(B, -1, x.shape[1]).astype(dt_),
+                           x[:, None]], axis=1)
+    c = lp["conv_b"].astype(F32) + jnp.sum(
+        lp["conv_w"].astype(F32)[None] * win.astype(F32), axis=1)
+    conv = lax.dynamic_update_slice_in_dim(
+        conv, jnp.where(active[:, None], win[:, 1:].reshape(B, -1).astype(
+            conv.dtype), tail), base, 0)
+    xc = jax.nn.silu(c).astype(dt_)
+    dt, Bm, Cm = _mamba1_dbc(xc, lp, cfg)
+    y, ssm = mamba1_state_update(ssm, base, dt, xc.astype(F32), _decay(lp),
+                                 Bm, Cm, active, use_kernel=use_kernel)
+    return _mamba1_out(y, xc, z, lp, dt_), ssm, conv
+
+
+def _mlp(u, lp):
+    """The dense MLP layer: ``(silu(u W_gate) * u W_up) W_down``."""
+    from .generate import _swiglu
+    return _swiglu(u, lp, "wg", "wu", "wd")
+
+
 # ---------------- walking the layers ----------------
 def _layer(stack, idx):
     """Layer ``idx`` (a number or a traced index) of one kind's stack."""
@@ -301,14 +447,20 @@ def _positions(cfg: LlamaConfig):
 
 
 def _expert_stacks(params):
-    e = params["layers"]["experts"]
+    """The expert layers' small leaves and their two expert stacks (None
+    without expert layers)."""
+    e = params["layers"].get("experts")
+    if e is None:
+        return None, None
     lp = {n: a for n, a in e.items() if n not in ("w1", "w2")}
     return lp, (e["w1"], e["w2"])
 
 
 def _head(params, x, cfg):
     from .generate import _w
-    return (x @ _w(params, "lm_head", x.dtype)).astype(jnp.float32)
+    head = (params["embed"].T.astype(x.dtype) if cfg.tie_embeddings
+            else _w(params, "lm_head", x.dtype))
+    return (x @ head).astype(jnp.float32)
 
 
 def _qkv(u, lp, cfg: LlamaConfig, nkv: int):
@@ -328,19 +480,26 @@ def trunk(params, tokens, cfg: LlamaConfig):
     """No cache: tokens (B, S) -> the final norm's hidden states (B, S,
     h). Every row starts from zero state."""
     from .generate import _latent_moe_ffn, _w
-    hy = cfg.hybrid
     B, S = tokens.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     e_lp, experts = _expert_stacks(params)
-    state0 = jnp.zeros((hy.ssm_head_dim, hy.ssm_state, hy.ssm_heads),
-                       jnp.float32)
-    tail0 = jnp.zeros((hy.conv_kernel - 1, hy.conv_dim), cfg.dtype)
+    s_shape, t_shape = state_shapes(cfg)
+    state0 = jnp.zeros(s_shape, jnp.float32)
+    tail0 = jnp.zeros(t_shape, cfg.dtype)
 
     def body(x, i):
         for kind, at, count in _positions(cfg):
             idx = i * count + at
-            if kind == "experts":
+            if kind == "mlp":
+                lp = _layer(params["layers"]["mlp"], idx)
+                f = _mlp(rms_norm(x, lp["norm"], cfg.rms_eps), lp)
+            elif kind == "mamba1":
+                lp = _layer(params["layers"]["mamba1"], idx)
+                f = jax.vmap(lambda u: _mamba1_seq(
+                    u, lp, cfg, state0, tail0)[0])(
+                    rms_norm(x, lp["norm"], cfg.rms_eps))
+            elif kind == "experts":
                 lp = _layer(e_lp, idx)
                 f, _ = _latent_moe_ffn(
                     rms_norm(x, lp["norm"], cfg.rms_eps), lp, cfg, experts,
@@ -393,20 +552,29 @@ def forward_chunk(params, tokens, dense: Dict, state: Dict, slot, pos: int,
                     rms_norm(x, lp["norm"], cfg.rms_eps), lp, cfg, experts,
                     idx, valid=valid, use_kernel=use_kernel)
                 stats = stats + st
-            elif kind == "mamba2":
-                lp = _layer(params["layers"]["mamba2"], idx)
+            elif kind == "mlp":
+                lp = _layer(params["layers"]["mlp"], idx)
+                f = _mlp(rms_norm(x, lp["norm"], cfg.rms_eps), lp)
+            elif kind in ("mamba2", "mamba1"):
+                lp = _layer(params["layers"][kind], idx)
                 # the row's state cut out of the pool where it lies; the
-                # barrier keeps the scan's re-layout of it (heads first)
-                # from being hoisted above the cut, onto the whole pool
+                # barrier keeps the Mamba-2 scan's re-layout of it (heads
+                # first) from being hoisted above the cut, onto the whole
+                # pool
                 s0, t0 = lax.optimization_barrier(tuple(
                     lax.dynamic_slice(
                         state[n], (idx, slot) + (0,) * (state[n].ndim - 2),
                         (1, 1) + state[n].shape[2:])[0, 0]
                     for n in ("ssm", "conv")))
-                f, s1, t1 = _mamba_seq(
-                    rms_norm(x, lp["norm"], cfg.rms_eps)[0], lp, cfg,
-                    jnp.where(fresh, 0, s0), jnp.where(fresh, 0, t0),
-                    n_valid=chunk_len)
+                u = rms_norm(x, lp["norm"], cfg.rms_eps)[0]
+                s0, t0 = jnp.where(fresh, 0, s0), jnp.where(fresh, 0, t0)
+                if kind == "mamba1":
+                    f, s1, t1 = _mamba1_seq(u, lp, cfg, s0, t0,
+                                            n_valid=chunk_len,
+                                            use_kernel=use_kernel)
+                else:
+                    f, s1, t1 = _mamba_seq(u, lp, cfg, s0, t0,
+                                           n_valid=chunk_len)
                 f = f[None]
                 state = {
                     n: lax.dynamic_update_slice(
@@ -479,9 +647,13 @@ def decode_forward(params, tokens, paged: Dict, block_tables, lengths,
                     experts, idx, valid=active[:, None],
                     use_kernel=use_kernel)
                 f, stats = f[:, 0], stats + st
-            elif kind == "mamba2":
-                lp = _layer(params["layers"]["mamba2"], idx)
-                f, ssm, conv = _mamba_decode(
+            elif kind == "mlp":
+                lp = _layer(params["layers"]["mlp"], idx)
+                f = _mlp(rms_norm(x, lp["norm"], cfg.rms_eps), lp)
+            elif kind in ("mamba2", "mamba1"):
+                lp = _layer(params["layers"][kind], idx)
+                f, ssm, conv = (
+                    _mamba1_decode if kind == "mamba1" else _mamba_decode)(
                     rms_norm(x, lp["norm"], cfg.rms_eps), lp, cfg,
                     held["ssm"], held["conv"], idx * slots, active,
                     use_kernel=use_kernel)

@@ -54,13 +54,16 @@ class YarnRope:
 
 
 #: the kinds of layer a ``layer_pattern`` may name. ``sliding`` and ``full``
-#: are attention + MLP pairs (the decoder ``_block``); the next three are
-#: the single-part layers of a hybrid model (``models/hybrid.py``): ONE
-#: mixer or ONE feed-forward part under its own pre-norm and residual.
-#: ``latent`` is an attention + MLP pair whose attention is latent
-#: attention (``models/latent.py``, :class:`LatentConfig`).
-LAYER_KINDS = ("sliding", "full", "mamba2", "attention", "experts", "latent")
-HYBRID_KINDS = LAYER_KINDS[2:5]
+#: are attention + MLP pairs (the decoder ``_block``); ``latent`` is an
+#: attention + MLP pair whose attention is latent attention
+#: (``models/latent.py``, :class:`LatentConfig`). The rest are the
+#: single-part layers of a hybrid model (``models/hybrid.py``): ONE mixer
+#: (Mamba-2, Mamba-1 or attention) or ONE feed-forward part (routed experts
+#: or a dense MLP) under its own pre-norm and residual.
+HYBRID_KINDS = ("mamba2", "attention", "experts", "mamba1", "mlp")
+LAYER_KINDS = ("sliding", "full", "latent") + HYBRID_KINDS
+#: the hybrid kinds that keep a recurrent state; a period has one of them
+STATE_KINDS = ("mamba2", "mamba1")
 
 #: what a layer of each kind keeps between calls of a serving program: a
 #: paged KV pool (``full``: block tables and admission follow it;
@@ -71,7 +74,8 @@ HYBRID_KINDS = LAYER_KINDS[2:5]
 #: engine build pools and programs from :meth:`LlamaConfig.cache_layers`,
 #: not from the kinds' names.
 CACHE_OF_KIND = {"sliding": "sliding", "full": "full", "attention": "full",
-                 "mamba2": "state", "experts": None, "latent": "latent"}
+                 "mamba2": "state", "experts": None, "latent": "latent",
+                 "mamba1": "state", "mlp": None}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,10 +118,18 @@ class LatentConfig:
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
     """The widths of a hybrid model's single-part layers (the published
-    ``nemotron_h`` keys in brackets). ``LlamaConfig.moe`` carries the
-    router's width and ``top_k``; how many of the routed experts THIS
-    process holds is a property of the parameters (the expert stacks'
-    expert axis and ``first_expert``), not of the config."""
+    ``nemotron_h`` keys in brackets, ``jamba``'s where they differ).
+    ``LlamaConfig.moe`` carries the router's width and ``top_k``; how many
+    of the routed experts THIS process holds is a property of the
+    parameters (the expert stacks' expert axis and ``first_expert``), not
+    of the config; a dense ``mlp`` layer is ``intermediate_size`` wide.
+
+    A Mamba-1 layer (``mamba1``) is ``channels`` wide with ``ssm_state``
+    states a channel, ONE decay a channel and state (``A_log`` is
+    ``(channels, ssm_state)``), a ``dt_rank``-wide step projected up to
+    the channels, and Jamba's three inner RMSNorms on the step, B and C;
+    ``ssm_heads`` to ``chunk_size`` and the expert widths are Mamba-2's
+    and the LatentMoE's."""
     ssm_heads: int = 8            # mamba_num_heads
     ssm_head_dim: int = 8         # mamba_head_dim
     ssm_groups: int = 2           # n_groups
@@ -128,6 +140,8 @@ class HybridConfig:
     expert_size: int = 48         # moe_intermediate_size
     shared_size: int = 64         # moe_shared_expert_intermediate_size
     routed_scale: float = 1.0     # routed_scaling_factor
+    channels: int = 64            # mamba_expand * hidden_size (Mamba-1)
+    dt_rank: int = 8              # mamba_dt_rank (Mamba-1)
 
     @property
     def d_inner(self) -> int:
@@ -178,8 +192,9 @@ class LlamaConfig:
     # layers by ``rope_theta`` under ``yarn`` when it is given
     rope_theta_sliding: Optional[float] = None
     yarn: Optional[YarnRope] = None
-    # a hybrid model: the period names "mamba2" / "attention" / "experts"
-    # layers, each ONE part (models/hybrid.py); weights are a stack per
+    # a hybrid model: the period names HYBRID_KINDS layers ("mamba2" or
+    # "mamba1", "attention", "experts", "mlp"), each ONE part
+    # (models/hybrid.py); weights are a stack per
     # kind, ``params["layers"][kind]``. Its attention applies no rotary
     # embedding, and ``moe`` carries the router's width and top_k.
     hybrid: Optional[HybridConfig] = None
@@ -264,6 +279,10 @@ class LlamaConfig:
                 f"layer_pattern={pat!r}: the kinds {HYBRID_KINDS} are a "
                 f"hybrid model's (LlamaConfig.hybrid) and do not mix with "
                 f"'sliding' / 'full'")
+        if sum(k in pat for k in STATE_KINDS) > 1:
+            raise ValueError(
+                f"layer_pattern={pat!r}: one kind of state-space layer a "
+                f"model (one recurrent-state pool, one layout)")
         if "experts" in pat and self.moe is None:
             raise ValueError("layer_pattern names expert layers: moe must "
                              "give the router's width and top_k")
@@ -309,6 +328,11 @@ class LlamaConfig:
               (self.sandwich_norms, "sandwich norms"),
               (self.embed_scale is not None, "an input scale"))
         return tuple(name for flag, name in on if flag)
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The period's kind of state-space layer, if it has one."""
+        return next((k for k in STATE_KINDS if k in self.period), None)
 
     def window_of(self, kind: str) -> Optional[int]:
         return self.sliding_window if kind == "sliding" else None

@@ -113,7 +113,17 @@ def _plant_lost_gate_scope(tree):
     return 'named_scope("attention_output_gate")'
 
 
+def _plant_lost_mamba1_kernel_name(tree):
+    (tree / "paddle_tpu/ops/pallas").mkdir(parents=True)
+    (tree / "paddle_tpu/ops/pallas/mamba1.py").write_text(
+        'named_scope("mamba1_state_update")\n'
+        'named_scope("mamba1_chunk_scan")\n'
+        'name="mamba1_chunk_scan"\n')
+    return 'name="mamba1_state_update"'
+
+
 @pytest.mark.parametrize("rule, plant", [
+    ("check_hybrid_names", _plant_lost_mamba1_kernel_name),
     ("check_hybrid_names", _plant_unfed_chunk_window_keys),
     ("check_hybrid_names", _plant_lost_gate_scope),
     ("check_fault_sites", _plant_unthreaded_site),

@@ -519,6 +519,75 @@ def test_gated_window_serving_programs_fit_one_chip(v5e, program):
         assert m.temp_size_in_bytes < 0.05e9
 
 
+# AI21-Jamba2-3B whole, as chipbench/configs/ai21-jamba2-3b.json has it: 26
+# Mamba-1 and 2 attention layers of one key/value head, a dense MLP each;
+# batch 256, 49,153 pages of 64 (every row can reach 12,288), 192 pages a
+# row. Weights 6.06 GB, the recurrent-state pool 2.39 GB (2.18 of state and
+# 0.20 of convolution tails), the KV pool 3.22 GB: 11.67 GB resident. As
+# compiled here the decode program's temp is 0.23 GB and the widest chunk
+# program's (context 12,288, width 256) 0.30 GB, nearly all of it the
+# float32 scores of 20 heads over 256 queries and 12,544 keys (the hybrid
+# family's chunk attends over a gathered context in jnp); 11.97 GB of the
+# chip's 15.75. The chunk program's temp was 2.17 GB while the chunk's keys
+# went into the two layers' pages by one scatter (generate._scatter_rows: a
+# copy of each pool in and out), and the tails' pool was re-laid in and out
+# of both programs (and on the chip twice again in every Mamba-1 layer)
+# while a row's convolution tail was laid (3, 5120).
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_mamba1_serving_programs_fit_one_chip(v5e, program):
+    from chipbench.archs import jamba as arch
+    from paddle_tpu.models import generate as gen
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "ai21-jamba2-3b.json")) as f:
+        c = json.load(f)
+    cfg = arch.program_config(c, 12288)
+    d = v5e[0]
+    on = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(lambda k: arch.weights(k, c),
+                               jax.random.key(0)))
+    B, page, pps = 256, 64, 192
+    pool = on(jax.eval_shape(lambda: gen.init_paged_cache(
+        cfg, 49153, page, state_slots=B)))
+    assert pool["ssm"].shape == (26, 256, 16, 5120)
+    assert pool["k"].shape == (2, 49153, 64, 1, 128)
+    i32 = jnp.int32
+    if program == "decode":
+        def step(params, last, paged, tables, lengths, active):
+            logits, paged = gen.paged_decode_forward(
+                params, last, paged, tables, lengths, cfg, active=active,
+                use_kernel=True)
+            return jnp.argmax(logits, -1), paged
+        avals = (_on(d, (B,), i32), pool, _on(d, (B, pps), i32),
+                 _on(d, (B,), i32), _on(d, (B,), jnp.bool_))
+    else:
+        def step(params, toks, paged, table, ctx_len, chunk_len, slot):
+            return gen.paged_prefill_chunk(
+                params, toks, paged, table, cfg, ctx_cap=pps * page,
+                ctx_len=ctx_len, chunk_len=chunk_len, use_kernel=True,
+                state_slot=slot)
+        avals = (_on(d, (1, 256), i32), pool, _on(d, (pps,), i32),
+                 _on(d, (), i32), _on(d, (), i32), _on(d, (), i32))
+    compiled = _compile(jax.jit(step, donate_argnums=(2,)), params, *avals)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    resident = sum(a.size * a.dtype.itemsize for a in
+                   jax.tree.leaves(params) + jax.tree.leaves(pool))
+    assert 11.6e9 < resident < 11.7e9
+    assert held < 12.0e9
+    assert m.temp_size_in_bytes < (0.25e9 if program == "decode" else 0.32e9)
+    text = compiled.as_text()
+    # no whole pool is copied: the convolution tails' (204 MB) and the K
+    # and V pools (1.6 GB each) were
+    assert not re.search(r"= bf16\[(26,256|6656),3,5120\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[2,49153,64,1,128\]\S* copy\(", text)
+    # the 13 Mamba-1 layers of a period, unrolled in the scan over two
+    name = "mamba1_state_update" if program == "decode" else \
+        "mamba1_chunk_scan"
+    assert len(re.findall(rf"%{name}[\w.\-]* = ", text)) >= 13
+
+
 # Mellum2-12B-A2.5B's published widths at the cut of chipbench/configs/
 # mellum2-12b-a2.5b.json (12 layers: nine sliding over 1,024 keys, three
 # full); batch 32, a full pool of 5,121 pages of 64 and a sliding pool of 32

@@ -186,6 +186,16 @@ _HYBRID_NAMES = {
     "state_pool_bytes": "paddle_tpu/serving/paged_cache.py",
     'named_scope("ssm_state_update")': "paddle_tpu/ops/pallas/ssm.py",
     'named_scope("ssm_chunk_scan")': "paddle_tpu/models/hybrid.py",
+    # the same two counters by the kind of state-space layer, and the
+    # Mamba-1 kernels' scopes and custom-call names
+    'f"{self._state_kind}_state_rows_total"':
+        "paddle_tpu/inference/predictor.py",
+    'f"{self._state_kind}_chunk_tokens_total"':
+        "paddle_tpu/inference/predictor.py",
+    'named_scope("mamba1_state_update")': "paddle_tpu/ops/pallas/mamba1.py",
+    'name="mamba1_state_update"': "paddle_tpu/ops/pallas/mamba1.py",
+    'named_scope("mamba1_chunk_scan")': "paddle_tpu/ops/pallas/mamba1.py",
+    'name="mamba1_chunk_scan"': "paddle_tpu/ops/pallas/mamba1.py",
     "latent_tokens_attended_total": "paddle_tpu/inference/predictor.py",
     "latent_decode_rows_total": "paddle_tpu/inference/predictor.py",
     "latent_chunk_tokens_total": "paddle_tpu/inference/predictor.py",
