@@ -40,8 +40,9 @@ u W_in``; ``x <- silu(b + sum_j w_j x_{t-K+1+j})`` depthwise and causal;
 softplus(d W_dt + dt_bias)`` (D,), ``A = -exp(A_log)`` (D, N), ``S_t[n, d] =
 exp(dt[d] A[d, n]) S_{t-1}[n, d] + dt[d] x_t[d] B_t[n]``, ``y_t = sum_n S_t
 C_t + D x_t``; out ``(y silu(z)) W_out``. The state is ``(N, D)``, channels
-last. A prefill chunk and a decode step both run ``ops/pallas/mamba1.py``
-(a scan token by token, the one-token update over the pool in place).
+last, the convolution's tail flat. A prefill chunk and a decode step both
+run ``ops/pallas/mamba1.py`` (a scan token by token; the one-token
+convolution step and state update over the pools in place).
 """
 from __future__ import annotations
 
@@ -54,7 +55,8 @@ from jax import lax
 
 from . import llama
 from .llama import LlamaConfig, rms_norm
-from ..ops.pallas.mamba1 import mamba1_chunk_scan, mamba1_state_update
+from ..ops.pallas.mamba1 import (mamba1_chunk_scan, mamba1_conv_step,
+                                 mamba1_state_update)
 from ..ops.pallas.ssm import ssm_state_update
 
 _HI = lax.Precision.HIGHEST
@@ -140,12 +142,17 @@ def state_shapes(cfg: LlamaConfig):
     ``(head_dim, state, heads)``, heads last, and the convolution's tail
     ``(conv_kernel - 1, conv_dim)``; Mamba-1 ``(state, channels)``,
     channels last (``ops/pallas/ssm.py`` and ``mamba1.py`` have the
-    reasons), and its tail flat."""
+    reasons), and its tail flat, ``((conv_kernel - 1) * channels,)``, tap
+    after tap. The Mamba-1 pools are written in a decode step by kernels
+    that alias them (``mamba1_conv_step``, ``mamba1_state_update``) and in
+    a chunk program by the chunk's one-row update (:func:`forward_chunk`);
+    a new kind of state keeps to the same rule: rows laid as whole tiles,
+    and in each program one writer that takes the pool as it is stored."""
     hy = cfg.hybrid
     if cfg.state_kind == "mamba1":
-        # the tail flat, ((K - 1) * channels,): laid (K - 1, channels) in
-        # bf16, XLA:TPU re-lays the whole pool twice in every layer of a
-        # decode step to cut and write a batch of rows (PERF.md §5)
+        # laid (K - 1, channels) in bf16, the 3 taps pad a tile of 16
+        # sublanes and XLA:TPU re-lays the whole pool twice in every layer
+        # of a decode step to cut and write a batch of rows (PERF.md §5)
         return ((hy.ssm_state, hy.channels),
                 ((hy.conv_kernel - 1) * hy.channels,))
     return ((hy.ssm_head_dim, hy.ssm_state, hy.ssm_heads),
@@ -392,18 +399,14 @@ def _mamba1_decode(u, lp, cfg: LlamaConfig, ssm, conv, base, active,
     """One token of every row: ``u`` (B, h); ``ssm`` / ``conv`` the pools
     of ALL state layers as ``(layers * slots, ...)``, this layer's rows
     from ``base`` on, row b in slot b. Rows not ``active`` keep their
-    state and tail. Returns ``(out (B, h), ssm, conv)``."""
+    state and tail. Each pool has one writer here, a kernel that aliases
+    it to its result (``ops/pallas/mamba1.py``: the convolution step the
+    flat tails, the state update the state), so the pools stay in the
+    layout they are stored in. Returns ``(out (B, h), ssm, conv)``."""
     dt_ = u.dtype
-    B = u.shape[0]
     x, z = _mamba1_in(u, lp, cfg)
-    tail = lax.dynamic_slice_in_dim(conv, base, B, 0)     # (B, (K - 1) D)
-    win = jnp.concatenate([tail.reshape(B, -1, x.shape[1]).astype(dt_),
-                           x[:, None]], axis=1)
-    c = lp["conv_b"].astype(F32) + jnp.sum(
-        lp["conv_w"].astype(F32)[None] * win.astype(F32), axis=1)
-    conv = lax.dynamic_update_slice_in_dim(
-        conv, jnp.where(active[:, None], win[:, 1:].reshape(B, -1).astype(
-            conv.dtype), tail), base, 0)
+    c, conv = mamba1_conv_step(conv, base, x, lp["conv_w"], lp["conv_b"],
+                               active, use_kernel=use_kernel)
     xc = jax.nn.silu(c).astype(dt_)
     dt, Bm, Cm = _mamba1_dbc(xc, lp, cfg)
     y, ssm = mamba1_state_update(ssm, base, dt, xc.astype(F32), _decay(lp),

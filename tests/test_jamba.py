@@ -218,6 +218,95 @@ def test_state_update_kernel_agrees_with_its_twin(rows, base):
         fa.set_interpret(False)
 
 
+@pytest.mark.parametrize("kernel,rows,base", [
+    (False, 32, 0), (False, 32, 64), (True, 32, 0), (True, 32, 96),
+    (True, 16, 32), (True, 3, 6)])
+def test_conv_step_agrees_with_the_plain_window_and_shift(kernel, rows,
+                                                          base):
+    """The convolution step, kernel interpreted or ``jnp`` twin, over a
+    pool of 4 layers x 32 slots at a layer's ``base`` (and 3 rows at a
+    ``base`` of 6: one block of the whole batch), against the window
+    ``[tail, x]`` convolved and shifted in numpy: an active row's tail
+    becomes the window's last three columns; an inactive row's tail, and
+    every row outside the layer's, stay bit for bit."""
+    K, D, R = 4, 256, 4 * 32
+    B = 32 if rows % 16 == 0 else rows
+    k = jax.random.split(jax.random.key(rows + base), 4)
+    pool = jax.random.normal(k[0], (R, (K - 1) * D)).astype(jnp.bfloat16)
+    x = jax.random.normal(k[1], (32, D)).astype(jnp.bfloat16)[:B]
+    w = jax.random.normal(k[2], (K, D)).astype(jnp.bfloat16)
+    b = jax.random.normal(k[3], (D,)).astype(jnp.bfloat16)
+    active = jnp.arange(B) % 5 != 1
+    fa.set_interpret(kernel)
+    try:
+        if kernel:
+            c, new = mamba1.mamba1_conv_step_kernel(pool, base, x, w, b,
+                                                    active, rows=rows)
+        else:
+            c, new = mamba1.mamba1_conv_step(pool, base, x, w, b, active,
+                                             use_kernel=False)
+    finally:
+        fa.set_interpret(False)
+    f = lambda a: np.asarray(a.astype(jnp.float32))
+    tail = f(pool)[base:base + B].reshape(B, K - 1, D)
+    win = np.concatenate([tail, f(x)[:, None]], axis=1)
+    want = f(b) + (f(w)[None] * win).sum(axis=1)
+    np.testing.assert_allclose(np.asarray(c), want, rtol=1e-5, atol=1e-5)
+    live = np.asarray(active)
+    got = f(new)[base:base + B].reshape(B, K - 1, D)
+    np.testing.assert_array_equal(got[live], win[live, 1:])
+    kept = np.ones(R, bool)
+    kept[base:base + B] = ~live
+    np.testing.assert_array_equal(np.asarray(new)[kept],
+                                  np.asarray(pool)[kept])
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_tail_handed_between_chunks_and_decode_steps(kernel):
+    """One row through a chunk, decode steps, a chunk from where the decode
+    steps left it, and decode steps again: every tail handed from a chunk
+    to the convolution step and back is read where the other wrote it, so
+    the logits follow the reference's over the whole sequence; twice, the
+    twins and every kernel (interpreted)."""
+    cfg, params = _model()
+    seq = _tokens(40, seed=12)
+    want = _ref_logits(params, seq)
+    cache = PagedKVCache(cfg, 2, 64, page_size=4, enable_prefix_cache=False)
+    cache.admit(1, seq.size)
+    table = jnp.asarray(cache.block_tables[1])
+    pool, done, got = cache.pool, 0, {}
+    active = jnp.asarray([False, True])
+    step = jax.jit(lambda p, t, pool, bt, ln: gen.paged_decode_forward(
+        p, t, pool, bt, ln, cfg, active=active, use_kernel=kernel))
+    fa.set_interpret(kernel)
+    try:
+        for what, n in (("chunk", 13), ("decode", 6), ("chunk", 11),
+                        ("decode", 9)):
+            if what == "chunk":
+                toks = np.zeros((1, 16), np.int32)
+                toks[0, :n] = seq[done:done + n]
+                lg, pool = gen.paged_prefill_chunk(
+                    params, jnp.asarray(toks), pool, table, cfg,
+                    ctx_cap=cache.ctx_cap_pages(cache.pages_for(done)) * 4,
+                    ctx_len=done, chunk_len=n, state_slot=1,
+                    use_kernel=kernel)
+                done += n
+                got[done - 1] = np.asarray(lg)[0]
+                continue
+            for _ in range(n):
+                lg, pool = step(params, jnp.asarray([0, seq[done]]), pool,
+                                jnp.asarray(cache.block_tables),
+                                jnp.asarray([0, done], np.int32))
+                got[done] = np.asarray(lg)[1]
+                done += 1
+    finally:
+        fa.set_interpret(False)
+    at = sorted(got)
+    np.testing.assert_allclose(np.stack([got[i] for i in at]), want[at],
+                               atol=TOL, rtol=0)
+    assert not np.asarray(pool["conv"][:, 0]).any()
+
+
 @pytest.mark.parametrize("T,lanes", [(9, 128), (24, 256)])
 def test_chunk_scan_kernel_agrees_with_its_twin(T, lanes):
     """The scan kernel, interpreted, against the jnp twin, over blocks of

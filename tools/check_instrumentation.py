@@ -196,6 +196,8 @@ _HYBRID_NAMES = {
     'name="mamba1_state_update"': "paddle_tpu/ops/pallas/mamba1.py",
     'named_scope("mamba1_chunk_scan")': "paddle_tpu/ops/pallas/mamba1.py",
     'name="mamba1_chunk_scan"': "paddle_tpu/ops/pallas/mamba1.py",
+    'named_scope("mamba1_conv_step")': "paddle_tpu/ops/pallas/mamba1.py",
+    'name="mamba1_conv_step"': "paddle_tpu/ops/pallas/mamba1.py",
     "latent_tokens_attended_total": "paddle_tpu/inference/predictor.py",
     "latent_decode_rows_total": "paddle_tpu/inference/predictor.py",
     "latent_chunk_tokens_total": "paddle_tpu/inference/predictor.py",

@@ -1,12 +1,13 @@
 #!/bin/sh
 # Runs of one cell, one process a run, in turn, from the tree given by
 # $TREE (default: this checkout); each run's standard output and error under
-# chiprun_out/pr41/<tag>_<n>.{out,err}, its result line and the harness's own
-# summary lines printed here. Stops at the first run that fails, unless
-# $GO_ON is set.
+# $RUN_DIR (default chiprun_out/pr41)/<tag>_<n>.{out,err}, its result line
+# and the harness's own summary lines printed here. A run is cut at
+# $RUN_LIMIT seconds where that is set. Stops at the first run that fails,
+# unless $GO_ON is set.
 #   sh tools/chip_calls/pr41_run.sh <tag> <cell> <seconds> <seed>:<trace 0|1>[:<plant>] ...
 top=$PWD
-out=$top/chiprun_out/pr41
+out=$top/${RUN_DIR:-chiprun_out/pr41}
 mkdir -p $out
 tree=${TREE:-$top}
 tag=$1; cell=$2; secs=$3; shift 3
@@ -19,7 +20,7 @@ for spec in "$@"; do
   extra=""
   [ -n "$plant" ] && extra="--plant $plant"
   t0=$(date +%s)
-  ( cd $tree && python3 -m chipbench.run --workload $cell --seed $seed \
+  ( cd $tree && ${RUN_LIMIT:+timeout $RUN_LIMIT} python3 -m chipbench.run --workload $cell --seed $seed \
       --seconds $secs --trace $trace $extra ) \
       > $out/${tag}_$n.out 2> $out/${tag}_$n.err
   rc=$?
